@@ -1951,9 +1951,9 @@ def serving_loop_stage() -> dict:
       every launch result with a blocking ``int(res)``
       (``search.blocking_syncs``); the persistent loop polls readiness
       and must stay at zero.  The acceptance bar is a >= 3x reduction.
-    * **launch->drain overhead** — the serial driver's blocked-fetch
-      histogram (``search.launch_s``) vs the persistent driver's poll
-      wait (``search.poll_s``).
+    * **launch->drain overhead** — the serial driver's blocked fetches
+      (``search.launch`` spans) vs the persistent driver's poll waits
+      (``search.poll`` spans), summed over the span ring.
     * **mixed-hash batching** — md5+sha1 slots through one
       ``BatchingScheduler`` must pack (occupancy mean > 1, where
       single-model-only batching served exactly 1 via the solo
@@ -1966,6 +1966,7 @@ def serving_loop_stage() -> dict:
     from distpow_tpu.models import puzzle
     from distpow_tpu.parallel.search import persistent_search, search
     from distpow_tpu.runtime.metrics import REGISTRY
+    from distpow_tpu.runtime.spans import SPANS
     from distpow_tpu.sched.engine import BatchingScheduler
 
     stage_t0 = time.time()
@@ -1998,15 +1999,15 @@ def serving_loop_stage() -> dict:
     persistent_search(nonces[0], 1, list(range(256)), batch_size=batch,
                       launch_candidates=launch_cand)
 
-    lh0 = REGISTRY.get_histogram("search.launch_s") or \
-        {"count": 0, "sum": 0.0}
+    def span_s(name, seq0):
+        return sum(s["dur_s"] for s in SPANS.recent()
+                   if s["name"] == name and s["seq"] > seq0)
+
+    seq0 = SPANS.total_recorded
     serial = run_driver(search)
-    lh1 = REGISTRY.get_histogram("search.launch_s")
-    ph0 = REGISTRY.get_histogram("search.poll_s") or \
-        {"count": 0, "sum": 0.0}
+    seq1 = SPANS.total_recorded
     ps0 = REGISTRY.get("search.persistent_steps")
     persistent = run_driver(persistent_search)
-    ph1 = REGISTRY.get_histogram("search.poll_s") or ph0
 
     assert persistent["secrets"] == serial["secrets"], \
         "serving-loop parity violation: drivers disagree on first hits"
@@ -2028,9 +2029,9 @@ def serving_loop_stage() -> dict:
         },
         "launch_drain_overhead_s": {
             "serial_blocked_fetch_sum": round(
-                (lh1["sum"] - lh0["sum"]), 6),
+                span_s("search.launch", seq0), 6),
             "persistent_poll_wait_sum": round(
-                (ph1["sum"] - ph0["sum"]), 6),
+                span_s("search.poll", seq1), 6),
         },
         "persistent_steps": REGISTRY.get("search.persistent_steps") - ps0,
         "wall_s": {"serial": serial["wall_s"],

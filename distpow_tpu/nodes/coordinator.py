@@ -492,7 +492,10 @@ class CoordRPCHandler:
         already carries, so the forensics plane stitches this node's
         view into the request timeline with no new protocol state."""
         t0 = time.monotonic()
-        ts0 = time.time()
+        # the trace id is read from the token inside; a request that
+        # never got that far is not recorded
+        span = SPANS.span("coord.mine", trace_id=0,
+                          node=self.tracer.identity)
         info: dict = {"path": "miss"}
         try:
             return self._mine_rpc(params, t0, info)
@@ -502,9 +505,10 @@ class CoordRPCHandler:
         finally:
             tid = info.pop("trace_id", 0)
             if tid:
-                SPANS.record("coord.mine", ts0, time.monotonic() - t0,
-                             trace_id=tid, node=self.tracer.identity,
-                             **info)
+                span.trace_id = tid
+                span.finish(**info)
+            else:
+                span.discard()
 
     def _mine_rpc(self, params, t0: float, info: dict) -> dict:
         metrics.inc("coord.mine_rpcs")
@@ -972,112 +976,117 @@ class CoordRPCHandler:
         metrics.inc("coord.fanouts")
         # the fan-out instant anchors this round's two latency
         # distributions: fanout->first-result (the race the paper's
-        # contract is about) and fanout->last-ack (cancel propagation)
+        # contract is about) and fanout->last-ack (cancel propagation).
+        # Forensics spans (docs/FORENSICS.md): the round's race from the
+        # fan-out to the first result, and the shard-issue phase inside
+        # it; each is recorded only where the round got past it — spans
+        # are derived observers, never new trace actions
+        span_of = dict(trace_id=trace.trace_id, node=self.tracer.identity,
+                       round=rid, nonce=nonce.hex(), ntz=ntz)
+        race = SPANS.span("coord.first_result", **span_of)
         fanout_t0 = time.monotonic()
-        fanout_ts = time.time()
-        RECORDER.record("coord.fanout", round=rid, nonce=nonce.hex(),
-                        ntz=ntz)
-        if plan is None:
-            plan = self.fleet.round_plan()
-        tasks, pending, inflight = self._assign_shards(trace, nonce, ntz, rid,
-                                                       model, plan)
-        # forensics span (docs/FORENSICS.md): the shard-issue phase,
-        # carved out of timestamps the round takes anyway — spans are
-        # derived observers, never new trace actions
-        SPANS.record("coord.fanout", fanout_ts,
-                     time.monotonic() - fanout_t0,
-                     trace_id=trace.trace_id, node=self.tracer.identity,
-                     round=rid, nonce=nonce.hex(), ntz=ntz,
-                     shards=len(tasks))
-
-        # first-result-wins (coordinator.go:202-206); under "reassign",
-        # waiting is interleaved with liveness probes, the harvest of
-        # the parallel fan-out's outstanding Mine acks AND the straggler
-        # hedge (docs/FLEET.md: a shard whose heartbeat-lease owner has
-        # gone silent past the fleet's hedge threshold gets a duplicate
-        # on the least-loaded live worker — first result still wins);
-        # orphaned and not-yet-placed shards are re-issued every round
-        # so coverage is never silently lost
-        hedged: set = set()
-        while True:
+        try:
+            RECORDER.record("coord.fanout", round=rid, nonce=nonce.hex(),
+                            ntz=ntz)
+            issue = SPANS.span("coord.fanout", **span_of)
             try:
-                first = results.get(timeout=probe_t)
-                break
-            except queue.Empty:
-                tasks, hung = self._harvest_inflight(inflight, tasks, (), rid)
-                tasks, orphans = self._reap_dead(tasks, ())
-                if not tasks:
-                    raise RuntimeError("all workers died while mining")
-                tasks, pending = self._issue_shards(
-                    trace, nonce, ntz, tasks, pending + hung + orphans, rid,
-                    model, plan
-                )
-                tasks = self._maybe_hedge(trace, nonce, ntz, tasks, rid,
-                                          model, plan, hedged)
-        first_result_s = time.monotonic() - fanout_t0
+                if plan is None:
+                    plan = self.fleet.round_plan()
+                tasks, pending, inflight = self._assign_shards(
+                    trace, nonce, ntz, rid, model, plan)
+                issue.finish(shards=len(tasks))
+            finally:
+                issue.discard()
+
+            # first-result-wins (coordinator.go:202-206); under
+            # "reassign", waiting is interleaved with liveness probes,
+            # the harvest of the parallel fan-out's outstanding Mine
+            # acks AND the straggler hedge (docs/FLEET.md: a shard whose
+            # heartbeat-lease owner has gone silent past the fleet's
+            # hedge threshold gets a duplicate on the least-loaded live
+            # worker — first result still wins); orphaned and
+            # not-yet-placed shards are re-issued every round so
+            # coverage is never silently lost
+            hedged: set = set()
+            while True:
+                try:
+                    first = results.get(timeout=probe_t)
+                    break
+                except queue.Empty:
+                    tasks, hung = self._harvest_inflight(inflight, tasks, (),
+                                                         rid)
+                    tasks, orphans = self._reap_dead(tasks, ())
+                    if not tasks:
+                        raise RuntimeError("all workers died while mining")
+                    tasks, pending = self._issue_shards(
+                        trace, nonce, ntz, tasks, pending + hung + orphans,
+                        rid, model, plan
+                    )
+                    tasks = self._maybe_hedge(trace, nonce, ntz, tasks, rid,
+                                              model, plan, hedged)
+            first_result_s = time.monotonic() - fanout_t0
+            race.finish(winner_byte=int(first["worker_byte"]))
+        finally:
+            race.discard()
         metrics.observe("coord.first_result_s", first_result_s,
                         trace_id=trace.trace_id)
         RECORDER.record("coord.first_result", round=rid,
                         nonce=nonce.hex(), ntz=ntz,
                         worker_byte=int(first["worker_byte"]),
                         latency_s=round(first_result_s, 6))
-        SPANS.record("coord.first_result", fanout_ts, first_result_s,
-                     trace_id=trace.trace_id, node=self.tracer.identity,
-                     round=rid, nonce=nonce.hex(), ntz=ntz,
-                     winner_byte=int(first["worker_byte"]))
         if first["secret"] is None:
             raise RuntimeError(
                 "protocol violation: first worker message was a cancellation "
                 f"ACK from worker_byte={first['worker_byte']}"
             )
         winner = bytes(first["secret"])
+        # the cancel storm starts where the race ended, so the two tile
+        # the round on the stitched timeline instead of double-counting
+        # the race
+        storm = SPANS.span("coord.cancel_storm", **span_of)
+        try:
+            tasks = self._broadcast_found(trace, nonce, ntz, winner, tasks,
+                                          rid, model)
 
-        tasks = self._broadcast_found(trace, nonce, ntz, winner, tasks, rid,
-                                      model)
-
-        # the 2-messages-per-task ack ledger (coordinator.go:237-248): the
-        # finder already delivered 1 message; every surviving task owes 2
-        remaining: Dict[int, int] = {}
-        for _, shard in tasks:
-            remaining[shard] = remaining.get(shard, 0) + 2
-        fb = int(first["worker_byte"])
-        if fb in remaining:
-            remaining[fb] -= 1
-        late: List[dict] = []
-        while any(v > 0 for v in remaining.values()):
-            try:
-                msg = results.get(timeout=probe_t)
-            except queue.Empty:
-                tasks, _ = self._harvest_inflight(
-                    inflight, tasks, (remaining,), rid
-                )
-                tasks, _ = self._reap_dead(tasks, (remaining,))
-                continue
-            if msg["secret"] is not None:
-                late.append(msg)
-                metrics.inc("coord.late_results")
-                log.info("late worker result: %s", msg["worker_byte"])
-            b = int(msg["worker_byte"])
-            if b in remaining:
-                remaining[b] -= 1
-        # the 2N-ack ledger just drained: every surviving worker has
-        # acknowledged the cancellation — fanout->last-ack is the
-        # cancel-propagation latency the ISSUE-3 plane measures
-        cancel_s = time.monotonic() - fanout_t0
-        metrics.observe("coord.cancel_propagation_s", cancel_s,
-                        trace_id=trace.trace_id)
-        RECORDER.record("coord.cancel_complete", round=rid,
-                        nonce=nonce.hex(), ntz=ntz,
-                        late_results=len(late),
-                        latency_s=round(cancel_s, 6))
-        # the cancel-storm span starts where first_result ended, so the
-        # two tile the round on the stitched timeline instead of
-        # double-counting the race
-        SPANS.record("coord.cancel_storm", fanout_ts + first_result_s,
-                     cancel_s - first_result_s,
-                     trace_id=trace.trace_id, node=self.tracer.identity,
-                     round=rid, nonce=nonce.hex(), ntz=ntz,
-                     late_results=len(late))
+            # the 2-messages-per-task ack ledger (coordinator.go:237-248):
+            # the finder already delivered 1 message; every surviving task
+            # owes 2
+            remaining: Dict[int, int] = {}
+            for _, shard in tasks:
+                remaining[shard] = remaining.get(shard, 0) + 2
+            fb = int(first["worker_byte"])
+            if fb in remaining:
+                remaining[fb] -= 1
+            late: List[dict] = []
+            while any(v > 0 for v in remaining.values()):
+                try:
+                    msg = results.get(timeout=probe_t)
+                except queue.Empty:
+                    tasks, _ = self._harvest_inflight(
+                        inflight, tasks, (remaining,), rid
+                    )
+                    tasks, _ = self._reap_dead(tasks, (remaining,))
+                    continue
+                if msg["secret"] is not None:
+                    late.append(msg)
+                    metrics.inc("coord.late_results")
+                    log.info("late worker result: %s", msg["worker_byte"])
+                b = int(msg["worker_byte"])
+                if b in remaining:
+                    remaining[b] -= 1
+            # the 2N-ack ledger just drained: every surviving worker has
+            # acknowledged the cancellation — fanout->last-ack is the
+            # cancel-propagation latency the ISSUE-3 plane measures
+            cancel_s = time.monotonic() - fanout_t0
+            metrics.observe("coord.cancel_propagation_s", cancel_s,
+                            trace_id=trace.trace_id)
+            RECORDER.record("coord.cancel_complete", round=rid,
+                            nonce=nonce.hex(), ntz=ntz,
+                            late_results=len(late),
+                            latency_s=round(cancel_s, 6))
+            storm.finish(late_results=len(late))
+        finally:
+            storm.discard()
 
         # late-result cache propagation (coordinator.go:250-280): each
         # rebroadcast is acked once per task (cache-update-only round)

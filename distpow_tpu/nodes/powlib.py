@@ -785,7 +785,10 @@ class POW:
     def _call_mine(self, tracer, nonce, num_trailing_zeros, trace,
                    hash_model=None) -> None:
         t0 = time.monotonic()
-        ts0 = time.time()
+        # the client half of the request timeline, finished at the two
+        # outcomes below and discarded on any other exit
+        span = SPANS.span("powlib.mine", trace_id=trace.trace_id,
+                          node=tracer.identity, ntz=num_trailing_zeros)
         try:
             trace.record_action(
                 act.PowlibMine(nonce=nonce, num_trailing_zeros=num_trailing_zeros)
@@ -795,13 +798,9 @@ class POW:
                                                num_trailing_zeros, hash_model)
             except _MineFailed as exc:
                 log.error("mine RPC failed: %s", exc)
-                # the client half of the request timeline records its
-                # failures too — a degraded mine is forensics evidence,
-                # not just a log line (docs/FORENSICS.md)
-                SPANS.record("powlib.mine", ts0, time.monotonic() - t0,
-                             trace_id=trace.trace_id,
-                             node=tracer.identity, ntz=num_trailing_zeros,
-                             outcome="error")
+                # a degraded mine is forensics evidence, not just a log
+                # line (docs/FORENSICS.md)
+                span.finish(outcome="error")
                 if not self._close_ev.is_set():
                     # deliver the failure: a silent drop would leave
                     # the client blocked on the notify queue forever
@@ -818,12 +817,9 @@ class POW:
             # included — the end-to-end latency a caller actually waits.
             # The trace id rides as the histogram's bucket exemplar and
             # keys the client-side span of the request timeline.
-            mine_s = time.monotonic() - t0
-            metrics.observe("powlib.mine_s", mine_s,
+            metrics.observe("powlib.mine_s", time.monotonic() - t0,
                             trace_id=trace.trace_id)
-            SPANS.record("powlib.mine", ts0, mine_s,
-                         trace_id=trace.trace_id, node=tracer.identity,
-                         ntz=num_trailing_zeros, outcome="ok")
+            span.finish(outcome="ok")
             token = decode_token(result["token"])
             result_trace = tracer.receive_token(token)
             mr = MineResult(
@@ -849,6 +845,7 @@ class POW:
             if not self._close_ev.is_set():
                 self.notify_queue.put(mr)
         finally:
+            span.discard()
             with self._inflight_lock:
                 self._inflight.discard(threading.current_thread())
 

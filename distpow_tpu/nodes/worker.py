@@ -49,7 +49,7 @@ from ..runtime.metrics import REGISTRY as metrics
 from ..runtime.config import WorkerConfig
 from ..runtime.health import SENTINELS
 from ..runtime.rpc import RPCClient, RPCServer, StatsOnly
-from ..runtime.spans import SPANS
+from ..runtime.spans import NULL_SPAN, SPANS
 from ..runtime.telemetry import RECORDER
 from ..runtime.tracing import Tracer, decode_token, make_tracer, wire_token
 from ..runtime.watchdog import WATCHDOG
@@ -858,13 +858,18 @@ class Worker:
                             pass
                     return
                 tid = _result_trace_id(res) if SPANS.enabled else 0
-                # the delivery clock starts ONCE per message, outside
+                # the delivery span starts ONCE per message, outside
                 # the retry loop: a delivery that burned attempts and
                 # backoff against an unreachable coordinator must show
                 # its full stall on the timeline, not just the final
-                # (fast) successful attempt (review PR 9)
-                fwd_ts = time.time()
-                fwd_t0 = time.monotonic()
+                # (fast) successful attempt (review PR 9).  It is
+                # recorded at a confirmed delivery only
+                forward = SPANS.span(
+                    "worker.result_forward", trace_id=tid,
+                    node=self.config.WorkerID,
+                    worker_byte=int(res["worker_byte"]), attempts=0,
+                    kind=("result" if res.get("secret") is not None
+                          else "ack")) if tid else NULL_SPAN
                 attempts = 0
                 while not self._stopping.is_set():
                     try:
@@ -878,21 +883,12 @@ class Worker:
                         client.go(
                             "CoordRPCHandler.Result", res
                         ).result(timeout=10.0)
-                        if tid:
-                            # the delivery leg of the request timeline:
-                            # a delayed/retried Result shows up HERE,
-                            # not in worker.solve — exactly the segment
-                            # that otherwise hides between two nodes'
-                            # clocks (docs/FORENSICS.md)
-                            SPANS.record(
-                                "worker.result_forward", fwd_ts,
-                                time.monotonic() - fwd_t0, trace_id=tid,
-                                node=self.config.WorkerID,
-                                worker_byte=int(res["worker_byte"]),
-                                attempts=attempts,
-                                kind=("result" if res.get("secret")
-                                      is not None else "ack"),
-                            )
+                        # the delivery leg of the request timeline: a
+                        # delayed/retried Result shows up HERE, not in
+                        # worker.solve — exactly the segment that
+                        # otherwise hides between two nodes' clocks
+                        # (docs/FORENSICS.md)
+                        forward.finish(attempts=attempts)
                         backoff = 0.2
                         break
                     except Exception as exc:
@@ -910,6 +906,7 @@ class Worker:
                             addr or self.config.CoordAddr, exc, backoff,
                         )
                         if self._stopping.wait(backoff):
+                            forward.discard()
                             return
                         backoff = min(backoff * 2, 5.0)
                         # tear down exactly the connection that failed;
@@ -931,6 +928,7 @@ class Worker:
                                     self.config.CoordAddr)
                             except OSError:
                                 continue
+                forward.discard()  # stopping: never delivered
 
         def destination(addr: str) -> "queue.Queue":
             q = self._forward_subqueues.get(addr)

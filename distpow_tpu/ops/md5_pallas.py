@@ -72,7 +72,7 @@ from ..models.sha1_jax import SHA1_K
 from ..models.sha256_jax import SHA256_INIT, SHA256_K
 from .difficulty import nibble_masks
 from .packing import build_tail_spec
-from .search_step import SENTINEL, _check_launch, mask_words_for
+from .search_step import SENTINEL, _check_launch, mask_words_for, named_jit
 
 LANES = 128
 # Per-model (sublanes, inner) tile geometry — see module docstring.
@@ -802,7 +802,6 @@ def _dyn_pallas_step(
         interpret=interpret,
     )
 
-    @jax.jit
     def step(chunk0, init, base, masks, part):
         chunk0 = jnp.asarray(chunk0, jnp.uint32).reshape((1,))
         m = call(chunk0, init, base, masks, part)[0, 0]
@@ -810,7 +809,7 @@ def _dyn_pallas_step(
             m == jnp.int32(_I32_MISS), jnp.uint32(SENTINEL), m.astype(jnp.uint32)
         )
 
-    return step
+    return named_jit(step, f"{model_name}_pallas_step")
 
 
 def build_pallas_search_step(

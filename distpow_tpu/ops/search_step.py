@@ -42,6 +42,16 @@ from .packing import TailSpec, build_tail_spec, make_words
 
 SENTINEL = 0xFFFFFFFF
 
+
+def named_jit(fn: Callable, name: str) -> Callable:
+    """``jax.jit(fn)`` under ``name``: the program's XLA module, and the
+    profiler's ``XLA Modules`` line, read ``jit_<name>`` instead of the
+    ``jit_step`` every search program would otherwise share.  Only the
+    name changes."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
 # Models whose fused XLA serving step is impractical to COMPILE on the
 # TPU backend (>30 min observed for sha512's limb-emulation graph in
 # both compress forms, r4c hardware session — docs/KERNELS.md): bench
@@ -108,7 +118,7 @@ def build_search_step(
 
         return jax.lax.fori_loop(0, launch_steps, body, jnp.uint32(SENTINEL))
 
-    return jax.jit(step) if jit else step
+    return named_jit(step, f"{model.name}_static_step") if jit else step
 
 
 def _check_launch(batch: int, launch_steps: int) -> None:
@@ -253,7 +263,7 @@ def _dyn_search_step(
                 0, launch_steps, body, jnp.uint32(SENTINEL)
             )
 
-    return jax.jit(step)
+    return named_jit(step, f"{model_name}_launch_step")
 
 
 @functools.lru_cache(maxsize=None)
@@ -284,7 +294,7 @@ def _dyn_search_step_w0(model_name: str, n_blocks: int, tb_loc, chunk_locs,
         hit = hit & (tb >= tb_lo) & (tb < tb_lo + tbc)
         return jnp.min(jnp.where(hit, tb - tb_lo, jnp.uint32(SENTINEL)))
 
-    return jax.jit(step)
+    return named_jit(step, f"{model_name}_probe_step")
 
 
 def step_operands(spec: TailSpec, difficulty: int, model: HashModel):
@@ -445,7 +455,8 @@ def persistent_search_step(
 
         return step_static
 
-    return jax.jit(make_step(static_tbc is None))
+    return named_jit(make_step(static_tbc is None),
+                     f"{model_name}_persistent_step")
 
 
 @functools.lru_cache(maxsize=512)
@@ -566,9 +577,9 @@ def slot_search_step(
     """
     model = get_hash_model(model_name)
     _check_launch(batch, launch_steps)
-    return jax.jit(jax.vmap(
+    return named_jit(jax.vmap(
         _slot_lane(model, n_blocks, tb_loc, chunk_locs, batch, launch_steps)
-    ))
+    ), f"{model_name}_slot_step")
 
 
 @functools.lru_cache(maxsize=None)
@@ -608,4 +619,4 @@ def mixed_slot_search_step(
             jax.vmap(lane)(*ops) for lane, ops in zip(lanes, group_ops)
         )
 
-    return jax.jit(step)
+    return named_jit(step, "mixed_slot_step")

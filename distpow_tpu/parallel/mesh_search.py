@@ -46,6 +46,7 @@ from ..ops.search_step import (
     eval_dyn_candidates,
     fold_dyn_masks,
     mask_words_for,
+    named_jit,
     step_operands,
 )
 from .compat import pvary as _pvary
@@ -146,7 +147,7 @@ def _dyn_mesh_step(
     sharded = _shard_map(
         body, mesh=mesh, in_specs=(P(), P(), P(), P(), P()), out_specs=P()
     )
-    return jax.jit(sharded)
+    return named_jit(sharded, f"{model_name}_mesh_step")
 
 
 def _width0_probe(nonce: bytes, difficulty: int, tb_lo: int, tbc: int,
@@ -248,7 +249,7 @@ def _dyn_pallas_mesh_step(
         body, mesh=mesh, in_specs=(P(), P(), P(), P(), P()), out_specs=P(),
         check_vma=False,
     )
-    return jax.jit(sharded)
+    return named_jit(sharded, f"{model_name}_pallas_mesh_step")
 
 
 def _pallas_mesh_step_factory(
@@ -449,7 +450,7 @@ def _mesh_step_factory(
                 return jax.lax.pmin(m, axis)
 
         sharded = _shard_map(body, mesh=mesh, in_specs=P(), out_specs=P())
-        return jax.jit(sharded)
+        return named_jit(sharded, f"{model.name}_static_mesh_step")
 
     def factory(vw: int, extra: bytes, target_chunks: int, launch_steps: int = 1):
         if vw == 0:
@@ -528,7 +529,7 @@ def mesh_slot_search_step(
         body, mesh=mesh, in_specs=(P(),) * 6, out_specs=P(),
         check_vma=False,
     )
-    return jax.jit(sharded)
+    return named_jit(sharded, f"{model_name}_mesh_slot_step")
 
 
 @functools.lru_cache(maxsize=None)
@@ -622,7 +623,7 @@ def mesh_persistent_step(
         make_step(static_tbc is None), mesh=mesh, in_specs=(P(),) * n_in,
         out_specs=P(), check_vma=False,
     )
-    return jax.jit(sharded)
+    return named_jit(sharded, f"{model_name}_mesh_persistent_step")
 
 
 def mesh_persistent_factory(

@@ -326,25 +326,19 @@ def search(
         hashes += n_cand
         metrics.inc("search.hashes", n_cand)
         # the sanctioned host sync: time blocked on the launch's result
-        # fetch — the per-launch latency distribution (pipelined, so a
-        # busy pipeline shows near-zero waits; a dry one shows the full
-        # device round trip).  Counted as a blocking sync: the
-        # conversion is issued without readiness confirmed, which is
-        # exactly what the persistent driver's polling drain avoids
-        # (bench.py --serving-loop measures the two against each other)
+        # fetch (pipelined, so a busy pipeline shows near-zero waits; a
+        # dry one shows the full device round trip).  Counted as a
+        # blocking sync: the conversion is issued without readiness
+        # confirmed, which is exactly what the persistent driver's
+        # polling drain avoids (bench.py --serving-loop measures the
+        # two against each other)
         metrics.inc("search.blocking_syncs")
-        fetch_ts = time.time()
-        fetch_t0 = time.monotonic()
-        f = int(res)
-        fetch_s = time.monotonic() - fetch_t0
-        metrics.observe("search.launch_s", fetch_s)
-        if SPANS.enabled:
-            # per-dispatch forensics segment: the trace id rides the
-            # miner thread's binding (nodes/worker.py SPANS.bind), so a
-            # request's launches line up under it on the stitched
-            # timeline (docs/FORENSICS.md)
-            SPANS.record("search.launch", fetch_ts, fetch_s,
-                         n_cand=n_cand)
+        # per-dispatch forensics span: the trace id rides the miner
+        # thread's binding (nodes/worker.py SPANS.bind), so a request's
+        # launches line up under it on the stitched timeline
+        # (docs/FORENSICS.md)
+        with SPANS.span("search.launch", n_cand=n_cand):
+            f = int(res)
         _RATE_METER.note(n_cand)
         if f == SENTINEL:
             return None
@@ -391,71 +385,79 @@ def search(
         with WATCHDOG.active():
             for width in range(0, max_width + 1):
                 for vw, lo, hi, extra in width_segments(width):
-                    WATCHDOG.beat()  # factory may compile (bounded gap)
-                    k = launch_steps_for(vw, target_chunks, tbc,
-                                         launch_candidates)
-                    step, chunks_per_step = factory(vw, extra,
-                                                    target_chunks, k)
-                    chunk0 = lo
-                    while chunk0 < hi:
-                        # A launch's compiled span can overshoot the
-                        # segment end (the chunk count is a compile-time
-                        # shape; the tail launch is not re-compiled
-                        # smaller).  Overshot chunk ints alias back into
-                        # already-covered candidates via the width mask —
-                        # harmless for first-hit order (an aliased hit
-                        # implies an equal in-launch or already-scanned
-                        # hit) — but they are NOT searched work: count
-                        # only the in-segment candidates, or hashes_tried
-                        # / search.hashes inflate by orders of magnitude
-                        # on small partitions and max_hashes budgets
-                        # misfire (found by the round-4 differential
-                        # fuzz: a [240,241] partition reported 16.7M
-                        # hashes for a 4.8k-candidate solve).
-                        n_cand = min(chunks_per_step, hi - chunk0) * tbc
-                        WATCHDOG.beat()
-                        if cancel_check is not None and cancel_check():
-                            flush_inflight_counts()
-                            metrics.inc("search.cancelled")
-                            return None
-                        if max_hashes is not None and hashes >= max_hashes:
-                            found = drain_all()
-                            # drain_all stops at the first hit: launches
-                            # still in flight behind it must be counted
-                            # (search.hashes == dispatched work on every
-                            # exit path, flush_inflight_counts)
-                            flush_inflight_counts()
-                            if found is not None:
-                                metrics.inc("search.found")
-                            return found
-                        if chunk0 == lo:
-                            # the segment's FIRST launch pays the compile
-                            # when the layout cache is cold (an unwarmed
-                            # width or model): one uninterruptible gap
-                            # that can far exceed the hang timeout for
-                            # the biggest graphs (sha512 unrolled:
-                            # >22 min observed) — widen the
-                            # window for just this launch so an armed
-                            # watchdog does not kill a healthy worker
-                            # mid-compile
-                            with WATCHDOG.grace(FIRST_COMPILE_GRACE_S):
-                                res = step(chunk0 & 0xFFFFFFFF)
-                        else:
-                            res = step(chunk0 & 0xFFFFFFFF)
-                        metrics.inc("search.launches")
-                        inflight.append((res, chunk0, vw, extra, n_cand))
-                        chunk0 += chunks_per_step
-                        if len(inflight) >= pipeline_depth:
-                            found = drain_one()
-                            if found is not None:
+                    # one span per width segment: a device gap inside a
+                    # solve then names the segment whose drain or first
+                    # dispatch left it
+                    dispatches = 0
+                    with SPANS.span("search.segment", width=width,
+                                    dispatches=0) as seg:
+                        WATCHDOG.beat()  # factory may compile (bounded gap)
+                        k = launch_steps_for(vw, target_chunks, tbc,
+                                             launch_candidates)
+                        step, chunks_per_step = factory(vw, extra,
+                                                        target_chunks, k)
+                        chunk0 = lo
+                        while chunk0 < hi:
+                            # A launch's compiled span can overshoot the
+                            # segment end (the chunk count is a compile-time
+                            # shape; the tail launch is not re-compiled
+                            # smaller).  Overshot chunk ints alias back into
+                            # already-covered candidates via the width mask —
+                            # harmless for first-hit order (an aliased hit
+                            # implies an equal in-launch or already-scanned
+                            # hit) — but they are NOT searched work: count
+                            # only the in-segment candidates, or hashes_tried
+                            # / search.hashes inflate by orders of magnitude
+                            # on small partitions and max_hashes budgets
+                            # misfire (found by the round-4 differential
+                            # fuzz: a [240,241] partition reported 16.7M
+                            # hashes for a 4.8k-candidate solve).
+                            n_cand = min(chunks_per_step, hi - chunk0) * tbc
+                            WATCHDOG.beat()
+                            if cancel_check is not None and cancel_check():
                                 flush_inflight_counts()
-                                metrics.inc("search.found")
+                                metrics.inc("search.cancelled")
+                                return None
+                            if max_hashes is not None and hashes >= max_hashes:
+                                found = drain_all()
+                                # drain_all stops at the first hit: launches
+                                # still in flight behind it must be counted
+                                # (search.hashes == dispatched work on every
+                                # exit path, flush_inflight_counts)
+                                flush_inflight_counts()
+                                if found is not None:
+                                    metrics.inc("search.found")
                                 return found
-                    found = drain_all()
-                    if found is not None:
-                        flush_inflight_counts()
-                        metrics.inc("search.found")
-                        return found
+                            if chunk0 == lo:
+                                # the segment's FIRST launch pays the compile
+                                # when the layout cache is cold (an unwarmed
+                                # width or model): one uninterruptible gap
+                                # that can far exceed the hang timeout for
+                                # the biggest graphs (sha512 unrolled:
+                                # >22 min observed) — widen the
+                                # window for just this launch so an armed
+                                # watchdog does not kill a healthy worker
+                                # mid-compile
+                                with WATCHDOG.grace(FIRST_COMPILE_GRACE_S):
+                                    res = step(chunk0 & 0xFFFFFFFF)
+                            else:
+                                res = step(chunk0 & 0xFFFFFFFF)
+                            metrics.inc("search.launches")
+                            dispatches += 1
+                            seg.annotate(dispatches=dispatches)
+                            inflight.append((res, chunk0, vw, extra, n_cand))
+                            chunk0 += chunks_per_step
+                            if len(inflight) >= pipeline_depth:
+                                found = drain_one()
+                                if found is not None:
+                                    flush_inflight_counts()
+                                    metrics.inc("search.found")
+                                    return found
+                        found = drain_all()
+                        if found is not None:
+                            flush_inflight_counts()
+                            metrics.inc("search.found")
+                            return found
         return None
     finally:
         _RATE_METER.exit()
@@ -543,7 +545,7 @@ def persistent_search(
       (``jax.Array.is_ready`` — a cheap flag query, not a result
       fetch) and only converts once ready, so the host never blocks
       inside a result fetch (``search.blocking_syncs`` stays flat;
-      the waiting time is observable as ``search.poll_s``);
+      the waiting time is observable as ``search.poll`` spans);
     * cancellation stops issuing dispatches the moment it is observed
       (and flips the :class:`StopFlag` future dispatches would carry —
       see its docstring for what actually exercises the set form):
@@ -597,31 +599,27 @@ def persistent_search(
         nonlocal hashes
         res, chunk0, vw, extra, seg_chunks, chunks_each, is_pair = \
             inflight.popleft()
-        poll_ts = time.time()
-        poll_t0 = time.monotonic()
-        waited = False
         # deliberately NO WATCHDOG.beat() inside the poll wait: a hung
         # device leaves is_ready() false forever, and beating here
         # would mask exactly the staleness the hang watchdog exists to
         # convert into a visible death — the poll wait accumulates
         # staleness like the serial driver's blocking fetch does
-        while not res.is_ready():
-            waited = True
-            if cancel_check is not None and cancel_check():
-                stop.set()
-                # account the popped head like the rest of the flush:
-                # dispatched work the device completes either way
-                hashes += seg_chunks * tbc
-                metrics.inc("search.hashes", seg_chunks * tbc)
-                return None, True
-            time.sleep(poll_interval_s)
-        if waited:
-            poll_s = time.monotonic() - poll_t0
-            metrics.observe("search.poll_s", poll_s)
-            if SPANS.enabled:
-                # the persistent twin of the serial driver's
-                # search.launch span (same thread-bound trace id)
-                SPANS.record("search.poll", poll_ts, poll_s)
+        if not res.is_ready():
+            # the persistent twin of the serial driver's search.launch
+            # span (same thread-bound trace id); a wait the cancel ends
+            # is not recorded
+            with SPANS.span("search.poll") as wait:
+                while not res.is_ready():
+                    if cancel_check is not None and cancel_check():
+                        wait.discard()
+                        stop.set()
+                        # account the popped head like the rest of the
+                        # flush: dispatched work the device completes
+                        # either way
+                        hashes += seg_chunks * tbc
+                        metrics.inc("search.hashes", seg_chunks * tbc)
+                        return None, True
+                    time.sleep(poll_interval_s)
         if is_pair:
             f, segs = _fetch_pair(res)
             metrics.inc("search.persistent_steps", segs)
@@ -672,86 +670,94 @@ def persistent_search(
         with WATCHDOG.active():
             for width in range(0, max_width + 1):
                 for vw, lo, hi, extra in width_segments(width):
-                    WATCHDOG.beat()  # step build may compile below
-                    k = launch_steps_for(vw, target_chunks, tbc,
-                                         launch_candidates)
-                    if vw == 0:
-                        step0 = cached_search_step(
-                            nonce, 0, difficulty, tb_lo, tbc, 1,
-                            model.name, extra, 1,
-                        )
-                        step, chunks_per_step, chunks_each = \
-                            None, 1, 1
-                    else:
-                        plan = (step_builder(vw, extra, target_chunks, k)
-                                if step_builder is not None else None)
-                        if plan is not None:
-                            step, chunks_each, chunks_per_step = plan
-                        else:
-                            step = cached_persistent_step(
-                                nonce, vw, difficulty, tb_lo, tbc,
-                                target_chunks, model.name, extra, k,
+                    # one span per width segment: a device gap inside a
+                    # solve then names the segment whose drain or first
+                    # dispatch left it
+                    dispatches = 0
+                    with SPANS.span("search.segment", width=width,
+                                    dispatches=0) as seg:
+                        WATCHDOG.beat()  # step build may compile below
+                        k = launch_steps_for(vw, target_chunks, tbc,
+                                             launch_candidates)
+                        if vw == 0:
+                            step0 = cached_search_step(
+                                nonce, 0, difficulty, tb_lo, tbc, 1,
+                                model.name, extra, 1,
                             )
-                            chunks_each = target_chunks
-                            chunks_per_step = target_chunks * k
-                    chunk0 = lo
-                    first_launch = True
-                    while chunk0 < hi:
-                        seg_chunks = min(chunks_per_step, hi - chunk0)
-                        WATCHDOG.beat()
-                        if cancel_check is not None and cancel_check():
-                            stop.set()
+                            step, chunks_per_step, chunks_each = \
+                                None, 1, 1
+                        else:
+                            plan = (step_builder(vw, extra, target_chunks, k)
+                                    if step_builder is not None else None)
+                            if plan is not None:
+                                step, chunks_each, chunks_per_step = plan
+                            else:
+                                step = cached_persistent_step(
+                                    nonce, vw, difficulty, tb_lo, tbc,
+                                    target_chunks, model.name, extra, k,
+                                )
+                                chunks_each = target_chunks
+                                chunks_per_step = target_chunks * k
+                        chunk0 = lo
+                        first_launch = True
+                        while chunk0 < hi:
+                            seg_chunks = min(chunks_per_step, hi - chunk0)
+                            WATCHDOG.beat()
+                            if cancel_check is not None and cancel_check():
+                                stop.set()
+                                flush_inflight_counts()
+                                metrics.inc("search.cancelled")
+                                return None
+                            if max_hashes is not None and hashes >= max_hashes:
+                                found, cancelled = drain_all()
+                                # drain_all stops at the first hit/cancel:
+                                # dispatches still in flight behind it must
+                                # count like every other exit path
+                                flush_inflight_counts()
+                                if cancelled:
+                                    metrics.inc("search.cancelled")
+                                    return None
+                                if found is not None:
+                                    metrics.inc("search.found")
+                                return found
+                            c = chunk0 & 0xFFFFFFFF
+                            if first_launch:
+                                first_launch = False
+                                # first dispatch of a segment may compile
+                                # (same grace rationale as the serial
+                                # driver's cold-layout launch)
+                                with WATCHDOG.grace(FIRST_COMPILE_GRACE_S):
+                                    res = step0(c) if vw == 0 else \
+                                        step(c, stop.operand())
+                            else:
+                                res = step0(c) if vw == 0 else \
+                                    step(c, stop.operand())
+                            metrics.inc("search.launches")
+                            dispatches += 1
+                            seg.annotate(dispatches=dispatches)
+                            inflight.append((res, chunk0, vw, extra,
+                                             seg_chunks, chunks_each,
+                                             vw != 0))
+                            chunk0 += chunks_per_step
+                            if len(inflight) >= pipeline_depth:
+                                found, cancelled = drain_one()
+                                if cancelled:
+                                    flush_inflight_counts()
+                                    metrics.inc("search.cancelled")
+                                    return None
+                                if found is not None:
+                                    flush_inflight_counts()
+                                    metrics.inc("search.found")
+                                    return found
+                        found, cancelled = drain_all()
+                        if cancelled:
                             flush_inflight_counts()
                             metrics.inc("search.cancelled")
                             return None
-                        if max_hashes is not None and hashes >= max_hashes:
-                            found, cancelled = drain_all()
-                            # drain_all stops at the first hit/cancel:
-                            # dispatches still in flight behind it must
-                            # count like every other exit path
+                        if found is not None:
                             flush_inflight_counts()
-                            if cancelled:
-                                metrics.inc("search.cancelled")
-                                return None
-                            if found is not None:
-                                metrics.inc("search.found")
+                            metrics.inc("search.found")
                             return found
-                        c = chunk0 & 0xFFFFFFFF
-                        if first_launch:
-                            first_launch = False
-                            # first dispatch of a segment may compile
-                            # (same grace rationale as the serial
-                            # driver's cold-layout launch)
-                            with WATCHDOG.grace(FIRST_COMPILE_GRACE_S):
-                                res = step0(c) if vw == 0 else \
-                                    step(c, stop.operand())
-                        else:
-                            res = step0(c) if vw == 0 else \
-                                step(c, stop.operand())
-                        metrics.inc("search.launches")
-                        inflight.append((res, chunk0, vw, extra,
-                                         seg_chunks, chunks_each,
-                                         vw != 0))
-                        chunk0 += chunks_per_step
-                        if len(inflight) >= pipeline_depth:
-                            found, cancelled = drain_one()
-                            if cancelled:
-                                flush_inflight_counts()
-                                metrics.inc("search.cancelled")
-                                return None
-                            if found is not None:
-                                flush_inflight_counts()
-                                metrics.inc("search.found")
-                                return found
-                    found, cancelled = drain_all()
-                    if cancelled:
-                        flush_inflight_counts()
-                        metrics.inc("search.cancelled")
-                        return None
-                    if found is not None:
-                        flush_inflight_counts()
-                        metrics.inc("search.found")
-                        return found
         return None
     finally:
         _RATE_METER.exit()

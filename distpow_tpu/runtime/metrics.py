@@ -175,11 +175,6 @@ Histogram names in use (same machine check, ``KNOWN_HISTOGRAMS`` /
 * ``repl.handoff_s`` — wall time of one warm shard handoff (all
   targets, chunked sends, deadline-bounded)
 * ``worker.time_to_cancel_s`` — Mine receipt to honored cancellation
-* ``search.launch_s``  — time blocked fetching one launch's result
-  (the serial driver's FIFO drain; parallel/search.py)
-* ``search.poll_s``    — time spent POLLING a launch to readiness in
-  the persistent driver's drain (the host stays responsive — cancel
-  checks run between polls; docs/SERVING.md)
 * ``powlib.mine_s``    — client-observed mine round-trip incl. retries
 * ``sched.batch_occupancy`` — real (non-padding) slots per batched
   launch: the continuous-batching win is this distribution's mean
@@ -287,7 +282,6 @@ KNOWN_HISTOGRAMS = frozenset({
     "coord.mine_s.hit", "coord.mine_s.miss",
     "coord.first_result_s", "coord.cancel_propagation_s",
     "worker.solve_s", "worker.time_to_cancel_s",
-    "search.launch_s", "search.poll_s",
     "powlib.mine_s",
     "sched.batch_occupancy", "sched.slot_wait_s",
     "rpc.frame.sent_bytes", "rpc.frame.recv_bytes",

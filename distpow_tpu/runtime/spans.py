@@ -36,6 +36,18 @@ Mechanics:
 * Fleet-scoped events with no request in scope (a lease expiry) record
   under ``trace_id=0`` — visible in the ring and in dumps, never in a
   per-trace fetch.
+* Profiler bridge: while a ``jax.profiler`` session collects, a span
+  opened with :meth:`SpanRecorder.span` also opens a
+  ``jax.profiler.TraceAnnotation`` named ``distpow.<span name>`` on the
+  opening thread, closed when the span finishes (its attrs ride along
+  as the event's stats).  The program's spans then sit on the device
+  trace's clock, beside the device operations they wait on.  With no
+  session collecting the bridge costs one check per span; with
+  ``DISTPOW_SPANS=0`` it does nothing.  A mark cannot be back-dated, so
+  :meth:`SpanRecorder.record`/:meth:`SpanRecorder.event` stay
+  ring-only, and so do :meth:`SpanRecorder.begin` spans: a profiler
+  mark belongs to the thread that opened it, and those spans finish on
+  another thread (``sched.slot``).
 
 Export: every node answers the ``Node.Spans`` RPC (runtime/rpc.py
 ``StatsOnly``) with its ring's spans for a trace id, or summaries of
@@ -49,12 +61,13 @@ Span-name vocabulary (kept small and documented — docs/FORENSICS.md):
 ``coord.first_result``, ``coord.cancel_storm``, ``coord.reassign``,
 ``fleet.hedge``, ``fleet.lease_expiry``, ``worker.solve``,
 ``worker.result_forward``, ``sched.slot``, ``search.launch``,
-``search.poll``.
+``search.poll``, ``search.segment``.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -68,7 +81,23 @@ DEFAULT_CAPACITY = 4096
 #: trace summaries and slowest-trace ranking prefer these durations.
 ROOT_SPANS = ("coord.mine", "powlib.mine")
 
+#: prefix of the profiler marks the bridge opens (module docstring)
+MARK_PREFIX = "distpow."
+
 _tls = threading.local()
+
+
+def _open_mark(name: str):
+    """An entered ``TraceAnnotation`` for span ``name`` while a profiler
+    session collects, else None.  No session can collect before JAX's
+    profiler module is loaded, so a process that never imports JAX
+    never imports it here either."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None or not prof.TraceAnnotation.is_enabled():
+        return None
+    mark = prof.TraceAnnotation(MARK_PREFIX + name)
+    mark.__enter__()
+    return mark
 
 
 class _NullSpan:
@@ -83,6 +112,9 @@ class _NullSpan:
     def finish(self, **attrs) -> None:
         pass
 
+    def discard(self) -> None:
+        pass
+
     def __enter__(self) -> "_NullSpan":
         return self
 
@@ -90,20 +122,22 @@ class _NullSpan:
         pass
 
 
-_NULL = _NullSpan()
+NULL_SPAN = _NullSpan()
 
 
 class SpanHandle:
     """One open span.  ``finish()`` records it exactly once; the
     context-manager form finishes at block exit (and tags an
     ``outcome`` on exceptions so an error path is visible in the
-    timeline, not just absent)."""
+    timeline, not just absent).  ``discard()`` ends it without a ring
+    record, for an exit the span does not describe.  ``mark`` opens the
+    profiler bridge's annotation (module docstring)."""
 
     __slots__ = ("_rec", "trace_id", "name", "node", "attrs", "ts",
-                 "_t0", "_done")
+                 "_t0", "_done", "_mark")
 
     def __init__(self, rec: "SpanRecorder", trace_id: int, name: str,
-                 node: str, attrs: dict):
+                 node: str, attrs: dict, mark: bool = False):
         self._rec = rec
         self.trace_id = trace_id
         self.name = name
@@ -112,6 +146,7 @@ class SpanHandle:
         self.ts = time.time()
         self._t0 = time.monotonic()
         self._done = False
+        self._mark = _open_mark(name) if mark else None
 
     def annotate(self, **attrs) -> None:
         self.attrs.update(attrs)
@@ -119,11 +154,26 @@ class SpanHandle:
     def finish(self, **attrs) -> None:
         if self._done:
             return
-        self._done = True
+        dur_s = time.monotonic() - self._t0
         if attrs:
             self.attrs.update(attrs)
+        self._close()
         self._rec._append(self.trace_id, self.name, self.node, self.ts,
-                          time.monotonic() - self._t0, self.attrs)
+                          dur_s, self.attrs)
+
+    def discard(self) -> None:
+        """End the span without recording it (idempotent, and a no-op
+        after ``finish``)."""
+        if not self._done:
+            self._close()
+
+    def _close(self) -> None:
+        self._done = True
+        mark, self._mark = self._mark, None
+        if mark is not None:
+            mark.set_metadata(**{k: v for k, v in self.attrs.items()
+                                 if isinstance(v, (int, float, str))})
+            mark.__exit__(None, None, None)
 
     def __enter__(self) -> "SpanHandle":
         return self
@@ -205,11 +255,13 @@ class SpanRecorder:
 
     def span(self, name: str, trace_id: Optional[int] = None,
              node: Optional[str] = None, **attrs):
-        """The sanctioned begin-site form: ``with SPANS.span(...)``."""
+        """The sanctioned begin-site form: ``with SPANS.span(...)``, or
+        a handle finished (or discarded) by the thread that opened it;
+        it opens the profiler mark too (module docstring)."""
         if not self._enabled:
-            return _NULL
+            return NULL_SPAN
         tid, nd = self._resolve(trace_id, node)
-        return SpanHandle(self, tid, name, nd, attrs)
+        return SpanHandle(self, tid, name, nd, attrs, mark=True)
 
     def begin(self, name: str, trace_id: Optional[int] = None,
               node: Optional[str] = None, **attrs):
@@ -217,9 +269,9 @@ class SpanRecorder:
         work crossing a thread boundary.  Lint-gated (``unclosed-span``,
         docs/LINT.md): every call site must justify where the single
         finish point is, because a leaked handle is a span that never
-        happened."""
+        happened.  Ring-only: it opens no profiler mark."""
         if not self._enabled:
-            return _NULL
+            return NULL_SPAN
         tid, nd = self._resolve(trace_id, node)
         return SpanHandle(self, tid, name, nd, attrs)
 

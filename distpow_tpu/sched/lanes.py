@@ -150,7 +150,6 @@ def build_pallas_group_step(gdef: tuple, batch: int,
     that does not align to the kernel's pow2 tile grid as judged by
     ``plan_launch_geometry`` — the same planner the pallas backend
     uses).  The raise IS the demotion signal."""
-    import jax
     import jax.numpy as jnp
 
     from ..backends.pallas_backend import plan_launch_geometry
@@ -162,6 +161,7 @@ def build_pallas_group_step(gdef: tuple, batch: int,
         _dyn_pallas_step,
         default_geometry,
     )
+    from ..ops.search_step import named_jit
 
     model_name, n_blocks, tb_loc, chunk_locs, n_pad = gdef
     if model_name not in MODEL_GEOMETRY:
@@ -211,7 +211,6 @@ def build_pallas_group_step(gdef: tuple, batch: int,
         model.digest_words, model_name,
     )
 
-    @jax.jit
     def step(init, base, masks, tb_lo, log_tbc, chunk0):
         outs = [
             kernel(
@@ -222,7 +221,8 @@ def build_pallas_group_step(gdef: tuple, batch: int,
         ]
         return jnp.stack(outs)
 
-    return _PallasGroupStep(step, batch)
+    return _PallasGroupStep(named_jit(step, f"{model_name}_pallas_slot_step"),
+                            batch)
 
 
 class LanePlanner:
