@@ -160,17 +160,17 @@ class JaxBackend:
         """StepFactory-shaped builder over the persistent step, so the
         shared ``_warm_layouts`` derivation (same target/k/mask-bucket
         keys as serving) warms the persistent programs too.  The warmup
-        dispatch carries a SET stop flag: the on-device loop exits at
-        its first condition check, so warming compiles the real program
-        at near-zero device cost."""
-        import jax.numpy as jnp
+        dispatch is chained behind ``CHAIN_HALT``: the on-device loop
+        exits at its first condition check, so warming compiles the
+        real program at near-zero device cost."""
+        import numpy as np
 
         from ..ops.search_step import (
+            CHAIN_HALT,
             cached_persistent_step,
             cached_search_step,
         )
 
-        stop_set = jnp.uint32(1)
         model_name = self.model.name
 
         def factory(vw, extra, target_chunks, launch_steps=1):
@@ -183,7 +183,7 @@ class JaxBackend:
                 nonce, vw, difficulty, 0, tbc, target_chunks, model_name,
                 extra, launch_steps,
             )
-            return (lambda chunk0: bound(chunk0, stop_set)[0]), \
+            return (lambda chunk0: np.asarray(bound(chunk0, CHAIN_HALT))[0]), \
                 target_chunks * launch_steps
 
         return factory

@@ -35,12 +35,49 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models.registry import HashModel, get_hash_model
 from .difficulty import meets_difficulty, nibble_masks
 from .packing import TailSpec, build_tail_spec, make_words
 
 SENTINEL = 0xFFFFFFFF
+# Element 0 of a persistent dispatch that did not run because its
+# predecessor hit or halted.  Flat indices stay below 2^31
+# (``_check_launch``), so neither marker can be a hit.
+HALT = 0xFFFFFFFE
+
+# The two constant chain operands of a persistent step, in host form
+# (see ``persistent_search_step``): CLEAR reads as a predecessor that
+# ran and found nothing, so the dispatch searches; HALT makes it exit
+# at its first loop check.  Bound steps take these objects themselves
+# and swap in device copies made once (``chain_operands``).
+# distpow: ok hot-path-host-sync -- host constants built once at import
+# from Python ints; no device value is read
+CHAIN_CLEAR = np.array([SENTINEL, 0], np.uint32)
+# distpow: ok hot-path-host-sync -- as above
+CHAIN_HALT = np.array([HALT, 0], np.uint32)
+
+
+@functools.lru_cache(maxsize=None)
+def chain_operands(sharding=None):
+    """Device copies of ``(CHAIN_CLEAR, CHAIN_HALT)``, placed the way a
+    persistent step's own results are: uncommitted on the default
+    device when ``sharding`` is None, else under ``sharding`` (the mesh
+    step's replicated results).  A chain operand placed otherwise than
+    the results it stands in for would compile a second program."""
+    return tuple(jax.device_put(c, sharding) for c in (CHAIN_CLEAR, CHAIN_HALT))
+
+
+def chain_operand(prev, sharding=None):
+    """The device operand a bound persistent step passes for ``prev``:
+    a predecessor's result as it is, a host constant as its device
+    copy."""
+    if prev is CHAIN_CLEAR:
+        return chain_operands(sharding)[0]
+    if prev is CHAIN_HALT:
+        return chain_operands(sharding)[1]
+    return prev
 
 
 def named_jit(fn: Callable, name: str) -> Callable:
@@ -391,20 +428,27 @@ def persistent_search_step(
     flag/result buffer through a ``while_loop``: each iteration
     evaluates one ``batch``-candidate sub-batch, folds its first hit
     into the carried best index, and the loop EXITS as soon as the
-    carry holds a hit (or the host-writable ``stop`` operand is
-    nonzero).  One dispatch therefore covers up to ``segments``
-    sub-batches of device work with zero host round trips between them,
-    a hit surfaces without paying for the launch's remaining segments,
-    and a dispatch issued after the host flips its stop flag costs one
-    loop-condition check.
+    carry holds a hit.  One dispatch therefore covers up to
+    ``segments`` sub-batches of device work with zero host round trips
+    between them, and a hit surfaces without paying for the launch's
+    remaining segments.
+
+    Chained halt: the last operand ``prev`` is the previous dispatch's
+    own ``uint32[2]`` result (or ``CHAIN_CLEAR`` / ``CHAIN_HALT``).
+    The loop runs only while ``prev[0] == SENTINEL``, i.e. the
+    predecessor ran and found nothing; otherwise the dispatch exits at
+    its first loop check and returns ``[HALT, 0]``, which halts its own
+    successor in turn.  So once one dispatch of a solve hits, every
+    dispatch queued behind it on the device costs one loop check,
+    without the host having to learn of the hit first.
 
     Signature of the returned jitted fn (all uint32):
     ``(init[S], base[n_blocks, W], masks[D], tb_lo, log_tbc_or_nothing,
-    chunk0, stop) -> uint32[2]`` — ``[0]`` is the first-hit flat index
-    over the full ``segments * batch`` span (reference enumeration
+    chunk0, prev[2]) -> uint32[2]`` — ``[0]`` is the first-hit flat
+    index over the full ``segments * batch`` span (reference enumeration
     order; segments scan in order and each segment folds its own
-    minimum) or SENTINEL, ``[1]`` is the number of segments actually
-    executed (the driver's evaluated-work accounting, and the
+    minimum), SENTINEL, or HALT; ``[1]`` is the number of segments
+    actually executed (the driver's evaluated-work accounting, and the
     ``search.persistent_steps`` instrument).
     """
     model = get_hash_model(model_name)
@@ -425,38 +469,50 @@ def persistent_search_step(
         return jnp.min(jnp.where(hit, f, jnp.uint32(SENTINEL)))
 
     def make_step(take_log_tbc: bool):
-        def step(init, base, masks, tb_lo, log_tbc, chunk0, stop):
+        def step(init, base, masks, tb_lo, log_tbc, chunk0, prev):
             f0 = jnp.arange(batch, dtype=jnp.uint32)
 
-            def cond(state):
-                seg, best = state
-                return (
-                    (seg < jnp.uint32(segments))
-                    & (best == jnp.uint32(SENTINEL))
-                    & (stop == jnp.uint32(0))
-                )
-
-            def body(state):
-                seg, best = state
+            def search_segment(seg):
                 f = seg * jnp.uint32(batch) + f0
-                found = sub(init, base, masks, tb_lo, log_tbc, chunk0, f)
-                return seg + jnp.uint32(1), jnp.minimum(best, found)
+                return sub(init, base, masks, tb_lo, log_tbc, chunk0, f)
 
-            seg, best = jax.lax.while_loop(
-                cond, body, (jnp.uint32(0), jnp.uint32(SENTINEL))
-            )
-            return jnp.stack([best, seg])
+            return chained_segments(prev, segments, search_segment)
 
         if take_log_tbc:
             return step
 
-        def step_static(init, base, masks, tb_lo, chunk0, stop):
-            return step(init, base, masks, tb_lo, jnp.uint32(0), chunk0, stop)
+        def step_static(init, base, masks, tb_lo, chunk0, prev):
+            return step(init, base, masks, tb_lo, jnp.uint32(0), chunk0, prev)
 
         return step_static
 
     return named_jit(make_step(static_tbc is None),
                      f"{model_name}_persistent_step")
+
+
+def chained_segments(prev, segments: int, search_segment):
+    """The loop of the persistent steps, single-device and mesh alike.
+
+    Runs ``search_segment(seg) -> first-hit flat index or SENTINEL``
+    for ``seg = 0, 1, ...`` until one hits or ``segments`` have run,
+    provided ``prev[0] == SENTINEL`` (see ``persistent_search_step``).
+    Returns ``uint32[2]``: the first hit, SENTINEL or HALT, then the
+    segments run."""
+    go = prev[0] == jnp.uint32(SENTINEL)
+
+    def cond(state):
+        seg, best = state
+        return go & (seg < jnp.uint32(segments)) & \
+            (best == jnp.uint32(SENTINEL))
+
+    def body(state):
+        seg, best = state
+        return seg + jnp.uint32(1), jnp.minimum(best, search_segment(seg))
+
+    seg, best = jax.lax.while_loop(
+        cond, body, (jnp.uint32(0), jnp.uint32(SENTINEL))
+    )
+    return jnp.stack([jnp.where(go, best, jnp.uint32(HALT)), seg])
 
 
 @functools.lru_cache(maxsize=512)
@@ -473,11 +529,12 @@ def cached_persistent_step(
 ):
     """Serving-path persistent step: binds request operands onto the
     layout-keyed multi-segment program, exactly as ``cached_search_step``
-    binds the relaunch-loop program.  Returns ``bound(chunk0, stop) ->
+    binds the relaunch-loop program.  Returns ``bound(chunk0, prev) ->
     uint32[2]`` covering up to ``segments * chunks_per_step * tb_count``
-    candidates per dispatch (early-exit on hit or stop).  Width 0 has a
-    single 256-candidate probe and no chunk axis — the driver serves it
-    through ``cached_search_step`` instead.
+    candidates per dispatch (early exit on a hit; no work behind a
+    ``prev`` that hit or halted, or behind ``CHAIN_HALT``).  Width 0 has
+    a single 256-candidate probe and no chunk axis — the driver serves
+    it through ``cached_search_step`` instead.
     """
     if width == 0:
         raise ValueError(
@@ -497,13 +554,15 @@ def cached_persistent_step(
     if pow2:
         log_tbc = jnp.uint32(tb_count.bit_length() - 1)
 
-        def bound(chunk0, stop):
-            return dyn(init, base, masks, tb_lo_op, log_tbc, chunk0, stop)
+        def bound(chunk0, prev):
+            return dyn(init, base, masks, tb_lo_op, log_tbc, chunk0,
+                       chain_operand(prev))
 
     else:
 
-        def bound(chunk0, stop):
-            return dyn(init, base, masks, tb_lo_op, chunk0, stop)
+        def bound(chunk0, prev):
+            return dyn(init, base, masks, tb_lo_op, chunk0,
+                       chain_operand(prev))
 
     return bound
 

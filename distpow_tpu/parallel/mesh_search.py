@@ -43,6 +43,8 @@ from ..ops.search_step import (
     _check_launch,
     _eval_candidates,
     cached_search_step,
+    chain_operand,
+    chained_segments,
     eval_dyn_candidates,
     fold_dyn_masks,
     mask_words_for,
@@ -550,7 +552,8 @@ def mesh_persistent_step(
 
     Mirrors ``ops.search_step.persistent_search_step`` with
     ``batch = batch_local * n_dev``: the same multi-segment on-device
-    ``while_loop`` with early exit on hit or host stop flag, but each
+    loop (``chained_segments``) with early exit on a hit and the same
+    chained halt behind a predecessor that hit or halted, but each
     segment's candidate sub-batch is split across the mesh (device ``d``
     owns flat ``[d * batch_local, (d+1) * batch_local)`` within the
     segment) and a per-segment ``lax.pmin`` folds the device minima into
@@ -558,8 +561,9 @@ def mesh_persistent_step(
     same segment boundary and exits together, the module-docstring
     "first result wins, everyone stops" protocol applied inside one
     dispatch.  Returned fn signature matches the single-device step:
-    ``(init, base, masks, tb_lo, [log_tbc,] chunk0, stop) -> uint32[2]``
-    (first-hit global flat index + segments executed).
+    ``(init, base, masks, tb_lo, [log_tbc,] chunk0, prev[2]) ->
+    uint32[2]`` (first-hit global flat index, SENTINEL or HALT, then
+    segments executed).
     """
     model = get_hash_model(model_name)
     n_dev = int(mesh.devices.size)
@@ -569,22 +573,13 @@ def mesh_persistent_step(
     mw = mask_words or model.digest_words
 
     def make_step(take_log_tbc: bool):
-        def step(init, base, masks, tb_lo, log_tbc, chunk0, stop):
+        def step(init, base, masks, tb_lo, log_tbc, chunk0, prev):
             d = jax.lax.axis_index(axis).astype(jnp.uint32)
             f0 = d * jnp.uint32(batch_local) + jnp.arange(
                 batch_local, dtype=jnp.uint32
             )
 
-            def cond(state):
-                seg, best = state
-                return (
-                    (seg < jnp.uint32(segments))
-                    & (best == jnp.uint32(SENTINEL))
-                    & (stop == jnp.uint32(0))
-                )
-
-            def seg_body(state):
-                seg, best = state
+            def search_segment(seg):
                 f = seg * jnp.uint32(batch_global) + f0
                 if static_tbc is None:
                     chunk = jnp.uint32(chunk0) + (f >> log_tbc)
@@ -598,20 +593,16 @@ def mesh_persistent_step(
                 )
                 hit = fold_dyn_masks(model, state_w, masks, mw)
                 found = jnp.min(jnp.where(hit, f, jnp.uint32(SENTINEL)))
-                found = jax.lax.pmin(found, axis)
-                return seg + one, jnp.minimum(best, found)
+                return jax.lax.pmin(found, axis)
 
-            seg, best = jax.lax.while_loop(
-                cond, seg_body, (jnp.uint32(0), jnp.uint32(SENTINEL))
-            )
-            return jnp.stack([best, seg])
+            return chained_segments(prev, segments, search_segment)
 
         if take_log_tbc:
             return step
 
-        def step_static(init, base, masks, tb_lo, chunk0, stop):
+        def step_static(init, base, masks, tb_lo, chunk0, prev):
             return step(init, base, masks, tb_lo, jnp.uint32(0), chunk0,
-                        stop)
+                        prev)
 
         return step_static
 
@@ -640,7 +631,7 @@ def mesh_persistent_factory(
     route's mesh lane).
 
     Returns ``builder(vw, extra, target_chunks, segments) ->
-    (bound(chunk0, stop), chunks_each, chunks_per_step)`` with the
+    (bound(chunk0, prev), chunks_each, chunks_per_step)`` with the
     driver's exact accounting contract: each dispatch covers up to
     ``segments`` on-device segments of ``target_chunks`` GLOBAL chunks
     each.  Raises ValueError (from the builder) when the global segment
@@ -649,8 +640,11 @@ def mesh_persistent_factory(
     pallas mesh factory.
 
     Bound operands are pre-placed replicated on the mesh at bind time
-    (``jax.device_put`` with a replicated ``NamedSharding``), so steady-
-    state dispatches move only the chunk cursor and stop flag.
+    (``jax.device_put`` with a replicated ``NamedSharding``), and so are
+    the constant chain operands (``chain_operand``), placed like the
+    step's own replicated results: steady-state dispatches move only
+    the chunk cursor, and the first dispatch of a solve compiles
+    nothing its successors did not.
     """
     from .compat import NamedSharding
 
@@ -686,14 +680,15 @@ def mesh_persistent_factory(
             log_tbc = jax.device_put(
                 jnp.uint32(tbc.bit_length() - 1), repl)
 
-            def bound(chunk0, stop):
+            def bound(chunk0, prev):
                 return dyn(init, base, masks, tb_lo_op, log_tbc, chunk0,
-                           stop)
+                           chain_operand(prev, repl))
 
         else:
 
-            def bound(chunk0, stop):
-                return dyn(init, base, masks, tb_lo_op, chunk0, stop)
+            def bound(chunk0, prev):
+                return dyn(init, base, masks, tb_lo_op, chunk0,
+                           chain_operand(prev, repl))
 
         return bound, target_chunks, target_chunks * segments
 

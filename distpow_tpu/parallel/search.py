@@ -40,9 +40,13 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Callable, Optional, Sequence, Tuple
 
+import numpy as np
+
 from ..models import puzzle
 from ..models.registry import HashModel, get_hash_model
 from ..ops.search_step import (
+    CHAIN_CLEAR,
+    CHAIN_HALT,
     SENTINEL,
     cached_persistent_step,
     cached_search_step,
@@ -471,47 +475,44 @@ DEFAULT_POLL_INTERVAL_S = 0.001
 
 
 class StopFlag:
-    """Host-writable device stop flag for the persistent loop
-    (docs/SERVING.md flag protocol).
+    """The chain operand of one solve's persistent dispatches
+    (docs/SERVING.md chained halt).
 
-    The flag is a one-element uint32 device buffer passed to every
-    persistent dispatch; the on-device while_loop reads it in its loop
-    condition, so a dispatch carrying a set flag exits after one
-    condition check instead of burning its full segment budget.  The
-    host "writes" it by replacing the buffer (``set()`` updates the
-    operand the NEXT dispatches bind — JAX buffers are immutable, so
-    already-enqueued dispatches still run their remaining segments).
-    Two call sites exercise the SET form today: backend warmup, which
-    compiles the persistent programs against a set flag so compilation
-    costs near-zero device work, and any dispatch a driver issues
-    after observing a cancel — the solo driver never issues one (it
-    stops dispatching the moment it observes the cancel), so there the
-    flag is the invariant guard, not the cancel mechanism: cancel
-    latency is bounded by stop-on-observe plus the ≤ ``pipeline_depth``
-    already-in-flight dispatches running out in the background (each
-    still early-exits on its own hit).  The buffer is created lazily
-    and reused across dispatches, so the steady-state cost is zero
-    transfers.
+    Every persistent dispatch takes, as its last operand, the result of
+    the persistent dispatch issued before it, and its on-device loop
+    runs only behind a predecessor that ran and found nothing
+    (``ops/search_step.py persistent_search_step``).  So the device,
+    not the host, ends a solve: a dispatch queued behind a hit exits at
+    its first loop check and passes the halt on, and the host can keep
+    dispatches in flight across width boundaries without searching past
+    the answer.  ``operand()`` is what the next dispatch binds:
+    ``CHAIN_CLEAR`` before the solve's first persistent dispatch, then
+    the last one's result (``chain``), or ``CHAIN_HALT`` for good once
+    ``set()``.  The solo driver sets it on a cancel and issues nothing
+    after, so there it is the invariant guard; dispatches already
+    queued run out in the background, each still stopping at its own
+    hit.  Warmup and the mesh lane's compile probe dispatch behind
+    ``CHAIN_HALT`` directly, so compiling costs near-zero device work.
     """
 
-    __slots__ = ("_operand", "_set")
+    __slots__ = ("_operand",)
 
     def __init__(self) -> None:
-        self._operand = None
-        self._set = False
+        self._operand = CHAIN_CLEAR
+
+    def chain(self, res) -> None:
+        """Make ``res``, the dispatch just issued, the next one's
+        predecessor."""
+        if self._operand is not CHAIN_HALT:
+            self._operand = res
 
     def set(self) -> None:
-        self._set = True
-        self._operand = None  # rebuilt hot with the new value
+        self._operand = CHAIN_HALT
 
     def is_set(self) -> bool:
-        return self._set
+        return self._operand is CHAIN_HALT
 
     def operand(self):
-        if self._operand is None:
-            import jax.numpy as jnp
-
-            self._operand = jnp.uint32(1 if self._set else 0)
         return self._operand
 
 
@@ -534,32 +535,43 @@ def persistent_search(
     first-hit enumeration order, byte-identical results (the golden
     parity suite, tests/test_serving_loop.py, asserts it).
 
-    Three differences from the relaunch loop, all on the host side of
-    the dispatch boundary (docs/SERVING.md):
+    Four differences from the relaunch loop (docs/SERVING.md):
 
     * each dispatch is a multi-segment on-device loop
       (``cached_persistent_step``) that early-exits on the first hit,
       so the per-dispatch candidate budget no longer trades hit
       latency against round-trip amortization;
+    * the dispatches of a solve form one chain (:class:`StopFlag`):
+      each takes its predecessor's result, and one behind a hit does
+      no work.  One FIFO therefore runs through the whole solve, with
+      no drain at width boundaries: the next width's first dispatch
+      chains from the last one issued, and FIFO order still returns
+      the reference-order first hit, because nothing behind a hit
+      searches;
     * the drain POLLS the in-flight head's readiness
       (``jax.Array.is_ready`` — a cheap flag query, not a result
-      fetch) and only converts once ready, so the host never blocks
-      inside a result fetch (``search.blocking_syncs`` stays flat;
-      the waiting time is observable as ``search.poll`` spans);
-    * cancellation stops issuing dispatches the moment it is observed
-      (and flips the :class:`StopFlag` future dispatches would carry —
-      see its docstring for what actually exercises the set form):
+      fetch) and only then reads it, whole, in one host transfer, so
+      the host never blocks inside a result fetch and never queues an
+      indexing program behind the next dispatch
+      (``search.blocking_syncs`` stays flat; the waiting time is
+      observable as ``search.poll`` spans);
+    * cancellation stops issuing dispatches the moment it is observed:
       the host returns immediately, and the abandoned device work is
       bounded at the in-flight window (≤ ``pipeline_depth`` dispatches
       running out their segment budget in the background) without
       shrinking launches.
+
+    The width-0 probe (256 candidates, ``cached_search_step``) stays
+    outside the chain: its result is a scalar.  With the default depth
+    of 2 it is drained before a second persistent dispatch is issued,
+    so at most one width-1 dispatch runs behind a probe hit.
 
     ``step_builder`` is the launch-lane hook (sched/lanes.py
     ``persistent_step_builder``): called per width segment as
     ``step_builder(vw, extra, target_chunks, k)`` it may return
     ``(step, chunks_each, chunks_per_step)`` — a drop-in for the
     default single-device persistent step with the identical
-    ``(chunk0, stop) -> uint32[2]`` contract and first-hit order over
+    ``(chunk0, prev) -> uint32[2]`` contract and first-hit order over
     the same global candidate span — or None to keep the default for
     that width.  The mesh lane serves every dispatch across all local
     devices this way; enumeration order (and so results) stays
@@ -574,31 +586,28 @@ def persistent_search(
         return _unsatisfiable_wait(model, difficulty, cancel_check,
                                    max_hashes)
     target_chunks = max(1, effective_batch(batch_size) // tbc)
-    stop = StopFlag()
+    chain = StopFlag()
 
     hashes = 0
-    # FIFO of in-flight dispatches:
-    # (res, chunk0, vw, extra, seg_chunks, chunks_each, is_pair)
+    # FIFO of in-flight dispatches over the whole solve:
+    # (res, chunk0, vw, extra, seg_chunks, chunks_each)
     # where seg_chunks is the dispatch's IN-SEGMENT chunk span (the
     # overscan clip the serial driver documents at its n_cand line) and
-    # chunks_each the chunk count of one on-device segment.
+    # chunks_each the chunk count of one on-device segment.  vw == 0 is
+    # the width-0 probe, outside the chain.
     inflight: deque = deque()
 
-    def _fetch_pair(res):
-        # the conversion site: only ever entered with res.is_ready()
-        # confirmed, so this does not serialize the pipeline
-        f = int(res[0])
-        segs = int(res[1])
-        return f, segs
+    def count(n_cand: int) -> None:
+        nonlocal hashes
+        hashes += n_cand
+        metrics.inc("search.hashes", n_cand)
 
     def drain_one() -> Tuple[Optional[SearchResult], bool]:
         """Poll the head to readiness, then convert.  Returns
         ``(found, cancelled)`` — polling honors cancel_check, so a
         cancel arriving mid-wait stops the search without blocking on
         the device."""
-        nonlocal hashes
-        res, chunk0, vw, extra, seg_chunks, chunks_each, is_pair = \
-            inflight.popleft()
+        res, chunk0, vw, extra, seg_chunks, chunks_each = inflight.popleft()
         # deliberately NO WATCHDOG.beat() inside the poll wait: a hung
         # device leaves is_ready() false forever, and beating here
         # would mask exactly the staleness the hang watchdog exists to
@@ -612,16 +621,19 @@ def persistent_search(
                 while not res.is_ready():
                     if cancel_check is not None and cancel_check():
                         wait.discard()
-                        stop.set()
                         # account the popped head like the rest of the
                         # flush: dispatched work the device completes
                         # either way
-                        hashes += seg_chunks * tbc
-                        metrics.inc("search.hashes", seg_chunks * tbc)
+                        count(seg_chunks * tbc)
                         return None, True
                     time.sleep(poll_interval_s)
-        if is_pair:
-            f, segs = _fetch_pair(res)
+        if vw:
+            # the whole ready pair in one host transfer: indexing the
+            # device array (res[0]) would run a slice program, queued
+            # behind the dispatch issued after this one
+            # distpow: ok hot-path-host-sync -- the drain's sanctioned
+            # conversion, entered only with res.is_ready() confirmed
+            f, segs = np.asarray(res).tolist()
             metrics.inc("search.persistent_steps", segs)
             n_cand = min(segs * chunks_each, seg_chunks) * tbc
         else:
@@ -630,8 +642,7 @@ def persistent_search(
             # conversion cannot block
             f = int(res)
             n_cand = seg_chunks * tbc
-        hashes += n_cand
-        metrics.inc("search.hashes", n_cand)
+        count(n_cand)
         _RATE_METER.note(n_cand)
         if f == SENTINEL:
             return None, False
@@ -646,17 +657,20 @@ def persistent_search(
             hashes_tried=hashes,
         ), False
 
-    def flush_inflight_counts() -> None:
-        # same accounting contract as the serial driver: dispatched
-        # work counts on every exit path without paying a fetch per
-        # launch (launches carrying a set stop flag exit early on
-        # device, so this is an upper bound there — documented in
-        # docs/SERVING.md)
-        nonlocal hashes
+    def flush_inflight_counts(behind_hit: bool = False) -> None:
+        """Account the dispatches still in flight at an early exit
+        without fetching them.  Behind a hit of a chained dispatch each
+        ran zero segments by construction: it counts nothing to
+        ``search.hashes`` and one to ``search.halted_dispatches``.  On
+        every other exit (cancel, budget, a probe hit) the device runs
+        them out: they count their dispatched span, an upper bound
+        (docs/SERVING.md)."""
         while inflight:
-            _res, _c0, _vw, _ex, seg_chunks, _ce, _p = inflight.popleft()
-            hashes += seg_chunks * tbc
-            metrics.inc("search.hashes", seg_chunks * tbc)
+            *_, seg_chunks, _ce = inflight.popleft()
+            if behind_hit:
+                metrics.inc("search.halted_dispatches")
+            else:
+                count(seg_chunks * tbc)
 
     def drain_all() -> Tuple[Optional[SearchResult], bool]:
         while inflight:
@@ -665,14 +679,28 @@ def persistent_search(
                 return found, cancelled
         return None, False
 
+    def finish(found: Optional[SearchResult], cancelled: bool):
+        if cancelled:
+            chain.set()
+            flush_inflight_counts()
+            metrics.inc("search.cancelled")
+            return None
+        # a hit at width 0 (empty chunk) came from the probe, which is
+        # outside the chain: what follows it was not halted
+        flush_inflight_counts(behind_hit=found is not None
+                              and bool(found.chunk))
+        if found is not None:
+            metrics.inc("search.found")
+        return found
+
     _RATE_METER.enter()
     try:
         with WATCHDOG.active():
             for width in range(0, max_width + 1):
                 for vw, lo, hi, extra in width_segments(width):
                     # one span per width segment: a device gap inside a
-                    # solve then names the segment whose drain or first
-                    # dispatch left it
+                    # solve then names the segment whose dispatch or
+                    # drain left it
                     dispatches = 0
                     with SPANS.span("search.segment", width=width,
                                     dispatches=0) as seg:
@@ -704,22 +732,9 @@ def persistent_search(
                             seg_chunks = min(chunks_per_step, hi - chunk0)
                             WATCHDOG.beat()
                             if cancel_check is not None and cancel_check():
-                                stop.set()
-                                flush_inflight_counts()
-                                metrics.inc("search.cancelled")
-                                return None
+                                return finish(None, True)
                             if max_hashes is not None and hashes >= max_hashes:
-                                found, cancelled = drain_all()
-                                # drain_all stops at the first hit/cancel:
-                                # dispatches still in flight behind it must
-                                # count like every other exit path
-                                flush_inflight_counts()
-                                if cancelled:
-                                    metrics.inc("search.cancelled")
-                                    return None
-                                if found is not None:
-                                    metrics.inc("search.found")
-                                return found
+                                return finish(*drain_all())
                             c = chunk0 & 0xFFFFFFFF
                             if first_launch:
                                 first_launch = False
@@ -728,36 +743,22 @@ def persistent_search(
                                 # driver's cold-layout launch)
                                 with WATCHDOG.grace(FIRST_COMPILE_GRACE_S):
                                     res = step0(c) if vw == 0 else \
-                                        step(c, stop.operand())
+                                        step(c, chain.operand())
                             else:
                                 res = step0(c) if vw == 0 else \
-                                    step(c, stop.operand())
+                                    step(c, chain.operand())
+                            if vw:
+                                chain.chain(res)
                             metrics.inc("search.launches")
                             dispatches += 1
                             seg.annotate(dispatches=dispatches)
                             inflight.append((res, chunk0, vw, extra,
-                                             seg_chunks, chunks_each,
-                                             vw != 0))
+                                             seg_chunks, chunks_each))
                             chunk0 += chunks_per_step
                             if len(inflight) >= pipeline_depth:
                                 found, cancelled = drain_one()
-                                if cancelled:
-                                    flush_inflight_counts()
-                                    metrics.inc("search.cancelled")
-                                    return None
-                                if found is not None:
-                                    flush_inflight_counts()
-                                    metrics.inc("search.found")
-                                    return found
-                        found, cancelled = drain_all()
-                        if cancelled:
-                            flush_inflight_counts()
-                            metrics.inc("search.cancelled")
-                            return None
-                        if found is not None:
-                            flush_inflight_counts()
-                            metrics.inc("search.found")
-                            return found
-        return None
+                                if found is not None or cancelled:
+                                    return finish(found, cancelled)
+            return finish(*drain_all())
     finally:
         _RATE_METER.exit()

@@ -25,6 +25,10 @@ declaration distpow-lint's ``metrics-registry`` rule verifies every
 * ``search.persistent_steps`` — on-device sub-batches (segments)
   executed inside persistent-loop dispatches (early-exit means this
   can be far below the dispatched segment budget)
+* ``search.halted_dispatches`` — persistent dispatches still in flight
+  behind a found answer, which the chained halt stopped on the device
+  before any segment ran (about one per found solve at the default
+  pipeline depth; docs/SERVING.md)
 * ``worker.mine_rpcs`` / ``worker.found_rpcs`` / ``worker.cancel_rpcs``
 * ``worker.results_sent``  — messages queued to the forwarder
 * ``worker.forward_retries`` — result deliveries retried after a
@@ -231,6 +235,7 @@ Number = Union[int, float]
 KNOWN_COUNTERS = frozenset({
     "search.hashes", "search.launches", "search.cancelled", "search.found",
     "search.blocking_syncs", "search.persistent_steps",
+    "search.halted_dispatches",
     "worker.mine_rpcs", "worker.found_rpcs", "worker.cancel_rpcs",
     "worker.results_sent", "worker.forward_retries",
     "coord.mine_rpcs", "coord.fanouts", "coord.late_results",

@@ -365,11 +365,13 @@ def persistent_step_builder(nonce: bytes, difficulty: int, tb_lo: int,
     Returns None when the single-device persistent step IS the plan
     (one device, or the override pins ``xla``); otherwise a builder
     whose per-width result is the mesh persistent step, compile-probed
-    at bind time with a SET stop flag (the warmup trick: the on-device
+    at bind time behind ``CHAIN_HALT`` (the warmup trick: the on-device
     loop exits at its first condition check, so probing compiles the
-    real program at near-zero device cost).  Any bind or probe failure
-    demotes the whole request to the single-device path — per-lane
-    compile-failure demotion, solo edition.
+    real program at near-zero device cost) with the operand types the
+    driver dispatches, so the probe's program is the served one.  Any
+    bind or probe failure demotes the whole request to the
+    single-device path — per-lane compile-failure demotion, solo
+    edition.
     """
     caps = caps or detect_caps()
     # the persistent route has exactly two lanes, mesh or the default
@@ -378,8 +380,8 @@ def persistent_step_builder(nonce: bytes, difficulty: int, tb_lo: int,
     if override not in ("auto", "mesh") or caps.n_devices <= 1:
         return None
     import jax
-    import jax.numpy as jnp
 
+    from ..ops.search_step import CHAIN_HALT
     from ..parallel.mesh_search import AXIS, make_mesh, \
         mesh_persistent_factory
 
@@ -396,9 +398,12 @@ def persistent_step_builder(nonce: bytes, difficulty: int, tb_lo: int,
             bound, chunks_each, chunks_per_step = factory(
                 vw, bytes(extra), target_chunks, segments
             )
-            # stop-set compile probe: surfaces compile failures here,
-            # where demotion is cheap, instead of mid-pipeline
-            int(bound(jnp.uint32(0), jnp.uint32(1))[1])
+            # halted compile probe: surfaces compile failures here,
+            # where demotion is cheap, instead of mid-pipeline.  chunk0
+            # is a Python int as the driver passes it, so the probe's
+            # program is the served one.  Not awaited: it would queue
+            # behind the solve's dispatches in flight and drain them
+            bound(0, CHAIN_HALT)
         except Exception as exc:
             metrics.inc("fallback.lane_demotions")
             demoted.append(True)

@@ -31,6 +31,9 @@ from distpow_tpu.models.registry import get_hash_model  # noqa: E402
 from distpow_tpu.ops.difficulty import nibble_masks  # noqa: E402
 from distpow_tpu.ops.packing import build_tail_spec  # noqa: E402
 from distpow_tpu.ops.search_step import (  # noqa: E402
+    CHAIN_CLEAR,
+    CHAIN_HALT,
+    HALT,
     cached_persistent_step,
     slot_search_step,
 )
@@ -192,14 +195,71 @@ def test_mesh_persistent_step_parity_nonpow2_partition():
         oracle = cached_persistent_step(nonce, vw, ntz, 0, tbc, chunks,
                                         "md5", b"", segs)
         for c0 in (0, 64, 1 << 12):
-            got = np.asarray(bound(jnp.uint32(c0), jnp.uint32(0)))
-            want = np.asarray(oracle(jnp.uint32(c0), jnp.uint32(0)))
+            got = np.asarray(bound(c0, CHAIN_CLEAR))
+            want = np.asarray(oracle(c0, CHAIN_CLEAR))
             np.testing.assert_array_equal(got, want,
                                           err_msg=f"tbc={tbc} c0={c0}")
     # indivisible global batch refuses cleanly (the demotion signal)
     f6 = mesh_persistent_factory(b"\x51\x52", 1, 0, 6, model, mesh, AXIS)
     with pytest.raises(ValueError, match="divide"):
         f6(2, b"", 1, 2)
+
+
+def test_mesh_persistent_chained_halt():
+    """On the 8-device mesh, as on one device: behind a dispatch that
+    hit, the next two of a chain run 0 segments and return HALT, and
+    CHAIN_HALT stops a dispatch at segment 0."""
+    import jax
+
+    model = get_hash_model("md5")
+    mesh = make_mesh(jax.devices())
+    factory = mesh_persistent_factory(b"\x51\x52", 2, 0, 256, model, mesh,
+                                      AXIS)
+    bound, _, _ = factory(1, b"", 8, 4)
+    first = bound(1, CHAIN_CLEAR)
+    second = bound(33, first)
+    third = bound(65, second)
+    f, segs = np.asarray(first).tolist()
+    assert f < 1 << 31 and segs >= 1
+    assert np.asarray(second).tolist() == np.asarray(third).tolist() \
+        == [HALT, 0]
+    assert np.asarray(bound(1, CHAIN_HALT)).tolist() == [HALT, 0]
+
+
+@pytest.mark.parametrize("nonce", [b"\x90\x00\x00", b"\x90\x0a\x00"],
+                         ids=["last-of-width-1", "first-of-width-2"])
+def test_persistent_search_mesh_builder_across_widths(nonce):
+    """persistent_search over the mesh lane, with hits in the last
+    dispatch of width 1 and the first of width 2: the oracle's secret,
+    one halted dispatch behind the hit, and a second solve of the same
+    layout compiles nothing (the chain's constants sit where the mesh
+    step's results do)."""
+    import jax
+
+    ntz, tbs = 4, list(range(256))
+    kw = dict(batch_size=1 << 10, launch_candidates=1 << 12)
+
+    def solve():
+        sb = persistent_step_builder(nonce, ntz, 0, 256,
+                                     get_hash_model("md5"))
+        return persistent_search(nonce, ntz, tbs, step_builder=sb, **kw)
+
+    solve()  # compiles each width's mesh program
+    compiles = []
+
+    def listen(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    halted = REGISTRY.get("search.halted_dispatches")
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        res = solve()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert res.secret == puzzle.python_search(nonce, ntz, tbs)
+    assert REGISTRY.get("search.halted_dispatches") - halted == 1
+    assert compiles == []
 
 
 def test_persistent_search_mesh_builder_matches_oracle():

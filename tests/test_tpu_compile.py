@@ -127,7 +127,8 @@ def test_persistent_xla_step_compiles_at_batch_2_20(one_chip):
                                   spec.chunk_locs, BATCH, None, segments, mw)
     init, base, masks = step_operands(spec, DIFFICULTY, model)
     u32 = jnp.uint32(0)
-    args = _shapes([init, base, masks, u32, u32, u32, u32], one_chip)
+    prev = jnp.zeros((2,), jnp.uint32)  # the chain operand
+    args = _shapes([init, base, masks, u32, u32, u32, prev], one_chip)
     compiled = step.lower(*args).compile()
     assert compiled.memory_analysis() is not None
 
