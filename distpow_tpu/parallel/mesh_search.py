@@ -69,6 +69,17 @@ def make_mesh(devices: Optional[Sequence] = None, axis: str = AXIS) -> Mesh:
     return Mesh(np.array(devs), (axis,))
 
 
+def _on_mesh(step, mesh: Mesh):
+    """Mark ``step`` as one whose every dispatch spans all the devices
+    of ``mesh``: the search drivers read ``mesh_devices`` to count its
+    candidates under ``search.mesh_hashes`` as well as
+    ``search.hashes``, and name the device count on the width's
+    ``search.segment`` span.  The width-0 probe and the single-device
+    steps carry no mark."""
+    step.mesh_devices = int(mesh.devices.size)
+    return step
+
+
 @functools.lru_cache(maxsize=None)
 def _dyn_mesh_step(
     mesh: Mesh,
@@ -331,7 +342,7 @@ def _pallas_mesh_step_factory(
         def step(chunk0):
             return dyn(init, base[0], masks, part, chunk0)
 
-        return step
+        return _on_mesh(step, mesh)
 
     def factory(vw: int, extra: bytes, target_chunks: int, launch_steps: int = 1):
         if vw == 0:
@@ -399,7 +410,7 @@ def _mesh_step_factory(
         def step(chunk0):
             return dyn(init, base, masks, part, chunk0)
 
-        return step
+        return _on_mesh(step, mesh)
 
     @functools.lru_cache(maxsize=32)
     def build_static(vw: int, extra: bytes, chunks_local: int):
@@ -452,7 +463,8 @@ def _mesh_step_factory(
                 return jax.lax.pmin(m, axis)
 
         sharded = _shard_map(body, mesh=mesh, in_specs=P(), out_specs=P())
-        return named_jit(sharded, f"{model.name}_static_mesh_step")
+        return _on_mesh(named_jit(sharded, f"{model.name}_static_mesh_step"),
+                        mesh)
 
     def factory(vw: int, extra: bytes, target_chunks: int, launch_steps: int = 1):
         if vw == 0:
@@ -690,7 +702,7 @@ def mesh_persistent_factory(
                 return dyn(init, base, masks, tb_lo_op, chunk0,
                            chain_operand(prev, repl))
 
-        return bound, target_chunks, target_chunks * segments
+        return _on_mesh(bound, mesh), target_chunks, target_chunks * segments
 
     return builder
 

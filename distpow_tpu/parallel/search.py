@@ -189,6 +189,13 @@ class _RateMeter:
 _RATE_METER = _RateMeter()
 
 
+def _register_mesh_hashes() -> None:
+    """Create ``search.mesh_hashes`` at each solve's start, so a worker
+    whose solves all ran on one device reads 0 there, where a program
+    that does not count mesh work has no such counter at all."""
+    metrics.inc("search.mesh_hashes", 0)
+
+
 # canonical home is the jax-free partition module (advisor r3: the
 # native backend validates runs without importing the JAX compute path);
 # re-exported here because the driver and both device backends import it
@@ -320,15 +327,21 @@ def search(
     target_chunks = max(1, effective_batch(batch_size) // tbc)
 
     hashes = 0
-    # FIFO of in-flight launches: (result, chunk0, var_width, extra, n_cand)
+    # FIFO of in-flight launches:
+    # (result, chunk0, var_width, extra, n_cand, on_mesh)
     inflight: deque = deque()
 
-    def drain_one() -> Optional[SearchResult]:
+    def count(n_cand: int, on_mesh: bool) -> None:
         nonlocal hashes
-        WATCHDOG.beat()  # about to block on a device result fetch
-        res, chunk0, vw, extra, n_cand = inflight.popleft()
         hashes += n_cand
         metrics.inc("search.hashes", n_cand)
+        if on_mesh:
+            metrics.inc("search.mesh_hashes", n_cand)
+
+    def drain_one() -> Optional[SearchResult]:
+        WATCHDOG.beat()  # about to block on a device result fetch
+        res, chunk0, vw, extra, n_cand, on_mesh = inflight.popleft()
+        count(n_cand, on_mesh)
         # the sanctioned host sync: time blocked on the launch's result
         # fetch (pipelined, so a busy pipeline shows near-zero waits; a
         # dry one shows the full device round trip).  Counted as a
@@ -371,11 +384,9 @@ def search(
         to dispatched work on every exit path — found, cancelled, or
         budget — while SearchResult.hashes_tried remains the DRAINED
         count (the enumeration-position bound at the find)."""
-        nonlocal hashes
         while inflight:
-            *_, n = inflight.popleft()
-            hashes += n
-            metrics.inc("search.hashes", n)
+            *_, n, on_mesh = inflight.popleft()
+            count(n, on_mesh)
 
     # The active() window covers every dispatch and drain: if the device
     # hangs mid-search, beats stop and the watchdog (if the worker
@@ -384,6 +395,7 @@ def search(
     # meter brackets the same window: its refcount zeroes the
     # hashes_per_s gauge when the LAST concurrent search exits, on
     # every exit path (found / cancelled / budget / error).
+    _register_mesh_hashes()
     _RATE_METER.enter()
     try:
         with WATCHDOG.active():
@@ -400,6 +412,10 @@ def search(
                                              launch_candidates)
                         step, chunks_per_step = factory(vw, extra,
                                                         target_chunks, k)
+                        # a mesh factory marks its steps (mesh_search.py
+                        # _on_mesh); the probe and one-device steps do not
+                        devices = getattr(step, "mesh_devices", None)
+                        seg.annotate(devices=devices or 1)
                         chunk0 = lo
                         while chunk0 < hi:
                             # A launch's compiled span can overshoot the
@@ -449,7 +465,8 @@ def search(
                             metrics.inc("search.launches")
                             dispatches += 1
                             seg.annotate(dispatches=dispatches)
-                            inflight.append((res, chunk0, vw, extra, n_cand))
+                            inflight.append((res, chunk0, vw, extra, n_cand,
+                                             devices is not None))
                             chunk0 += chunks_per_step
                             if len(inflight) >= pipeline_depth:
                                 found = drain_one()
@@ -590,24 +607,28 @@ def persistent_search(
 
     hashes = 0
     # FIFO of in-flight dispatches over the whole solve:
-    # (res, chunk0, vw, extra, seg_chunks, chunks_each)
+    # (res, chunk0, vw, extra, seg_chunks, chunks_each, on_mesh)
     # where seg_chunks is the dispatch's IN-SEGMENT chunk span (the
-    # overscan clip the serial driver documents at its n_cand line) and
-    # chunks_each the chunk count of one on-device segment.  vw == 0 is
-    # the width-0 probe, outside the chain.
+    # overscan clip the serial driver documents at its n_cand line),
+    # chunks_each the chunk count of one on-device segment and on_mesh
+    # whether the step spans the worker's mesh.  vw == 0 is the width-0
+    # probe, outside the chain.
     inflight: deque = deque()
 
-    def count(n_cand: int) -> None:
+    def count(n_cand: int, on_mesh: bool) -> None:
         nonlocal hashes
         hashes += n_cand
         metrics.inc("search.hashes", n_cand)
+        if on_mesh:
+            metrics.inc("search.mesh_hashes", n_cand)
 
     def drain_one() -> Tuple[Optional[SearchResult], bool]:
         """Poll the head to readiness, then convert.  Returns
         ``(found, cancelled)`` — polling honors cancel_check, so a
         cancel arriving mid-wait stops the search without blocking on
         the device."""
-        res, chunk0, vw, extra, seg_chunks, chunks_each = inflight.popleft()
+        res, chunk0, vw, extra, seg_chunks, chunks_each, on_mesh = \
+            inflight.popleft()
         # deliberately NO WATCHDOG.beat() inside the poll wait: a hung
         # device leaves is_ready() false forever, and beating here
         # would mask exactly the staleness the hang watchdog exists to
@@ -624,7 +645,7 @@ def persistent_search(
                         # account the popped head like the rest of the
                         # flush: dispatched work the device completes
                         # either way
-                        count(seg_chunks * tbc)
+                        count(seg_chunks * tbc, on_mesh)
                         return None, True
                     time.sleep(poll_interval_s)
         if vw:
@@ -642,7 +663,7 @@ def persistent_search(
             # conversion cannot block
             f = int(res)
             n_cand = seg_chunks * tbc
-        count(n_cand)
+        count(n_cand, on_mesh)
         _RATE_METER.note(n_cand)
         if f == SENTINEL:
             return None, False
@@ -666,11 +687,11 @@ def persistent_search(
         them out: they count their dispatched span, an upper bound
         (docs/SERVING.md)."""
         while inflight:
-            *_, seg_chunks, _ce = inflight.popleft()
+            *_, seg_chunks, _ce, on_mesh = inflight.popleft()
             if behind_hit:
                 metrics.inc("search.halted_dispatches")
             else:
-                count(seg_chunks * tbc)
+                count(seg_chunks * tbc, on_mesh)
 
     def drain_all() -> Tuple[Optional[SearchResult], bool]:
         while inflight:
@@ -693,6 +714,7 @@ def persistent_search(
             metrics.inc("search.found")
         return found
 
+    _register_mesh_hashes()
     _RATE_METER.enter()
     try:
         with WATCHDOG.active():
@@ -726,6 +748,11 @@ def persistent_search(
                                 )
                                 chunks_each = target_chunks
                                 chunks_per_step = target_chunks * k
+                        # the mesh lane's steps carry the mark
+                        # (mesh_search.py _on_mesh); the probe and the
+                        # single-device step do not
+                        devices = getattr(step, "mesh_devices", None)
+                        seg.annotate(devices=devices or 1)
                         chunk0 = lo
                         first_launch = True
                         while chunk0 < hi:
@@ -753,7 +780,8 @@ def persistent_search(
                             dispatches += 1
                             seg.annotate(dispatches=dispatches)
                             inflight.append((res, chunk0, vw, extra,
-                                             seg_chunks, chunks_each))
+                                             seg_chunks, chunks_each,
+                                             devices is not None))
                             chunk0 += chunks_per_step
                             if len(inflight) >= pipeline_depth:
                                 found, cancelled = drain_one()

@@ -15,6 +15,10 @@ declaration distpow-lint's ``metrics-registry`` rule verifies every
 ``metrics.inc("…")`` call site against — docs/LINT.md):
 
 * ``search.hashes``        — candidates evaluated (all backends)
+* ``search.mesh_hashes``   — the part of ``search.hashes`` whose
+  dispatches spanned every device of the worker's mesh (the steps the
+  mesh factories mark, parallel/mesh_search.py ``_on_mesh``); the
+  width-0 probe and single-device steps add nothing here
 * ``search.launches``      — device dispatches
 * ``search.cancelled``     — searches stopped by a cancel check
 * ``search.found``         — searches that returned a secret
@@ -235,7 +239,7 @@ Number = Union[int, float]
 KNOWN_COUNTERS = frozenset({
     "search.hashes", "search.launches", "search.cancelled", "search.found",
     "search.blocking_syncs", "search.persistent_steps",
-    "search.halted_dispatches",
+    "search.halted_dispatches", "search.mesh_hashes",
     "worker.mine_rpcs", "worker.found_rpcs", "worker.cancel_rpcs",
     "worker.results_sent", "worker.forward_retries",
     "coord.mine_rpcs", "coord.fanouts", "coord.late_results",

@@ -208,6 +208,9 @@ def test_search_drivers_record_segments_and_launch_waits(driver):
         list(range(len(r.secret)))
     assert all(x["node"] == "w-test" and x["attrs"]["dispatches"] >= 1
                for x in segs)
+    # one device serves every width here (tests/test_mesh_reference.py
+    # covers the mesh steps)
+    assert all(x["attrs"]["devices"] == 1 for x in segs)
     if driver == "search":
         launches = [x for x in spans if x["name"] == "search.launch"]
         assert sum(x["attrs"]["dispatches"] for x in segs) >= len(launches)
