@@ -348,11 +348,15 @@ class PallasMeshBackend(JaxMeshBackend):
     ``jax-mesh``, but each device runs the hand-written kernel
     (ops/md5_pallas.py) instead of the fused XLA step — one compiled
     kernel program serves every device because the partition descriptor
-    is a runtime SMEM operand (parallel/mesh_search.py
-    _dyn_pallas_mesh_step).  Configurations the kernel cannot express
-    fall back to the XLA mesh factory per width, counted under
-    ``fallback.pallas_mesh_step``.
-    Warmup/search flow is inherited — only the step factory differs.
+    is a runtime SMEM operand.  ``search`` drives the chained persistent
+    loop (parallel/search.py persistent_search) through the chained
+    Pallas mesh step (parallel/mesh_search.py
+    pallas_mesh_persistent_step), so a solve's dispatches stop on the
+    device, on every chip, at the segment of its hit.  Requests and
+    widths the kernel cannot express are served through the XLA mesh
+    persistent step, counted under ``fallback.pallas_mesh_step``.
+    ``_step_factory`` keeps the relaunch form for library callers of
+    ``search_mesh``.
     """
 
     name = "pallas-mesh"
@@ -360,6 +364,115 @@ class PallasMeshBackend(JaxMeshBackend):
     def __init__(self, *args, interpret: bool = False, **kwargs):
         super().__init__(*args, **kwargs)
         self.interpret = interpret
+
+    def _persistent_builder(self, nonce: bytes, difficulty: int, tb_lo: int,
+                            tbc: int):
+        """The ``step_builder`` ``search`` serves through: the chained
+        Pallas mesh step per width, else the XLA mesh persistent step,
+        else (a segment batch that does not divide across the mesh) None,
+        the driver's single-device persistent step."""
+        from ..parallel.mesh_search import (
+            AXIS,
+            mesh_persistent_factory,
+            pallas_mesh_persistent_factory,
+        )
+        from ..runtime.metrics import REGISTRY as metrics
+
+        mesh = self._get_mesh()
+        xla_builder = mesh_persistent_factory(
+            nonce, difficulty, tb_lo, tbc, self.model, mesh, AXIS)
+        try:
+            pallas_builder = pallas_mesh_persistent_factory(
+                nonce, difficulty, tb_lo, tbc, self.model, mesh, AXIS,
+                interpret=self.interpret,
+            )
+        except ValueError as exc:
+            metrics.inc("fallback.pallas_mesh_step")
+            log.warning("pallas-mesh: %s; serving via the XLA mesh step",
+                        exc)
+            pallas_builder = None
+
+        def builder(vw, extra, target_chunks, segments):
+            if pallas_builder is not None:
+                try:
+                    return pallas_builder(vw, extra, target_chunks, segments)
+                except ValueError as exc:
+                    metrics.inc("fallback.pallas_mesh_step")
+                    log.warning("pallas-mesh: %s; serving width %d via the "
+                                "XLA mesh step", exc, vw)
+            try:
+                return xla_builder(vw, extra, target_chunks, segments)
+            except ValueError:
+                return None
+
+        return builder
+
+    def warmup(self, nonce_lens: Sequence[int], widths: Sequence[int]) -> None:
+        """Compile the programs ``search`` serves, in both sharding
+        regimes and both mask-word buckets, through ``_warm_layouts``'s
+        derivation of the served compile keys.  Each warm dispatch runs
+        behind ``CHAIN_HALT``: its loop exits at segment 0."""
+        import numpy as np
+
+        from ..ops.search_step import (
+            CHAIN_HALT,
+            cached_persistent_step,
+            cached_search_step,
+        )
+
+        model_name = self.model.name
+
+        def build(nonce, tbc, difficulty):
+            builder = self._persistent_builder(nonce, difficulty, 0, tbc)
+
+            def factory(vw, extra, target_chunks, launch_steps=1):
+                if vw == 0:
+                    return cached_search_step(
+                        nonce, 0, difficulty, 0, tbc, 1, model_name, extra, 1
+                    ), 1
+                plan = builder(vw, extra, target_chunks, launch_steps)
+                if plan is None:
+                    bound = cached_persistent_step(
+                        nonce, vw, difficulty, 0, tbc, target_chunks,
+                        model_name, extra, launch_steps,
+                    )
+                    chunks = target_chunks * launch_steps
+                else:
+                    bound, _, chunks = plan
+                return (lambda chunk0:
+                        np.asarray(bound(chunk0, CHAIN_HALT))[0]), chunks
+
+            return factory
+
+        _warm_layouts(build, nonce_lens, widths, self.batch_size,
+                      max_launch=self.max_launch)
+        n_dev = int(self._get_mesh().devices.size)
+        if n_dev > 1:
+            # a partition smaller than the device count selects the
+            # chunk-split regime, a compile key of its own; the dispatch
+            # size does not depend on the partition, so one
+            # representative warms every such partition
+            _warm_layouts(build, nonce_lens, widths, self.batch_size,
+                          tbc=n_dev // 2, max_launch=self.max_launch)
+
+    def search(self, nonce, difficulty, thread_bytes, cancel_check=None):
+        from ..parallel.partition import contiguous_bounds
+        from ..parallel.search import persistent_search
+
+        nonce = bytes(nonce)
+        tb_lo, tbc = contiguous_bounds(thread_bytes)
+        res = persistent_search(
+            nonce,
+            difficulty,
+            thread_bytes,
+            model=self.model,
+            batch_size=self.batch_size,
+            cancel_check=cancel_check,
+            launch_candidates=self.max_launch,
+            step_builder=self._persistent_builder(nonce, difficulty, tb_lo,
+                                                  tbc),
+        )
+        return None if res is None else res.secret
 
     def _step_factory(self, nonce: bytes, difficulty: int, tb_lo: int,
                       tbc: int):

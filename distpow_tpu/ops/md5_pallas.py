@@ -132,6 +132,14 @@ MODEL_GEOMETRY = {"md5": (64, 512), "sha256": (32, 256),
                   # 1's digest collapses to 8 words), so the same
                   # height should hold; not swept on a chip yet
                   "sha256d": (32, 256)}
+# Grid steps in one segment of the chained pallas-mesh step
+# (parallel/mesh_search.py pallas_mesh_persistent_step), so a segment is
+# SEGMENT_GRID_STEPS * sublanes * LANES * inner candidates a chip: 2^24
+# at md5's (64, 512), ~1.6 ms at the kernel's ~10.5 GH/s a chip.  Each
+# segment ends in one cross-chip pmin (3-5 us on a v5e 2x2) and a loop
+# check, so the overhead stays near 1%, while a solve runs on average
+# half a segment past its hit.
+SEGMENT_GRID_STEPS = 4
 _I32_MISS = 0x7FFFFFFF  # in-kernel miss marker (int32 reduction domain)
 
 # Models whose tile only serves on REAL TPU hardware: interpret mode

@@ -9,7 +9,9 @@ prefix -> RPC peer.  The "first result wins, everyone stops" protocol
 (coordinator.go:202-230) compresses onto ICI: every step ends in a
 ``lax.pmin`` of the per-device first-hit flat index, so all devices
 observe a win at the same step boundary and the host stops dispatching —
-the Found broadcast without any RPC.
+the Found broadcast without any RPC.  The persistent steps
+(``mesh_persistent_step``, ``pallas_mesh_persistent_step``) take a
+``pmin`` per on-device segment instead, and stop on the device.
 
 Two sharding regimes, chosen automatically:
 
@@ -265,6 +267,136 @@ def _dyn_pallas_mesh_step(
     return named_jit(sharded, f"{model_name}_pallas_mesh_step")
 
 
+@functools.lru_cache(maxsize=None)
+def pallas_mesh_persistent_step(
+    mesh: Mesh,
+    axis: str,
+    model_name: str,
+    tb_word: int,
+    tb_shift: int,
+    chunk_word_shifts,
+    grid: int,
+    sublanes: int,
+    inner: int,
+    interpret: bool,
+    mask_words: int,
+    tb_split: bool,
+    log_ndev: int,
+    seg_local: int,
+    segments: int,
+    base_width: int,
+):
+    """The Pallas kernel on every device of the mesh, in the chained
+    multi-segment loop of the persistent steps (``chained_segments``).
+
+    Each loop iteration is one segment: ``seg_local`` candidates a
+    device, one ``_dyn_pallas_step`` call of ``grid`` steps, then a
+    ``lax.pmin`` of the global first-hit index, so every device sees a
+    hit at the same segment boundary and leaves the loop with the
+    others.  A segment covers one contiguous global span of the
+    enumeration, ``[seg * seg_global, (seg + 1) * seg_global)`` flat: in
+    the tb-split regime every device scans the segment's chunks on its
+    own thread-byte slice; in the chunk-split regime the span is cut
+    into ``n_dev`` contiguous chunk spans, one a device.  (Splitting the
+    whole dispatch instead would start device ``d`` at ``d / n_dev`` of
+    it, and the loop would stop at a hit there before device 0 reached
+    an earlier one.)  The first hit of the first segment that has one is
+    therefore the dispatch's reference-order first.
+
+    Returned fn: ``(init[S], base[base_width], masks[mask_words],
+    part[2]=(tb_lo, log_tbc), chunk0, prev[2]) -> uint32[2]``, the
+    signature of ``mesh_persistent_step`` with the partition as one
+    operand: the first-hit global flat index, SENTINEL or HALT, then the
+    segments run.  Behind a ``prev`` that hit or halted it runs none.
+    """
+    from ..ops.md5_pallas import _TILE_FNS, _dyn_pallas_step
+
+    kernel = _dyn_pallas_step(
+        tb_word, tb_shift, chunk_word_shifts, grid, sublanes, interpret,
+        inner, mask_words, model_name,
+    )
+    # The kernel's jaxpr is traced here, once, outside shard_map and the
+    # loop, and bound in the loop body as it is: traced inside the loop
+    # body it took ~3.5 s a program on a v5e host, this way ~0.5 s
+    u32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.uint32)
+    kernel_jaxpr = jax.make_jaxpr(kernel)(
+        u32(()), u32((_TILE_FNS[model_name][1],)), u32((base_width,)),
+        u32((mask_words,)), u32((2,)))
+
+    def call_kernel(*operands):
+        return jax.core.eval_jaxpr(kernel_jaxpr.jaxpr, kernel_jaxpr.consts,
+                                   *operands)[0]
+
+    one = jnp.uint32(1)
+    seg_global = seg_local << log_ndev
+    _check_launch(seg_global, segments)
+
+    def body(init, base, masks, part, chunk0, prev):
+        d = jax.lax.axis_index(axis).astype(jnp.uint32)
+        tb_lo, log_tbc = part[0], part[1]
+        # global chunks one segment spans
+        seg_chunks = jnp.uint32(seg_global) >> log_tbc
+        if tb_split:
+            log_tbl = log_tbc - jnp.uint32(log_ndev)
+            part_dev = jnp.stack(
+                [tb_lo + (d << log_tbl), log_tbl]).astype(jnp.uint32)
+
+        def search_segment(seg):
+            c0 = jnp.uint32(chunk0) + seg * seg_chunks
+            if tb_split:
+                f_l = call_kernel(c0, init, base, masks, part_dev)
+                f_g = ((f_l >> log_tbl) << log_tbc) + (d << log_tbl) \
+                    + (f_l & ((one << log_tbl) - one))
+            else:
+                chunks_dev = jnp.uint32(seg_local) >> log_tbc
+                f_l = call_kernel(c0 + d * chunks_dev, init, base, masks,
+                                  part)
+                f_g = d * jnp.uint32(seg_local) + f_l
+            f_g = jnp.where(f_l == jnp.uint32(SENTINEL), jnp.uint32(SENTINEL),
+                            seg * jnp.uint32(seg_global) + f_g)
+            return jax.lax.pmin(f_g, axis)
+
+        return chained_segments(prev, segments, search_segment)
+
+    # check_vma=False: as in _dyn_pallas_mesh_step, the pallas_call's
+    # output carries no varying-axes annotation; the per-segment pmin is
+    # what makes the loop carry replicated
+    sharded = _shard_map(
+        body, mesh=mesh, in_specs=(P(),) * 6, out_specs=P(), check_vma=False,
+    )
+    return named_jit(sharded, f"{model_name}_pallas_mesh_persistent_step")
+
+
+def _pallas_mesh_geometry(model: HashModel, n_dev: int, tbc: int,
+                          interpret: bool):
+    """``(sublanes, inner)`` of the kernel for a mesh of ``n_dev``
+    devices and a partition of ``tbc`` thread bytes, or ValueError where
+    the kernel cannot express them: a device count or partition that is
+    not a power of two, a model without a kernel, or a TPU-only tile in
+    interpret mode."""
+    from ..ops.md5_pallas import (
+        INTERPRET_XLA_FALLBACK,
+        MODEL_GEOMETRY,
+        default_geometry,
+    )
+
+    if n_dev & (n_dev - 1):
+        raise ValueError("pallas mesh requires a power-of-two device count")
+    if tbc & (tbc - 1):
+        raise ValueError("pallas kernel requires power-of-two tb_count")
+    if model.name not in MODEL_GEOMETRY:
+        raise ValueError(f"no pallas kernel for model {model.name}")
+    if interpret and model.name in INTERPRET_XLA_FALLBACK:
+        # same guard as build_pallas_search_step: interpret mode would
+        # hand the unrolled limb-pair tile to XLA:CPU (pathological
+        # compile); the mesh backend maps this to its XLA fallback
+        raise ValueError(
+            f"{model.name} pallas tile is TPU-only (interpret-mode "
+            f"XLA:CPU compile of the limb-pair graph is pathological)"
+        )
+    return default_geometry(model.name, interpret)
+
+
 def _pallas_mesh_step_factory(
     nonce: bytes,
     difficulty: int,
@@ -285,29 +417,10 @@ def _pallas_mesh_step_factory(
     without a kernel); ``PallasMeshBackend`` catches these per width and
     falls back to the XLA mesh factory transparently.
     """
-    from ..ops.md5_pallas import (
-        INTERPRET_XLA_FALLBACK,
-        LANES,
-        MODEL_GEOMETRY,
-        default_geometry,
-    )
+    from ..ops.md5_pallas import LANES
 
     n_dev = int(mesh.devices.size)
-    if n_dev & (n_dev - 1):
-        raise ValueError("pallas mesh requires a power-of-two device count")
-    if tbc & (tbc - 1):
-        raise ValueError("pallas kernel requires power-of-two tb_count")
-    if model.name not in MODEL_GEOMETRY:
-        raise ValueError(f"no pallas kernel for model {model.name}")
-    if interpret and model.name in INTERPRET_XLA_FALLBACK:
-        # same guard as build_pallas_search_step: interpret mode would
-        # hand the unrolled limb-pair tile to XLA:CPU (pathological
-        # compile); the mesh backend maps this to its XLA fallback
-        raise ValueError(
-            f"{model.name} pallas tile is TPU-only (interpret-mode "
-            f"XLA:CPU compile of the limb-pair graph is pathological)"
-        )
-    geom = default_geometry(model.name, interpret)
+    geom = _pallas_mesh_geometry(model, n_dev, tbc, interpret)
     if sublanes is None:
         sublanes = geom[0]
     if inner is None:
@@ -707,6 +820,89 @@ def mesh_persistent_factory(
     return builder
 
 
+def pallas_mesh_persistent_factory(
+    nonce: bytes,
+    difficulty: int,
+    tb_lo: int,
+    tbc: int,
+    model: HashModel,
+    mesh: Mesh,
+    axis: str = AXIS,
+    interpret: bool = False,
+    segment_candidates: Optional[int] = None,
+):
+    """Persistent step builder over the mesh backed by the Pallas kernel
+    (``pallas_mesh_persistent_step``): the ``step_builder`` the
+    ``pallas-mesh`` backend hands ``parallel.search.persistent_search``.
+
+    Returns ``builder(vw, extra, target_chunks, segments) ->
+    (bound(chunk0, prev), chunks_each, chunks_per_step)``, the contract
+    of ``mesh_persistent_factory``.  A dispatch covers what the driver
+    planned, ``target_chunks * tbc * segments`` candidates, cut into
+    on-device segments of ``segment_candidates`` a device (by default
+    ``SEGMENT_GRID_STEPS`` grid steps of the model's kernel geometry,
+    ops/md5_pallas.py).  A dispatch smaller than one segment runs one
+    segment of its own size, rounded up to the tile grid.  Both sizes
+    follow from the driver's batch and budget alone, never from the
+    partition, so one program serves every power-of-two partition of a
+    sharding regime.
+
+    Raises ValueError where the kernel cannot express the request
+    (``_pallas_mesh_geometry``) or, from the builder, a width (a
+    multi-block tail); the backend serves those through
+    ``mesh_persistent_factory``.  Operands and the chain's constants are
+    placed replicated on the mesh at bind time, like the step's own
+    results, so a dispatch behind ``CHAIN_HALT`` compiles the program
+    the solve's dispatches run.
+    """
+    from ..ops.md5_pallas import LANES, SEGMENT_GRID_STEPS
+    from .compat import NamedSharding
+
+    n_dev = int(mesh.devices.size)
+    sublanes, inner = _pallas_mesh_geometry(model, n_dev, tbc, interpret)
+    tile = sublanes * LANES
+    if segment_candidates is None:
+        segment_candidates = SEGMENT_GRID_STEPS * tile * inner
+    tb_split = tbc >= n_dev
+    log_ndev = n_dev.bit_length() - 1
+    repl = NamedSharding(mesh, P())
+
+    @functools.lru_cache(maxsize=32)
+    def builder(vw: int, extra: bytes, target_chunks: int, segments: int):
+        spec = build_tail_spec(bytes(nonce), vw, model, extra)
+        if spec.n_blocks != 1:
+            raise ValueError("pallas kernel requires a single-block tail")
+        per_dev = (target_chunks * tbc * segments) >> log_ndev
+        seg_local = -(-min(segment_candidates, per_dev) // tile) * tile
+        n_seg = -(-per_dev // seg_local)
+        tiles = seg_local // tile
+        inner_eff = inner
+        while tiles % inner_eff:
+            inner_eff //= 2
+        _, tb_w, tb_s = spec.tb_loc
+        dyn = pallas_mesh_persistent_step(
+            mesh, axis, model.name, tb_w, tb_s,
+            tuple((w, s) for _, w, s in spec.chunk_locs), tiles // inner_eff,
+            sublanes, inner_eff, interpret,
+            mask_words_for(difficulty, model), tb_split, log_ndev, seg_local,
+            n_seg, len(spec.base_words[0]),
+        )
+        init, base, masks = step_operands(spec, difficulty, model)
+        init, base, masks, part = (
+            jax.device_put(a, repl) for a in (
+                init, base[0], masks,
+                jnp.asarray([tb_lo, tbc.bit_length() - 1], jnp.uint32)))
+
+        def bound(chunk0, prev):
+            return dyn(init, base, masks, part, chunk0,
+                       chain_operand(prev, repl))
+
+        chunks_each = (seg_local << log_ndev) // tbc
+        return _on_mesh(bound, mesh), chunks_each, chunks_each * n_seg
+
+    return builder
+
+
 def search_mesh(
     nonce: bytes,
     difficulty: int,
@@ -720,8 +916,8 @@ def search_mesh(
 ) -> Optional[SearchResult]:
     """Mesh-parallel ``search`` with identical semantics and result decode.
 
-    ``step_factory`` overrides the default XLA mesh factory — the
-    pallas-mesh backend plugs its kernel-backed factory in here.
+    ``step_factory`` overrides the default XLA mesh factory, e.g. with
+    the kernel-backed ``PallasMeshBackend._step_factory``.
     """
     model = model or get_hash_model("md5")
     mesh = mesh if mesh is not None else make_mesh()

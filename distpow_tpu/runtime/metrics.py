@@ -80,9 +80,9 @@ declaration distpow-lint's ``metrics-registry`` rule verifies every
 * ``fallback.pallas_step`` — width segments the ``pallas`` backend
   served through the fused XLA step because the kernel refused them
   (backends/pallas_backend.py)
-* ``fallback.pallas_mesh_step`` — request factories or width segments
-  the ``pallas-mesh`` backend served through the XLA mesh step
-  (backends/__init__.py)
+* ``fallback.pallas_mesh_step`` — requests or width segments the
+  ``pallas-mesh`` backend served through the XLA mesh steps instead of
+  the kernel (backends/__init__.py)
 * ``fallback.lane_demotions`` — launch lanes demoted to a slower lane
   after a build or dispatch failure (sched/lanes.py)
 * ``fallback.warmup_failures`` — boot warmups that raised

@@ -959,10 +959,10 @@ def test_pallas_mesh_fallback_is_counted(monkeypatch, refuse):
     from distpow_tpu.runtime.metrics import REGISTRY
 
     if refuse == "request":
-        monkeypatch.setattr(mesh_search, "_pallas_mesh_step_factory",
+        monkeypatch.setattr(mesh_search, "pallas_mesh_persistent_factory",
                             _raise_value_error)
     else:
-        monkeypatch.setattr(mesh_search, "_pallas_mesh_step_factory",
+        monkeypatch.setattr(mesh_search, "pallas_mesh_persistent_factory",
                             lambda *a, **k: _raise_value_error)
     before = REGISTRY.get("fallback.pallas_mesh_step")
     b = PallasMeshBackend(hash_model="md5", batch_size=1 << 12,

@@ -709,19 +709,38 @@ def test_pallas_mesh_falls_back_for_long_nonce():
 
 
 def test_pallas_mesh_warmup_covers_serving_compile_keys():
-    """After boot warmup, serving any pow2 partition must not compile a
-    new mesh-kernel program (the same layout-keyed discipline the XLA
-    mesh path proves in test_search.py)."""
+    """After boot warmup, serving any pow2 partition in either sharding
+    regime must not build a new chained mesh-kernel program (the same
+    layout-keyed discipline the XLA mesh path proves in test_search.py),
+    and a solve of a partition warmup stands for compiles nothing.
+    Every solve here ends in width 1, so width 2's first dispatch is in
+    flight behind it: warmup covers one width past the widest solve."""
+    import jax
+
     from distpow_tpu.backends import PallasMeshBackend
-    from distpow_tpu.parallel.mesh_search import _dyn_pallas_mesh_step
+    from distpow_tpu.parallel.mesh_search import pallas_mesh_persistent_step
 
     b = PallasMeshBackend(batch_size=1 << 14, interpret=True)
-    b.warmup([3], [0, 1])
-    misses = _dyn_pallas_mesh_step.cache_info().misses
-    for tbs in (list(range(256)), list(range(128, 192))):
-        secret = b.search(b"\x07\x08\x09", 2, tbs)
-        assert secret is not None
-    assert _dyn_pallas_mesh_step.cache_info().misses == misses
+    b.warmup([3], [0, 1, 2])
+    n_dev = len(jax.devices())
+    misses = pallas_mesh_persistent_step.cache_info().misses
+    compiles = []
+
+    def listen(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        # the partitions warmup represents: tb-split, chunk-split
+        for tbs in (list(range(256)), list(range(n_dev // 2))):
+            assert b.search(b"\x07\x08\x09", 2, tbs) is not None
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    for tbs in (list(range(128, 192)), [4, 5]):
+        assert b.search(b"\x07\x08\x09", 2, tbs) is not None
+    assert pallas_mesh_persistent_step.cache_info().misses == misses
 
 
 @pytest.mark.slow

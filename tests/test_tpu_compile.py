@@ -6,7 +6,8 @@ fast memory, a program that does not fit) would otherwise surface on
 the chip.  These compile the main path's programs at the shapes
 chip_smoke.py serves: the md5 / sha256 / sha1 / sha512 kernels at
 their MODEL_GEOMETRY, the md5 persistent XLA serving step at batch
-2^20, and the md5 pallas-mesh step over a 2x2 mesh.
+2^20, and the md5 pallas-mesh steps over a 2x2 mesh: the relaunch step
+and the chained persistent step the backend serves through.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and xdist workers that
@@ -168,3 +169,42 @@ def test_pallas_mesh_step_compiles_on_2x2(topo):
     text = step.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text
+
+
+def test_pallas_mesh_persistent_step_compiles_on_2x2(topo):
+    """The pallas-mesh backend's served width-4 program over four
+    chips: a 2^30-candidate dispatch of 2^24-candidate segments a chip,
+    each a kernel call and a found-index all-reduce inside the chained
+    while loop."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from distpow_tpu.models.registry import get_hash_model
+    from distpow_tpu.ops.md5_pallas import LANES, MODEL_GEOMETRY, \
+        SEGMENT_GRID_STEPS
+    from distpow_tpu.ops.search_step import mask_words_for, step_operands
+    from distpow_tpu.parallel.mesh_search import AXIS, make_mesh, \
+        pallas_mesh_persistent_step
+    from distpow_tpu.parallel.search import DEFAULT_LAUNCH_CANDIDATES
+
+    model = get_hash_model("md5")
+    mesh = make_mesh(topo.devices)
+    n_dev = len(topo.devices)
+    sublanes, inner = MODEL_GEOMETRY["md5"]
+    seg_local = SEGMENT_GRID_STEPS * sublanes * LANES * inner
+    assert seg_local == 1 << 24
+    segments = DEFAULT_LAUNCH_CANDIDATES // n_dev // seg_local
+    spec, tb_w, tb_s, chunk_ws = _layout(model)
+    mw = mask_words_for(DIFFICULTY, model)
+    step = pallas_mesh_persistent_step(
+        mesh, AXIS, "md5", tb_w, tb_s, chunk_ws, SEGMENT_GRID_STEPS,
+        sublanes, inner, False, mw, True, n_dev.bit_length() - 1, seg_local,
+        segments, len(spec.base_words[0]))
+    init, base, masks = step_operands(spec, DIFFICULTY, model)
+    u2 = jnp.zeros((2,), jnp.uint32)
+    args = _shapes([init, base[0], masks, u2, jnp.uint32(0), u2],
+                   NamedSharding(mesh, PartitionSpec()))
+    text = step.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text
+    assert "while" in text
