@@ -30,14 +30,12 @@ at any point costs only the tail, never the registry's standing —
      (compile-impractical, docs/KERNELS.md) — deadline-gated
 
 A family of CPU-only stages rides after the device phases (each also
-standalone via ``--control-plane`` / ``--serving-loop`` /
+standalone via ``--control-plane`` /
 ``--load-slo`` / ``--membership`` / ``--forensics-overhead`` /
 ``--cluster-scale`` / ``--cache-ha`` / ``--soak`` /
 ``--mesh-serving``):
-the RPC control-plane latency stage
-(ISSUE 5), the serving-loop stage (ISSUE 6: blocking host syncs per
-solve, serial vs persistent driver, plus mixed-hash batching
-occupancy), the open-loop load + cluster-SLO stage (ISSUE 8: achieved
+the RPC control-plane latency stage, the open-loop load +
+cluster-SLO stage (achieved
 solves/s and cluster-merged p95 under seeded Poisson traffic, judged
 against config/slo.json), the elastic-membership stage (ISSUE 12:
 lease-expiry reassignment + straggler hedging), the
@@ -1941,208 +1939,6 @@ def serving_stage(ks=(1, 4, 16)) -> dict:
     return line
 
 
-def serving_loop_stage() -> dict:
-    """Serving-loop overhead stage (``--serving-loop``): CPU-only, no
-    chip needed (ISSUE 6).
-
-    Measures the host cost of the two serving loops on identical work:
-
-    * **blocking host syncs per solve** — the serial loop converts
-      every launch result with a blocking ``int(res)``
-      (``search.blocking_syncs``); the persistent loop polls readiness
-      and must stay at zero.  The acceptance bar is a >= 3x reduction.
-    * **launch->drain overhead** — the serial driver's blocked fetches
-      (``search.launch`` spans) vs the persistent driver's poll waits
-      (``search.poll`` spans), summed over the span ring.
-    * **mixed-hash batching** — md5+sha1 slots through one
-      ``BatchingScheduler`` must pack (occupancy mean > 1, where
-      single-model-only batching served exactly 1 via the solo
-      fallback) in fewer launches than per-model solos.
-
-    First-hit parity is asserted inline: every persistent/batched
-    secret must be byte-identical to the serial driver's (which the
-    golden suite pins to the reference enumeration oracle).
-    """
-    from distpow_tpu.models import puzzle
-    from distpow_tpu.parallel.search import persistent_search, search
-    from distpow_tpu.runtime.metrics import REGISTRY
-    from distpow_tpu.runtime.spans import SPANS
-    from distpow_tpu.sched.engine import BatchingScheduler
-
-    stage_t0 = time.time()
-    ntz = int(os.environ.get("BENCH_SERVING_LOOP_NTZ", "4"))
-    batch = 1 << 10
-    launch_cand = 1 << 12  # small launches => many drains per solve
-    nonces = [bytes([0xD0, i, 0x5A]) for i in range(4)]
-
-    def run_driver(drive):
-        t0 = time.monotonic()
-        b0 = REGISTRY.get("search.blocking_syncs")
-        l0 = REGISTRY.get("search.launches")
-        secrets = []
-        for nonce in nonces:
-            res = drive(nonce, ntz, list(range(256)), batch_size=batch,
-                        launch_candidates=launch_cand)
-            assert res is not None
-            assert puzzle.check_secret(nonce, res.secret, ntz)
-            secrets.append(res.secret)
-        return {
-            "secrets": secrets,
-            "syncs": REGISTRY.get("search.blocking_syncs") - b0,
-            "launches": REGISTRY.get("search.launches") - l0,
-            "wall_s": round(time.monotonic() - t0, 3),
-        }
-
-    # warm both drivers' compiles outside the timed windows
-    search(nonces[0], 1, list(range(256)), batch_size=batch,
-           launch_candidates=launch_cand)
-    persistent_search(nonces[0], 1, list(range(256)), batch_size=batch,
-                      launch_candidates=launch_cand)
-
-    def span_s(name, seq0):
-        return sum(s["dur_s"] for s in SPANS.recent()
-                   if s["name"] == name and s["seq"] > seq0)
-
-    seq0 = SPANS.total_recorded
-    serial = run_driver(search)
-    seq1 = SPANS.total_recorded
-    ps0 = REGISTRY.get("search.persistent_steps")
-    persistent = run_driver(persistent_search)
-
-    assert persistent["secrets"] == serial["secrets"], \
-        "serving-loop parity violation: drivers disagree on first hits"
-    n = len(nonces)
-    syncs_serial = serial["syncs"] / n
-    syncs_persistent = persistent["syncs"] / n
-    reduction = round(syncs_serial / max(syncs_persistent, 1 / n), 2)
-    out = {
-        "ntz": ntz,
-        "solves": n,
-        "syncs_per_solve": {
-            "serial": round(syncs_serial, 2),
-            "persistent": round(syncs_persistent, 2),
-        },
-        "syncs_reduction_x": reduction,
-        "launches_per_solve": {
-            "serial": round(serial["launches"] / n, 2),
-            "persistent": round(persistent["launches"] / n, 2),
-        },
-        "launch_drain_overhead_s": {
-            "serial_blocked_fetch_sum": round(
-                span_s("search.launch", seq0), 6),
-            "persistent_poll_wait_sum": round(
-                span_s("search.poll", seq1), 6),
-        },
-        "persistent_steps": REGISTRY.get("search.persistent_steps") - ps0,
-        "wall_s": {"serial": serial["wall_s"],
-                   "persistent": persistent["wall_s"]},
-    }
-    print(f"[bench] serving-loop: {out['syncs_per_solve']['serial']} "
-          f"blocking syncs/solve serial vs "
-          f"{out['syncs_per_solve']['persistent']} persistent "
-          f"({reduction}x reduction)", file=sys.stderr)
-
-    # mixed-hash sub-stage: md5+sha1 through one scheduler
-    mh0 = REGISTRY.get("sched.mixed_hash_launches")
-    sl0 = REGISTRY.get("sched.launches")
-    reqs = [(("sha1" if i % 2 else "md5"), bytes([0xD1, i])) for i in
-            range(8)]
-    # per-model solo baseline: the same 8 requests one at a time
-    solo_eng = BatchingScheduler(hash_model="md5", batch_size=batch,
-                                 max_slots=8, extra_models=("sha1",))
-    try:
-        for m, nonce in reqs:
-            s = solo_eng.search(nonce, 3, list(range(256)), hash_model=m)
-            assert s == puzzle.python_search(nonce, 3, list(range(256)),
-                                             algo=m)
-    finally:
-        solo_eng.close()
-    solo_launches = REGISTRY.get("sched.launches") - sl0
-
-    occ0 = REGISTRY.get_histogram("sched.batch_occupancy") or \
-        {"count": 0, "sum": 0.0}
-    sl1 = REGISTRY.get("sched.launches")
-    eng = BatchingScheduler(hash_model="md5", batch_size=batch,
-                            max_slots=8, extra_models=("sha1",),
-                            start=False)
-    try:
-        slots = [eng.submit(nonce, 3, list(range(256)), hash_model=m)
-                 for m, nonce in reqs]
-        eng.start()
-        for (m, nonce), s in zip(reqs, slots):
-            secret = s.result(timeout=300)
-            assert secret == puzzle.python_search(
-                nonce, 3, list(range(256)), algo=m)
-    finally:
-        eng.close()
-    batched_launches = REGISTRY.get("sched.launches") - sl1
-    occ1 = REGISTRY.get_histogram("sched.batch_occupancy")
-    occ_n = occ1["count"] - occ0["count"]
-    mean_occ = (occ1["sum"] - occ0["sum"]) / max(occ_n, 1)
-    out["mixed_hash"] = {
-        "models": ["md5", "sha1"],
-        "requests": len(reqs),
-        "solo_launches": solo_launches,
-        "batched_launches": batched_launches,
-        "mean_occupancy": round(mean_occ, 3),
-        "mixed_hash_launches": REGISTRY.get("sched.mixed_hash_launches")
-        - mh0,
-    }
-    print(f"[bench] serving-loop mixed-hash: {batched_launches} launches "
-          f"batched vs {solo_launches} solo, mean occupancy "
-          f"{mean_occ:.2f}", file=sys.stderr)
-    out["wall_s_total"] = round(time.time() - stage_t0, 1)
-    if reduction < 3.0:
-        print(f"[bench] WARNING: serving-loop sync reduction {reduction}x "
-              f"(< 3x acceptance floor)", file=sys.stderr)
-    if mean_occ <= 1.0:
-        print(f"[bench] WARNING: mixed-hash occupancy {mean_occ:.2f} "
-              f"(<= 1: no batching)", file=sys.stderr)
-    return out
-
-
-def _serving_loop_subprocess(timeout_s: float = 600.0):
-    """Run the serving-loop stage from inside a full device bench.
-
-    jax in THIS process is already pinned to the TPU by the device
-    phases, and the platform cannot be re-pinned after first backend
-    use, while the stage's numbers are defined on the CPU.  So the stage
-    reuses the standalone ``--serving-loop`` entry point in a CPU-pinned
-    subprocess, which never loads the TPU runtime (the chip stays with
-    this process),
-    with provenance redirected to a temp path so the child's
-    ``finalize_record`` cannot touch the real ``last_measured.json`` —
-    the stage dict rides home through the PARENT's finalize_record.
-    """
-    import subprocess
-    import tempfile
-
-    env = dict(os.environ)
-    env["BENCH_FORCE_PLATFORM"] = "cpu"
-    try:
-        with tempfile.TemporaryDirectory() as td:
-            env["BENCH_LAST_MEASURED_PATH"] = os.path.join(td, "lm.json")
-            out = subprocess.run(
-                [sys.executable, os.path.abspath(__file__),
-                 "--serving-loop"],
-                capture_output=True, text=True, timeout=timeout_s,
-                env=env,
-            )
-    except subprocess.TimeoutExpired:
-        print(f"[bench] serving-loop stage exceeded {timeout_s}s in its "
-              f"CPU subprocess", file=sys.stderr)
-        return None
-    if out.stderr:
-        sys.stderr.write(out.stderr)
-    try:
-        line = json.loads(out.stdout.strip().splitlines()[-1])
-        return line["serving_loop"]
-    except Exception as exc:
-        print(f"[bench] serving-loop stage failed "
-              f"(rc={out.returncode}): {exc}", file=sys.stderr)
-        return None
-
-
 def mesh_serving_arm(n_requested: int) -> dict:
     """One ``--mesh-serving-arm`` child: scheduler solves/s at this
     process's virtual-CPU-device count.
@@ -2254,8 +2050,8 @@ def mesh_serving_stage(timeout_s: float = 600.0):
     solves/s at 4 devices, with the mesh lane actually serving
     (``sched.lane_launches.mesh`` > 0) — both asserted into ``ok``.
 
-    The arms are CPU-pinned subprocesses (the ``_serving_loop_subprocess``
-    pattern), so they never load the TPU runtime; child provenance is redirected to a temp path as a belt-and-braces
+    The arms are CPU-pinned subprocesses, so they never load the TPU
+    runtime; child provenance is redirected to a temp path as a belt-and-braces
     guard even though the arm entry point never writes provenance.
     """
     import subprocess
@@ -2353,21 +2149,6 @@ def main() -> None:
             sys.exit(1)
         line, _ = finalize_record({}, _read_last_measured(), None,
                                   mesh_serving=ms)
-        print(json.dumps(line))
-        return
-    if "--serving-loop" in sys.argv:
-        # standalone serving-loop run: CPU-only BY DESIGN (the stage's
-        # numbers are host costs defined on the CPU, and unlike
-        # --control-plane it drives real jax dispatches).  The line rides
-        # finalize_record's serving-loop shape and kernel provenance
-        # stays untouched (docstring there).
-        if not forced:
-            import jax
-
-            jax.config.update("jax_platforms", "cpu")
-        sl = serving_loop_stage()
-        line, _ = finalize_record({}, _read_last_measured(), None,
-                                  serving_loop=sl)
         print(json.dumps(line))
         return
     if "--control-plane" in sys.argv:
@@ -2773,10 +2554,10 @@ def main() -> None:
         # same solve timed stone-cold, i.e. it measured Mosaic compiles, not
         # the serving path (VERDICT r2 weak #1).
         try:
-            from distpow_tpu.backends.pallas_backend import PallasBackend
+            from distpow_tpu.backends import get_backend
             from distpow_tpu.models import puzzle
 
-            pb = PallasBackend(batch_size=1 << 21)
+            pb = get_backend("pallas", batch_size=1 << 21)
             t0 = time.time()
             pb.warmup([4], [0, 1, 2, 3, 4])
             print(f"[bench] pallas worker warmup (len-4 nonces, widths 0-4): "
@@ -2872,17 +2653,6 @@ def main() -> None:
             print(f"[bench] control-plane stage failed: {exc}",
                   file=sys.stderr)
 
-    # ---- Serving-loop stage (CPU subprocess, deadline-gated) ---------
-    # the subprocess timeout also clips to the remaining deadline: a
-    # stage admitted with seconds to spare must not overshoot the
-    # budget the rest of the run enforces by its full 600 s ceiling
-    serving_loop = None
-    if os.environ.get("BENCH_SERVING_LOOP") != "0" and \
-            time.time() <= deadline:
-        serving_loop = _serving_loop_subprocess(
-            timeout_s=min(600.0, max(1.0, deadline - time.time()))
-        )
-
     # ---- Load-SLO stage (CPU, deadline-gated) ------------------------
     # the open-loop + cluster-SLO row (ISSUE 8): python backends only —
     # like the control-plane stage it never touches jax
@@ -2972,7 +2742,6 @@ def main() -> None:
     # ---- Final line ---------------------------------------------------
     line, prov = finalize_record(rates, last_measured, baseline,
                                  control_plane=control_plane,
-                                 serving_loop=serving_loop,
                                  load_slo=load_slo,
                                  membership=membership,
                                  forensics=forensics,

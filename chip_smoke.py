@@ -289,30 +289,21 @@ def phase_b(failures: list) -> None:
 
 def timed_span(backend) -> float:
     """Candidates/s over SPAN_CANDIDATES at SPAN_NTZ through the
-    backend's own step factory (the second of two runs: the first
+    backend's own step builder (the second of two runs: the first
     compiles every width)."""
-    from distpow_tpu.parallel.mesh_search import search_mesh
-    from distpow_tpu.parallel.search import search
+    from distpow_tpu.parallel.search import persistent_search
     from distpow_tpu.runtime.metrics import REGISTRY
 
     threads = list(range(256))
-    if hasattr(backend, "_get_mesh"):
-        def run():
-            return search_mesh(
-                SPAN_NONCE, SPAN_NTZ, threads, mesh=backend._get_mesh(),
-                model=backend.model, batch_size=backend.batch_size,
-                launch_candidates=backend.max_launch,
-                max_hashes=SPAN_CANDIDATES,
-                step_factory=backend._step_factory(SPAN_NONCE, SPAN_NTZ,
-                                                   0, 256))
-    else:
-        def run():
-            return search(
-                SPAN_NONCE, SPAN_NTZ, threads, model=backend.model,
-                batch_size=backend.batch_size,
-                launch_candidates=backend.max_launch,
-                max_hashes=SPAN_CANDIDATES,
-                step_factory=backend._factory(SPAN_NONCE, SPAN_NTZ, 0, 256))
+
+    def run():
+        return persistent_search(
+            SPAN_NONCE, SPAN_NTZ, threads, model=backend.model,
+            batch_size=backend.batch_size,
+            launch_candidates=backend.max_launch,
+            max_hashes=SPAN_CANDIDATES,
+            step_builder=backend._step_builder(SPAN_NONCE, SPAN_NTZ, 0, 256))
+
     for timed in (False, True):
         h0 = REGISTRY.get("search.hashes")
         t0 = time.monotonic()

@@ -643,7 +643,6 @@ class Worker:
             mesh_devices=config.MeshDevices,
             max_launch=config.MaxLaunchCandidates or None,
             interpret=getattr(config, "PallasInterpret", False),
-            loop=getattr(config, "SearchLoop", "persistent") or "persistent",
         )
         self.scheduler = None
         if (getattr(config, "Scheduler", "off") or "off") == "batching":

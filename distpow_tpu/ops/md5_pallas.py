@@ -166,9 +166,9 @@ def default_geometry(model_name: str, interpret: bool = False):
     independent, and XLA:CPU's codegen on the interpreted sha256 tile
     is superlinear in tile height (the (16, 128) serving geometry
     compiles for ~20 min where (8, 128) takes ~3).  Every sublane
-    resolution site (the builder, PallasBackend, the pallas-mesh step
-    factory) goes through here so the cap cannot be bypassed by a
-    caller resolving geometry itself.
+    resolution site (the builder, the pallas mesh step factory, the
+    scheduler's pallas lane) goes through here so the cap cannot be
+    bypassed by a caller resolving geometry itself.
     """
     geom = MODEL_GEOMETRY.get(model_name, MODEL_GEOMETRY["md5"])
     return (min(geom[0], 8), geom[1]) if interpret else geom
@@ -864,7 +864,7 @@ def build_pallas_search_step(
         # the loop form takes seconds).  Off-TPU dev serving of these
         # models goes through the XLA fallback; the kernel is a
         # TPU-hardware path.  ValueError = the signal every caller
-        # (PallasBackend, the mesh step factory) already maps to a
+        # (the pallas mesh step factory, the lane planner) maps to a
         # transparent fallback.
         raise ValueError(
             f"{model.name} pallas tile is TPU-only (interpret-mode "
@@ -914,25 +914,3 @@ def build_pallas_search_step(
         return dyn(chunk0, init, base, masks_arr, part)
 
     return step
-
-
-@functools.lru_cache(maxsize=512)
-def cached_pallas_search_step(
-    nonce: bytes,
-    width: int,
-    difficulty: int,
-    tb_lo: int,
-    tb_count: int,
-    chunks_per_step: int,
-    model_name: str = "md5",
-    extra_const_chunk: bytes = b"",
-    sublanes: Optional[int] = None,
-    interpret: bool = False,
-    launch_steps: int = 1,
-    inner: Optional[int] = None,
-):
-    return build_pallas_search_step(
-        nonce, width, difficulty, tb_lo, tb_count, chunks_per_step,
-        model_name, extra_const_chunk, sublanes, interpret, launch_steps,
-        inner,
-    )

@@ -529,7 +529,7 @@ def cached_persistent_step(
 ):
     """Serving-path persistent step: binds request operands onto the
     layout-keyed multi-segment program, exactly as ``cached_search_step``
-    binds the relaunch-loop program.  Returns ``bound(chunk0, prev) ->
+    binds the width-0 probe's program.  Returns ``bound(chunk0, prev) ->
     uint32[2]`` covering up to ``segments * chunks_per_step * tb_count``
     candidates per dispatch (early exit on a hit; no work behind a
     ``prev`` that hit or halted, or behind ``CHAIN_HALT``).  Width 0 has

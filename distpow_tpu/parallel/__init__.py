@@ -1,25 +1,12 @@
-"""Parallel search: partition algebra (jax-free) + device search drivers.
+"""Parallel search: partition algebra (jax-free) + the device search driver.
 
-``search``/``mesh_search`` import jax, so they are exposed lazily —
-jax-free consumers (the native C++ backend, runtime, CLI parsers) can
-use the partition algebra without pulling the JAX compute path into
-their import graph (advisor r3; same rationale as models/__init__.py).
-
-Laziness is implemented with *properties on the module's class*, not
-PEP 562 ``__getattr__``: the public name ``search`` (the function,
-README surface) collides with the ``parallel.search`` submodule, and
-whenever anything imports the submodule first (``backends/__init__``
-does ``from ..parallel.search import ...``), the import system writes
-the MODULE into this package's ``__dict__`` — after which a module
-``__getattr__`` never fires and ``from distpow_tpu.parallel import
-search`` silently hands callers the module instead of the function
-(caught by the round-4 verify drive).  A property is a data descriptor
-on the type, so it wins over the instance ``__dict__`` regardless of
-import order.
+``persistent_search``, ``SearchResult`` and ``make_mesh`` import jax, so
+they are exposed lazily (PEP 562 ``__getattr__``) — jax-free consumers
+(the native C++ backend, runtime, CLI parsers) can use the partition
+algebra without pulling the JAX compute path into their import graph.
+None of the lazy names is also a submodule name, so an import of a
+submodule cannot shadow one.
 """
-
-import sys
-import types
 
 from .partition import (  # noqa: F401
     contiguous_bounds,
@@ -32,40 +19,16 @@ from .partition import (  # noqa: F401
 __all__ = [
     "contiguous_bounds", "remainder_bits", "split_thread_bytes",
     "thread_bytes", "worker_bits",
-    "SearchResult", "search", "persistent_search", "make_mesh",
-    "search_mesh",
+    "SearchResult", "persistent_search", "make_mesh",
 ]
 
-
-def _lazy(submodule: str, name: str) -> property:
-    """Property pair: reads resolve ``name`` from ``submodule`` (the
-    getter wins over instance ``__dict__`` by descriptor protocol);
-    writes land in ``__dict__`` so the import system's own
-    ``parallel.search = <module>`` setattr succeeds silently instead of
-    raising ImportWarning on ``import distpow_tpu.parallel.search``
-    (review r4).  Caveat (documented trap, no in-repo user):
-    ``import distpow_tpu.parallel.search as s`` binds the FUNCTION —
-    use ``from distpow_tpu.parallel.search import X`` for module
-    internals, as the whole repo already does."""
-
-    def _get(self):
-        import importlib
-
-        mod = importlib.import_module(f".{submodule}", __name__)
-        return getattr(mod, name)
-
-    def _set(self, value):
-        self.__dict__[name] = value
-
-    return property(_get, _set)
+_LAZY = {"SearchResult": "search", "persistent_search": "search",
+         "make_mesh": "mesh_search"}
 
 
-class _ParallelModule(types.ModuleType):
-    SearchResult = _lazy("search", "SearchResult")
-    search = _lazy("search", "search")
-    persistent_search = _lazy("search", "persistent_search")
-    make_mesh = _lazy("mesh_search", "make_mesh")
-    search_mesh = _lazy("mesh_search", "search_mesh")
+def __getattr__(name: str):
+    if name not in _LAZY:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    import importlib
 
-
-sys.modules[__name__].__class__ = _ParallelModule
+    return getattr(importlib.import_module(f".{_LAZY[name]}", __name__), name)

@@ -2,8 +2,7 @@
 (parallel/mesh_search.py, the lane planner, bench.py's CPU arms).
 
 Written for the installed JAX: ``jax.shard_map`` (varying-axes checking
-spelled ``check_vma``), ``jax.lax.pcast(..., to="varying")`` and the
-``jax_num_cpu_devices`` config option.  A JAX upgrade that moves one of
+spelled ``check_vma``) and the ``jax_num_cpu_devices`` config option.  A JAX upgrade that moves one of
 them is a change to this file alone.
 
 ``cpu_devices_env`` stays on the pre-init XLA host-device-count flag:
@@ -26,12 +25,6 @@ def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
     axes cannot be typed (a pallas_call output)."""
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=check_vma)
-
-
-def pvary(x, axis: str):
-    """Mark a replicated value as varying over ``axis`` (shard_map's
-    varying-manual-axes typing)."""
-    return jax.lax.pcast(x, (axis,), to="varying")
 
 
 def request_cpu_devices(n: int) -> None:

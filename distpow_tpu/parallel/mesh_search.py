@@ -6,30 +6,23 @@ thread-byte range is sub-partitioned across the devices of a mesh exactly
 the way the coordinator partitions it across workers
 (coordinator.go:326, worker.go:301-316) — prefix -> core instead of
 prefix -> RPC peer.  The "first result wins, everyone stops" protocol
-(coordinator.go:202-230) compresses onto ICI: every step ends in a
-``lax.pmin`` of the per-device first-hit flat index, so all devices
-observe a win at the same step boundary and the host stops dispatching —
-the Found broadcast without any RPC.  The persistent steps
-(``mesh_persistent_step``, ``pallas_mesh_persistent_step``) take a
-``pmin`` per on-device segment instead, and stop on the device.
+(coordinator.go:202-230) compresses onto ICI: the persistent steps
+(``mesh_persistent_step``, ``pallas_mesh_persistent_step``) end every
+on-device segment in a ``lax.pmin`` of the per-device first-hit flat
+index, so all devices observe a win at the same segment boundary and
+leave the loop together — the Found broadcast without any RPC.  The
+factories below bind them as ``step_builder``s of
+``parallel.search.persistent_search``.
 
-Two sharding regimes, chosen automatically:
-
-* **thread-byte split** (the common case): each device owns a contiguous
-  slice of the thread-byte run and scans the same chunk range in lockstep.
-* **chunk split** (when there are fewer thread bytes than devices): each
-  device owns a contiguous slice of the chunk range instead.
-
-Both regimes report hits as *global* flat indices (chunk-major,
-thread-byte-minor over the whole worker partition), so the driver's decode
-and the reference enumeration-order guarantee are identical to the
-single-device path.
+Every step reports hits as *global* flat indices (chunk-major,
+thread-byte-minor over the whole worker partition), so the driver's
+decode and the reference enumeration-order guarantee are identical to
+the single-device path.
 """
 
 from __future__ import annotations
 
 import functools
-import logging
 from typing import Optional, Sequence
 
 import jax
@@ -38,13 +31,10 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.registry import HashModel, get_hash_model
-from ..ops.difficulty import nibble_masks
 from ..ops.packing import build_tail_spec
 from ..ops.search_step import (
     SENTINEL,
     _check_launch,
-    _eval_candidates,
-    cached_search_step,
     chain_operand,
     chained_segments,
     eval_dyn_candidates,
@@ -53,14 +43,9 @@ from ..ops.search_step import (
     named_jit,
     step_operands,
 )
-from .compat import pvary as _pvary
 from .compat import shard_map as _shard_map
-from .partition import contiguous_bounds
-from .search import SearchResult, StepFactory, search
 
 AXIS = "workers"
-
-log = logging.getLogger("distpow.mesh_search")
 
 
 def make_mesh(devices: Optional[Sequence] = None, axis: str = AXIS) -> Mesh:
@@ -73,198 +58,15 @@ def make_mesh(devices: Optional[Sequence] = None, axis: str = AXIS) -> Mesh:
 
 def _on_mesh(step, mesh: Mesh):
     """Mark ``step`` as one whose every dispatch spans all the devices
-    of ``mesh``: the search drivers read ``mesh_devices`` to count its
+    of ``mesh``: the search driver reads ``mesh_devices`` to count its
     candidates under ``search.mesh_hashes`` as well as
-    ``search.hashes``, and name the device count on the width's
+    ``search.hashes``, and names the device count on the width's
     ``search.segment`` span.  The width-0 probe and the single-device
-    steps carry no mark."""
-    step.mesh_devices = int(mesh.devices.size)
+    steps carry no mark, nor do the steps of a one-device mesh (the
+    ``pallas`` backend's): their dispatches span one device."""
+    if mesh.devices.size > 1:
+        step.mesh_devices = int(mesh.devices.size)
     return step
-
-
-@functools.lru_cache(maxsize=None)
-def _dyn_mesh_step(
-    mesh: Mesh,
-    axis: str,
-    model_name: str,
-    n_blocks: int,
-    tb_loc,
-    chunk_locs,
-    batch_local: int,
-    tb_split: bool,
-    log_ndev: int,
-    launch_steps: int = 1,
-    mask_words: int = 0,  # 0 => all digest words significant
-):
-    """Layout-keyed jitted mesh step (the dynamic regime of
-    ops/search_step.py, spread over the device mesh).
-
-    Returned fn: ``(init[S], base[n_blocks,16], masks[D],
-    part[2]=(tb_lo, log_tbc), chunk0) -> uint32`` — the *global* first-hit
-    flat index after the ``lax.pmin`` collective, or SENTINEL.
-
-    ``launch_steps`` consecutive sub-batches run per dispatch in an
-    on-device fori_loop (see ops/search_step.py); each sub-batch advances
-    the global flat index by ``batch_local * n_dev`` and the chunk base by
-    the same count of candidates — identical in both sharding regimes, so
-    the loop body is regime-agnostic.
-    """
-    model = get_hash_model(model_name)
-    one = jnp.uint32(1)
-    mw = mask_words or model.digest_words
-    batch_global = batch_local << log_ndev
-    # same uint32 flat-index bound the single-device steps enforce
-    # (ops/search_step.py _check_launch) — a MaxLaunchCandidates > 2^31
-    # must raise here too, not silently wrap the global index
-    _check_launch(batch_global, launch_steps)
-
-    def body(init, base, masks, part, chunk0):
-        d = jax.lax.axis_index(axis).astype(jnp.uint32)
-        tb_lo, log_tbc = part[0], part[1]
-        fl = jnp.arange(batch_local, dtype=jnp.uint32)
-        if tb_split:
-            log_tbl = log_tbc - jnp.uint32(log_ndev)
-            chunk_off0 = fl >> log_tbl
-            tb_local = fl & ((one << log_tbl) - one)
-            tb = tb_lo + (d << log_tbl) + tb_local
-            f_global0 = (chunk_off0 << log_tbc) + (d << log_tbl) + tb_local
-        else:
-            chunks_local = jnp.uint32(batch_local) >> log_tbc
-            chunk_off0 = d * chunks_local + (fl >> log_tbc)
-            tb_idx = fl & ((one << log_tbc) - one)
-            tb = tb_lo + tb_idx
-            f_global0 = (chunk_off0 << log_tbc) + tb_idx
-        gchunks = jnp.uint32(batch_global) >> log_tbc  # chunks per sub-batch
-
-        def sub(i):
-            chunk = jnp.uint32(chunk0) + chunk_off0 + i * gchunks
-            state = eval_dyn_candidates(
-                model, n_blocks, tb_loc, chunk_locs, init, base, tb, chunk
-            )
-            hit = fold_dyn_masks(model, state, masks, mw)
-            f_global = f_global0 + i * jnp.uint32(batch_global)
-            return jnp.min(jnp.where(hit, f_global, jnp.uint32(SENTINEL)))
-
-        if launch_steps == 1:
-            m = sub(jnp.uint32(0))
-        else:
-            # the loop carry must already be device-varying (its updates
-            # depend on axis_index), or shard_map rejects the fori_loop
-            init_best = _pvary(jnp.uint32(SENTINEL), axis)
-            m = jax.lax.fori_loop(
-                0,
-                launch_steps,
-                lambda i, best: jnp.minimum(best, sub(i.astype(jnp.uint32))),
-                init_best,
-            )
-        return jax.lax.pmin(m, axis)
-
-    sharded = _shard_map(
-        body, mesh=mesh, in_specs=(P(), P(), P(), P(), P()), out_specs=P()
-    )
-    return named_jit(sharded, f"{model_name}_mesh_step")
-
-
-def _width0_probe(nonce: bytes, difficulty: int, tb_lo: int, tbc: int,
-                  model: HashModel, extra: bytes):
-    """Width-0 factory result shared by every mesh factory: 256
-    candidates max — no mesh benefit; the single-device layout-keyed
-    probe is warmup-covered."""
-    return (
-        cached_search_step(
-            bytes(nonce), 0, difficulty, tb_lo, tbc, 1,
-            model.name, bytes(extra),
-        ),
-        1,
-    )
-
-
-def _chunk_split_budget(target_chunks: int, tbc: int, n_dev: int) -> int:
-    """Per-device chunk budget for the chunk-split regime, shared by the
-    XLA and pallas mesh factories: normalize to a multiple of 256 so
-    batch_local — the compile key — is independent of which pow2
-    tbc < n_dev the request carries (one warmed program serves every
-    small partition), and divide the global budget by n_dev so one
-    dispatch never covers n_dev x the configured launch volume."""
-    eb_local = max(256, (target_chunks * tbc // n_dev) // 256 * 256)
-    return max(1, eb_local // tbc)
-
-
-@functools.lru_cache(maxsize=None)
-def _dyn_pallas_mesh_step(
-    mesh: Mesh,
-    axis: str,
-    model_name: str,
-    tb_word: int,
-    tb_shift: int,
-    chunk_word_shifts,
-    grid: int,
-    sublanes: int,
-    inner: int,
-    interpret: bool,
-    mask_words: int,
-    tb_split: bool,
-    log_ndev: int,
-    batch_local: int,
-    launch_steps: int,
-):
-    """The Pallas search kernel spread over the device mesh.
-
-    One compiled kernel program serves every device: the kernel's
-    partition descriptor and chunk base are runtime SMEM operands, so
-    inside ``shard_map`` each device derives its own from
-    ``axis_index`` — tb-split hands device ``d`` the thread-byte slice
-    ``(tb_lo + d*tbl, log2 tbl)``; chunk-split hands it a contiguous
-    chunk span ``chunk0 + d * launch_steps * chunks_local``.  The
-    kernel's local first-hit flat index is then mapped back to the TRUE
-    global flat index (chunk-major over the whole worker partition) and
-    ``lax.pmin`` picks the first hit in reference enumeration order —
-    identical driver semantics to the XLA mesh step.
-
-    Note the chunk-split DEVICE assignment differs from the XLA mesh
-    step's (contiguous spans here vs per-sub-batch interleaving there):
-    both cover the same candidate set and both return the minimal
-    global flat index, so results are bit-identical either way.
-    """
-    from ..ops.md5_pallas import _dyn_pallas_step
-
-    kernel = _dyn_pallas_step(
-        tb_word, tb_shift, chunk_word_shifts, grid, sublanes, interpret,
-        inner, mask_words, model_name,
-    )
-    one = jnp.uint32(1)
-    _check_launch(batch_local << log_ndev, launch_steps)
-    span_local = jnp.uint32(launch_steps * batch_local)
-
-    def body(init, base, masks, part, chunk0):
-        d = jax.lax.axis_index(axis).astype(jnp.uint32)
-        tb_lo, log_tbc = part[0], part[1]
-        if tb_split:
-            log_tbl = log_tbc - jnp.uint32(log_ndev)
-            part_dev = jnp.stack(
-                [tb_lo + (d << log_tbl), log_tbl]).astype(jnp.uint32)
-            f_l = kernel(jnp.uint32(chunk0), init, base, masks, part_dev)
-            chunk_off = f_l >> log_tbl
-            rest = f_l & ((one << log_tbl) - one)
-            f_g = (chunk_off << log_tbc) + (d << log_tbl) + rest
-        else:
-            chunk_span = span_local >> log_tbc  # chunks per device
-            c0_dev = jnp.uint32(chunk0) + d * chunk_span
-            f_l = kernel(c0_dev, init, base, masks, part)
-            f_g = d * span_local + f_l
-        f_g = jnp.where(f_l == jnp.uint32(SENTINEL), jnp.uint32(SENTINEL),
-                        f_g)
-        return jax.lax.pmin(f_g, axis)
-
-    # check_vma=False: pallas_call's out_shape carries no varying-axes
-    # annotation, so shard_map's per-value VMA typing cannot see that the
-    # kernel output is device-varying; the explicit pmin below is the
-    # collective that makes the result replicated regardless.
-    sharded = _shard_map(
-        body, mesh=mesh, in_specs=(P(), P(), P(), P(), P()), out_specs=P(),
-        check_vma=False,
-    )
-    return named_jit(sharded, f"{model_name}_pallas_mesh_step")
 
 
 @functools.lru_cache(maxsize=None)
@@ -358,9 +160,9 @@ def pallas_mesh_persistent_step(
 
         return chained_segments(prev, segments, search_segment)
 
-    # check_vma=False: as in _dyn_pallas_mesh_step, the pallas_call's
-    # output carries no varying-axes annotation; the per-segment pmin is
-    # what makes the loop carry replicated
+    # check_vma=False: the pallas_call's output carries no varying-axes
+    # annotation; the per-segment pmin is what makes the loop carry
+    # replicated
     sharded = _shard_map(
         body, mesh=mesh, in_specs=(P(),) * 6, out_specs=P(), check_vma=False,
     )
@@ -375,8 +177,8 @@ def _pallas_mesh_geometry(model: HashModel, n_dev: int, tbc: int,
     not a power of two, a model without a kernel, or a TPU-only tile in
     interpret mode."""
     from ..ops.md5_pallas import (
+        _TILE_FNS,
         INTERPRET_XLA_FALLBACK,
-        MODEL_GEOMETRY,
         default_geometry,
     )
 
@@ -384,221 +186,17 @@ def _pallas_mesh_geometry(model: HashModel, n_dev: int, tbc: int,
         raise ValueError("pallas mesh requires a power-of-two device count")
     if tbc & (tbc - 1):
         raise ValueError("pallas kernel requires power-of-two tb_count")
-    if model.name not in MODEL_GEOMETRY:
+    if model.name not in _TILE_FNS:
         raise ValueError(f"no pallas kernel for model {model.name}")
     if interpret and model.name in INTERPRET_XLA_FALLBACK:
         # same guard as build_pallas_search_step: interpret mode would
         # hand the unrolled limb-pair tile to XLA:CPU (pathological
-        # compile); the mesh backend maps this to its XLA fallback
+        # compile); the backend maps this to its XLA fallback
         raise ValueError(
             f"{model.name} pallas tile is TPU-only (interpret-mode "
             f"XLA:CPU compile of the limb-pair graph is pathological)"
         )
     return default_geometry(model.name, interpret)
-
-
-def _pallas_mesh_step_factory(
-    nonce: bytes,
-    difficulty: int,
-    tb_lo: int,
-    tbc: int,
-    model: HashModel,
-    mesh: Mesh,
-    axis: str,
-    sublanes: Optional[int] = None,
-    inner: Optional[int] = None,
-    interpret: bool = False,
-    max_launch: Optional[int] = None,
-) -> StepFactory:
-    """Step factory backed by the Pallas kernel per device.
-
-    Raises ValueError for configurations the kernel cannot express
-    (non-pow2 device count or partition, multi-block tails, models
-    without a kernel); ``PallasMeshBackend`` catches these per width and
-    falls back to the XLA mesh factory transparently.
-    """
-    from ..ops.md5_pallas import LANES
-
-    n_dev = int(mesh.devices.size)
-    geom = _pallas_mesh_geometry(model, n_dev, tbc, interpret)
-    if sublanes is None:
-        sublanes = geom[0]
-    if inner is None:
-        inner = geom[1]
-    tile = sublanes * LANES
-    tb_split = tbc >= n_dev and tbc % n_dev == 0
-    log_ndev = n_dev.bit_length() - 1
-    tbl = tbc // n_dev if tb_split else tbc
-
-    @functools.lru_cache(maxsize=32)
-    def bind(vw: int, extra: bytes, chunks_local: int, launch_steps: int):
-        spec = build_tail_spec(bytes(nonce), vw, model, extra)
-        if spec.n_blocks != 1:
-            raise ValueError("pallas kernel requires a single-block tail")
-        batch_local = chunks_local * tbl
-        mw = mask_words_for(difficulty, model)
-        inner_eff = max(1, inner)
-        tiles = batch_local * launch_steps // tile
-        while tiles % inner_eff:
-            inner_eff //= 2
-        grid = tiles // inner_eff
-        _, tb_w, tb_s = spec.tb_loc
-        chunk_ws = tuple((w, s) for _, w, s in spec.chunk_locs)
-        dyn = _dyn_pallas_mesh_step(
-            mesh, axis, model.name, tb_w, tb_s, chunk_ws, grid, sublanes,
-            inner_eff, interpret, mw, tb_split, log_ndev, batch_local,
-            launch_steps,
-        )
-        init, base, masks = step_operands(spec, difficulty, model)
-        part = jnp.asarray([tb_lo, tbc.bit_length() - 1], jnp.uint32)
-
-        def step(chunk0):
-            return dyn(init, base[0], masks, part, chunk0)
-
-        return _on_mesh(step, mesh)
-
-    def factory(vw: int, extra: bytes, target_chunks: int, launch_steps: int = 1):
-        if vw == 0:
-            return _width0_probe(nonce, difficulty, tb_lo, tbc, model, extra)
-        if tb_split:
-            chunks_local = max(1, target_chunks)
-        else:
-            chunks_local = _chunk_split_budget(target_chunks, tbc, n_dev)
-        batch_local = chunks_local * tbl
-        # round the per-device batch up to a whole tile grid
-        if batch_local % tile:
-            batch_local = ((batch_local // tile) + 1) * tile
-            chunks_local = max(1, batch_local // tbl)
-            batch_local = chunks_local * tbl
-            if batch_local % tile:
-                raise ValueError(
-                    f"per-device batch {batch_local} (tbl={tbl}) cannot "
-                    f"align to tile {tile}"
-                )
-        # re-clamp the launch multiplier to the rounded GLOBAL batch:
-        # the driver computed launch_steps for the unrounded batch, and
-        # the launch must respect both the dispatch budget and the
-        # uint32/int32 flat-index bound
-        batch_global = batch_local << log_ndev
-        budget = min(max_launch or (1 << 31) - 1, (1 << 31) - 1)
-        k = max(1, min(launch_steps, budget // batch_global))
-        step = bind(vw, bytes(extra), chunks_local, k)
-        global_chunks = (chunks_local if tb_split
-                         else chunks_local * n_dev) * k
-        return step, global_chunks
-
-    # resolved geometry, exposed so tests can pin the interpret-mode
-    # sublanes cap at this site (default_geometry's third caller)
-    factory.sublanes = sublanes
-    factory.inner = inner
-    return factory
-
-
-def _mesh_step_factory(
-    nonce: bytes,
-    difficulty: int,
-    tb_lo: int,
-    tbc: int,
-    model: HashModel,
-    mesh: Mesh,
-    axis: str,
-) -> StepFactory:
-    n_dev = int(mesh.devices.size)
-    tb_split = tbc >= n_dev and tbc % n_dev == 0
-    pow2 = (tbc & (tbc - 1)) == 0 and (n_dev & (n_dev - 1)) == 0
-
-    @functools.lru_cache(maxsize=32)
-    def bind_dyn(vw: int, extra: bytes, chunks_local: int, launch_steps: int):
-        spec = build_tail_spec(bytes(nonce), vw, model, extra)
-        tbl = tbc // n_dev if tb_split else tbc
-        dyn = _dyn_mesh_step(
-            mesh, axis, model.name, spec.n_blocks, spec.tb_loc,
-            spec.chunk_locs, chunks_local * tbl, tb_split,
-            n_dev.bit_length() - 1, launch_steps,
-            mask_words_for(difficulty, model),
-        )
-        init, base, masks = step_operands(spec, difficulty, model)
-        part = jnp.asarray([tb_lo, tbc.bit_length() - 1], jnp.uint32)
-
-        def step(chunk0):
-            return dyn(init, base, masks, part, chunk0)
-
-        return _on_mesh(step, mesh)
-
-    @functools.lru_cache(maxsize=32)
-    def build_static(vw: int, extra: bytes, chunks_local: int):
-        """Fallback for non-power-of-two partitions or device counts."""
-        # say at REQUEST time why this request is about to stall
-        # (VERDICT r2 weak #5): these programs bake the nonce, so no
-        # warmup can cover them and each fresh nonce recompiles
-        log.warning(
-            "compiling a nonce-keyed static mesh program (devices=%d, "
-            "tbc=%d — not both powers of two): expect a multi-second "
-            "compile stall for each fresh nonce on this mesh; real TPU "
-            "slices are powers of two and serve from warmed layout-keyed "
-            "programs instead", n_dev, tbc,
-        )
-        spec = build_tail_spec(bytes(nonce), vw, model, extra)
-        masks = nibble_masks(difficulty, model)
-
-        if tb_split:
-            tbl = tbc // n_dev
-
-            def body(chunk0):
-                d = jax.lax.axis_index(axis).astype(jnp.uint32)
-                fl = jnp.arange(chunks_local * tbl, dtype=jnp.uint32)
-                chunk_off = fl // jnp.uint32(tbl)
-                tb_local = fl % jnp.uint32(tbl)
-                tb = jnp.uint32(tb_lo) + d * jnp.uint32(tbl) + tb_local
-                chunk = jnp.uint32(chunk0) + chunk_off
-                hit = _eval_candidates(spec, masks, model, tb, chunk)
-                f_global = (
-                    chunk_off * jnp.uint32(tbc)
-                    + d * jnp.uint32(tbl)
-                    + tb_local
-                )
-                m = jnp.min(jnp.where(hit, f_global, jnp.uint32(SENTINEL)))
-                return jax.lax.pmin(m, axis)
-
-        else:
-
-            def body(chunk0):
-                d = jax.lax.axis_index(axis).astype(jnp.uint32)
-                fl = jnp.arange(chunks_local * tbc, dtype=jnp.uint32)
-                chunk_off_local = fl // jnp.uint32(tbc)
-                tb_idx = fl % jnp.uint32(tbc)
-                chunk_off = d * jnp.uint32(chunks_local) + chunk_off_local
-                tb = jnp.uint32(tb_lo) + tb_idx
-                chunk = jnp.uint32(chunk0) + chunk_off
-                hit = _eval_candidates(spec, masks, model, tb, chunk)
-                f_global = chunk_off * jnp.uint32(tbc) + tb_idx
-                m = jnp.min(jnp.where(hit, f_global, jnp.uint32(SENTINEL)))
-                return jax.lax.pmin(m, axis)
-
-        sharded = _shard_map(body, mesh=mesh, in_specs=P(), out_specs=P())
-        return _on_mesh(named_jit(sharded, f"{model.name}_static_mesh_step"),
-                        mesh)
-
-    def factory(vw: int, extra: bytes, target_chunks: int, launch_steps: int = 1):
-        if vw == 0:
-            return _width0_probe(nonce, difficulty, tb_lo, tbc, model, extra)
-        if tb_split:
-            # every device scans the same chunks on its own tb slice
-            chunks_local = max(1, target_chunks)
-        else:
-            chunks_local = _chunk_split_budget(target_chunks, tbc, n_dev)
-        if pow2:
-            k = max(1, launch_steps)
-            step = bind_dyn(vw, bytes(extra), chunks_local, k)
-        else:
-            # nonce-keyed static fallback compiles per request anyway;
-            # multi-sub-batch launches are not worth a bespoke program
-            k = 1
-            step = build_static(vw, bytes(extra), chunks_local)
-        global_chunks = (chunks_local if tb_split else chunks_local * n_dev) * k
-        return step, global_chunks
-
-    return factory
 
 
 @functools.lru_cache(maxsize=None)
@@ -901,35 +499,3 @@ def pallas_mesh_persistent_factory(
         return _on_mesh(bound, mesh), chunks_each, chunks_each * n_seg
 
     return builder
-
-
-def search_mesh(
-    nonce: bytes,
-    difficulty: int,
-    thread_bytes: Sequence[int],
-    *,
-    mesh: Optional[Mesh] = None,
-    axis: str = AXIS,
-    model: Optional[HashModel] = None,
-    step_factory: Optional[StepFactory] = None,
-    **kwargs,
-) -> Optional[SearchResult]:
-    """Mesh-parallel ``search`` with identical semantics and result decode.
-
-    ``step_factory`` overrides the default XLA mesh factory, e.g. with
-    the kernel-backed ``PallasMeshBackend._step_factory``.
-    """
-    model = model or get_hash_model("md5")
-    mesh = mesh if mesh is not None else make_mesh()
-    tb_lo, tbc = contiguous_bounds(thread_bytes)
-    factory = step_factory or _mesh_step_factory(
-        bytes(nonce), difficulty, tb_lo, tbc, model, mesh, axis
-    )
-    return search(
-        nonce,
-        difficulty,
-        thread_bytes,
-        model=model,
-        step_factory=factory,
-        **kwargs,
-    )

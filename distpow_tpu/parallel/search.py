@@ -1,4 +1,4 @@
-"""Batched search drivers (single-device and factory-pluggable).
+"""The search driver: chained persistent dispatches over one solve.
 
 Replaces the reference worker's ``miner`` goroutine hot loop
 (worker.go:258-401).  Differences dictated by the accelerator model
@@ -18,6 +18,9 @@ Replaces the reference worker's ``miner`` goroutine hot loop
 * Dispatches are pipelined (depth 2 by default) so the host prepares launch
   N+1 while the device crunches launch N; results are drained FIFO, which
   preserves reference enumeration order for the returned first match.
+* Every device backend serves through :func:`persistent_search`; a
+  backend's ``step_builder`` picks the device form of each width's
+  dispatches (the mesh steps, the Pallas kernel) under one contract.
 
 Batch-boundary note: a width-``w`` launch whose chunk range overruns
 ``256**w`` hashes candidates whose ``w``-byte little-endian chunk encoding
@@ -124,17 +127,6 @@ def effective_batch(batch_size: int) -> int:
     every pow2 tbc <= 256."""
     return max(256, batch_size - batch_size % 256)
 
-# A step factory maps (variable_width, extra_const_chunk, target_chunks,
-# launch_steps) to (step_fn, chunks_per_step) where step_fn(chunk0)->uint32
-# evaluates chunks_per_step * tb_count candidates starting at chunk0 and
-# returns the flat index (chunk-major, thread-byte-minor, i.e. reference
-# enumeration order, worker.go:318-319) of the first hit, or SENTINEL.
-# ``launch_steps`` asks for that many target_chunks-sized sub-batches per
-# dispatch; a factory may serve fewer — the driver always trusts the
-# returned chunks_per_step.
-StepFactory = Callable[[int, bytes, int, int], Tuple[Callable, int]]
-
-
 @dataclass
 class SearchResult:
     secret: bytes
@@ -198,7 +190,7 @@ def _register_mesh_hashes() -> None:
 
 # canonical home is the jax-free partition module (advisor r3: the
 # native backend validates runs without importing the JAX compute path);
-# re-exported here because the driver and both device backends import it
+# re-exported here because the driver and the device backends import it
 # from this module.
 from .partition import contiguous_bounds  # noqa: E402,F401
 
@@ -208,7 +200,7 @@ def assemble_secret(
 ) -> Tuple[bytes, int]:
     """Host-side inverse of a launch's flat index: ``(secret, tb)``.
 
-    One home for the candidate reconstruction both drivers share — the
+    One home for the candidate reconstruction two drains share — the
     solo FIFO drain below and the continuous-batching scheduler's
     per-slot drain (sched/engine.py).  The width mask reproduces the
     launch-overrun aliasing documented in the module docstring: an
@@ -241,7 +233,7 @@ def width_segments(width: int):
 
 def _unsatisfiable_wait(model: HashModel, difficulty: int, cancel_check,
                         max_hashes) -> None:
-    """Shared unsatisfiable-difficulty gate (both drivers).
+    """The driver's unsatisfiable-difficulty gate.
 
     Unsatisfiable: the digest only has max_difficulty nibbles.  The
     reference would brute-force forever (worker.go:246-256 never
@@ -267,221 +259,6 @@ def _unsatisfiable_wait(model: HashModel, difficulty: int, cancel_check,
         if max_hashes is not None:
             return None
         time.sleep(0.01)
-
-
-def default_step_factory(
-    nonce: bytes,
-    difficulty: int,
-    tb_lo: int,
-    tb_count: int,
-    model: HashModel,
-) -> StepFactory:
-    """Single-device factory over the fused XLA search step."""
-
-    def factory(vw: int, extra: bytes, target_chunks: int, launch_steps: int = 1):
-        chunks = max(1, target_chunks) if vw else 1
-        k = launch_steps if vw else 1
-        step = cached_search_step(
-            bytes(nonce), vw, difficulty, tb_lo, tb_count,
-            chunks, model.name, extra, k,
-        )
-        return step, chunks * k
-
-    return factory
-
-
-def search(
-    nonce: bytes,
-    difficulty: int,
-    thread_bytes: Sequence[int],
-    *,
-    model: Optional[HashModel] = None,
-    batch_size: int = DEFAULT_BATCH,
-    pipeline_depth: int = DEFAULT_PIPELINE_DEPTH,
-    cancel_check: Optional[Callable[[], bool]] = None,
-    max_hashes: Optional[int] = None,
-    max_width: int = 8,
-    step_factory: Optional[StepFactory] = None,
-    launch_candidates: Optional[int] = None,
-) -> Optional[SearchResult]:
-    """Find the first (reference-enumeration-order) solving secret.
-
-    Returns None if cancelled or ``max_hashes`` exhausted.  ``step_factory``
-    overrides the launch builder — the mesh driver (parallel/mesh_search.py)
-    and the Pallas kernel path (ops/md5_pallas.py) plug in here.
-    ``launch_candidates`` defaults to the model's cost-scaled budget
-    (``scaled_launch_candidates``) so a direct library caller gets the
-    same ~0.1-0.25 s launch granularity a backend would.
-    """
-    model = model or get_hash_model("md5")
-    if launch_candidates is None:
-        launch_candidates = scaled_launch_candidates(model.cost_ops)
-    nonce = bytes(nonce)
-    tb_lo, tbc = contiguous_bounds(thread_bytes)
-    if difficulty > model.max_difficulty:
-        return _unsatisfiable_wait(model, difficulty, cancel_check,
-                                   max_hashes)
-    factory = step_factory or default_step_factory(
-        nonce, difficulty, tb_lo, tbc, model
-    )
-    target_chunks = max(1, effective_batch(batch_size) // tbc)
-
-    hashes = 0
-    # FIFO of in-flight launches:
-    # (result, chunk0, var_width, extra, n_cand, on_mesh)
-    inflight: deque = deque()
-
-    def count(n_cand: int, on_mesh: bool) -> None:
-        nonlocal hashes
-        hashes += n_cand
-        metrics.inc("search.hashes", n_cand)
-        if on_mesh:
-            metrics.inc("search.mesh_hashes", n_cand)
-
-    def drain_one() -> Optional[SearchResult]:
-        WATCHDOG.beat()  # about to block on a device result fetch
-        res, chunk0, vw, extra, n_cand, on_mesh = inflight.popleft()
-        count(n_cand, on_mesh)
-        # the sanctioned host sync: time blocked on the launch's result
-        # fetch (pipelined, so a busy pipeline shows near-zero waits; a
-        # dry one shows the full device round trip).  Counted as a
-        # blocking sync: the conversion is issued without readiness
-        # confirmed, which is exactly what the persistent driver's
-        # polling drain avoids (bench.py --serving-loop measures the
-        # two against each other)
-        metrics.inc("search.blocking_syncs")
-        # per-dispatch forensics span: the trace id rides the miner
-        # thread's binding (nodes/worker.py SPANS.bind), so a request's
-        # launches line up under it on the stitched timeline
-        # (docs/FORENSICS.md)
-        with SPANS.span("search.launch", n_cand=n_cand):
-            f = int(res)
-        _RATE_METER.note(n_cand)
-        if f == SENTINEL:
-            return None
-        secret, tb = assemble_secret(chunk0, f, vw, extra, tb_lo, tbc)
-        chunk_bytes = secret[1:]
-        if not puzzle.check_secret(nonce, secret, difficulty, model.name):
-            raise RuntimeError(
-                f"kernel returned non-solving candidate tb={tb} "
-                f"chunk={chunk_bytes.hex()} (kernel/oracle divergence)"
-            )
-        return SearchResult(
-            secret=secret, thread_byte=tb, chunk=chunk_bytes, hashes_tried=hashes
-        )
-
-    def drain_all() -> Optional[SearchResult]:
-        while inflight:
-            found = drain_one()
-            if found is not None:
-                return found
-        return None
-
-    def flush_inflight_counts() -> None:
-        """Account launches still in flight at an early exit WITHOUT
-        draining them (the device completes them either way; fetching
-        would add a round trip per launch).  Keeps search.hashes equal
-        to dispatched work on every exit path — found, cancelled, or
-        budget — while SearchResult.hashes_tried remains the DRAINED
-        count (the enumeration-position bound at the find)."""
-        while inflight:
-            *_, n, on_mesh = inflight.popleft()
-            count(n, on_mesh)
-
-    # The active() window covers every dispatch and drain: if the device
-    # hangs mid-search, beats stop and the watchdog (if the worker
-    # enabled it — WorkerConfig.DeviceHangTimeoutS) converts the zombie
-    # into a visible process death (runtime/watchdog.py).  The rate
-    # meter brackets the same window: its refcount zeroes the
-    # hashes_per_s gauge when the LAST concurrent search exits, on
-    # every exit path (found / cancelled / budget / error).
-    _register_mesh_hashes()
-    _RATE_METER.enter()
-    try:
-        with WATCHDOG.active():
-            for width in range(0, max_width + 1):
-                for vw, lo, hi, extra in width_segments(width):
-                    # one span per width segment: a device gap inside a
-                    # solve then names the segment whose drain or first
-                    # dispatch left it
-                    dispatches = 0
-                    with SPANS.span("search.segment", width=width,
-                                    dispatches=0) as seg:
-                        WATCHDOG.beat()  # factory may compile (bounded gap)
-                        k = launch_steps_for(vw, target_chunks, tbc,
-                                             launch_candidates)
-                        step, chunks_per_step = factory(vw, extra,
-                                                        target_chunks, k)
-                        # a mesh factory marks its steps (mesh_search.py
-                        # _on_mesh); the probe and one-device steps do not
-                        devices = getattr(step, "mesh_devices", None)
-                        seg.annotate(devices=devices or 1)
-                        chunk0 = lo
-                        while chunk0 < hi:
-                            # A launch's compiled span can overshoot the
-                            # segment end (the chunk count is a compile-time
-                            # shape; the tail launch is not re-compiled
-                            # smaller).  Overshot chunk ints alias back into
-                            # already-covered candidates via the width mask —
-                            # harmless for first-hit order (an aliased hit
-                            # implies an equal in-launch or already-scanned
-                            # hit) — but they are NOT searched work: count
-                            # only the in-segment candidates, or hashes_tried
-                            # / search.hashes inflate by orders of magnitude
-                            # on small partitions and max_hashes budgets
-                            # misfire (found by the round-4 differential
-                            # fuzz: a [240,241] partition reported 16.7M
-                            # hashes for a 4.8k-candidate solve).
-                            n_cand = min(chunks_per_step, hi - chunk0) * tbc
-                            WATCHDOG.beat()
-                            if cancel_check is not None and cancel_check():
-                                flush_inflight_counts()
-                                metrics.inc("search.cancelled")
-                                return None
-                            if max_hashes is not None and hashes >= max_hashes:
-                                found = drain_all()
-                                # drain_all stops at the first hit: launches
-                                # still in flight behind it must be counted
-                                # (search.hashes == dispatched work on every
-                                # exit path, flush_inflight_counts)
-                                flush_inflight_counts()
-                                if found is not None:
-                                    metrics.inc("search.found")
-                                return found
-                            if chunk0 == lo:
-                                # the segment's FIRST launch pays the compile
-                                # when the layout cache is cold (an unwarmed
-                                # width or model): one uninterruptible gap
-                                # that can far exceed the hang timeout for
-                                # the biggest graphs (sha512 unrolled:
-                                # >22 min observed) — widen the
-                                # window for just this launch so an armed
-                                # watchdog does not kill a healthy worker
-                                # mid-compile
-                                with WATCHDOG.grace(FIRST_COMPILE_GRACE_S):
-                                    res = step(chunk0 & 0xFFFFFFFF)
-                            else:
-                                res = step(chunk0 & 0xFFFFFFFF)
-                            metrics.inc("search.launches")
-                            dispatches += 1
-                            seg.annotate(dispatches=dispatches)
-                            inflight.append((res, chunk0, vw, extra, n_cand,
-                                             devices is not None))
-                            chunk0 += chunks_per_step
-                            if len(inflight) >= pipeline_depth:
-                                found = drain_one()
-                                if found is not None:
-                                    flush_inflight_counts()
-                                    metrics.inc("search.found")
-                                    return found
-                        found = drain_all()
-                        if found is not None:
-                            flush_inflight_counts()
-                            metrics.inc("search.found")
-                            return found
-        return None
-    finally:
-        _RATE_METER.exit()
 
 
 # Host-side poll cadence while a launch result is not yet ready.  Short
@@ -548,11 +325,13 @@ def persistent_search(
     poll_interval_s: float = DEFAULT_POLL_INTERVAL_S,
     step_builder: Optional[Callable] = None,
 ) -> Optional[SearchResult]:
-    """Persistent-loop twin of :func:`search` — same contract, same
-    first-hit enumeration order, byte-identical results (the golden
-    parity suite, tests/test_serving_loop.py, asserts it).
+    """Find the first (reference-enumeration-order) solving secret.
 
-    Four differences from the relaunch loop (docs/SERVING.md):
+    Returns None if cancelled or ``max_hashes`` exhausted.
+    ``launch_candidates`` defaults to the model's cost-scaled budget
+    (``scaled_launch_candidates``), so a direct library caller gets the
+    launch granularity a backend would.  Four properties of the loop
+    (docs/SERVING.md):
 
     * each dispatch is a multi-segment on-device loop
       (``cached_persistent_step``) that early-exits on the first hit,
@@ -569,8 +348,7 @@ def persistent_search(
       (``jax.Array.is_ready`` — a cheap flag query, not a result
       fetch) and only then reads it, whole, in one host transfer, so
       the host never blocks inside a result fetch and never queues an
-      indexing program behind the next dispatch
-      (``search.blocking_syncs`` stays flat; the waiting time is
+      indexing program behind the next dispatch (the waiting time is
       observable as ``search.poll`` spans);
     * cancellation stops issuing dispatches the moment it is observed:
       the host returns immediately, and the abandoned device work is
@@ -583,16 +361,17 @@ def persistent_search(
     of 2 it is drained before a second persistent dispatch is issued,
     so at most one width-1 dispatch runs behind a probe hit.
 
-    ``step_builder`` is the launch-lane hook (sched/lanes.py
-    ``persistent_step_builder``): called per width segment as
+    ``step_builder`` is the device-form hook (the backends, and
+    sched/lanes.py ``persistent_step_builder``): called per width
+    segment as
     ``step_builder(vw, extra, target_chunks, k)`` it may return
     ``(step, chunks_each, chunks_per_step)`` — a drop-in for the
     default single-device persistent step with the identical
     ``(chunk0, prev) -> uint32[2]`` contract and first-hit order over
     the same global candidate span — or None to keep the default for
-    that width.  The mesh lane serves every dispatch across all local
-    devices this way; enumeration order (and so results) stays
-    byte-identical.
+    that width.  The mesh steps serve every dispatch across the local
+    devices this way, and the Pallas mesh step runs the kernel;
+    enumeration order (and so results) stays byte-identical.
     """
     model = model or get_hash_model("md5")
     if launch_candidates is None:
@@ -608,8 +387,11 @@ def persistent_search(
     hashes = 0
     # FIFO of in-flight dispatches over the whole solve:
     # (res, chunk0, vw, extra, seg_chunks, chunks_each, on_mesh)
-    # where seg_chunks is the dispatch's IN-SEGMENT chunk span (the
-    # overscan clip the serial driver documents at its n_cand line),
+    # where seg_chunks is the dispatch's IN-SEGMENT chunk span: a
+    # dispatch's compiled span can overshoot the segment end, and the
+    # overshot chunk ints alias back into covered candidates through the
+    # width mask, so they are not searched work and are not counted
+    # (else hashes_tried and max_hashes inflate on small partitions);
     # chunks_each the chunk count of one on-device segment and on_mesh
     # whether the step spans the worker's mesh.  vw == 0 is the width-0
     # probe, outside the chain.
@@ -633,11 +415,10 @@ def persistent_search(
         # device leaves is_ready() false forever, and beating here
         # would mask exactly the staleness the hang watchdog exists to
         # convert into a visible death — the poll wait accumulates
-        # staleness like the serial driver's blocking fetch does
+        # staleness like a blocking fetch would
         if not res.is_ready():
-            # the persistent twin of the serial driver's search.launch
-            # span (same thread-bound trace id); a wait the cancel ends
-            # is not recorded
+            # the wait for a dispatch's result, under the miner thread's
+            # trace id; a wait the cancel ends is not recorded
             with SPANS.span("search.poll") as wait:
                 while not res.is_ready():
                     if cancel_check is not None and cancel_check():
@@ -748,9 +529,9 @@ def persistent_search(
                                 )
                                 chunks_each = target_chunks
                                 chunks_per_step = target_chunks * k
-                        # the mesh lane's steps carry the mark
-                        # (mesh_search.py _on_mesh); the probe and the
-                        # single-device step do not
+                        # the mesh steps carry the mark (mesh_search.py
+                        # _on_mesh); the probe and the single-device
+                        # steps do not
                         devices = getattr(step, "mesh_devices", None)
                         seg.annotate(devices=devices or 1)
                         chunk0 = lo
@@ -766,8 +547,10 @@ def persistent_search(
                             if first_launch:
                                 first_launch = False
                                 # first dispatch of a segment may compile
-                                # (same grace rationale as the serial
-                                # driver's cold-layout launch)
+                                # when the layout cache is cold: one
+                                # uninterruptible gap that can far exceed
+                                # the hang timeout for the biggest graphs,
+                                # so the watchdog widens its window for it
                                 with WATCHDOG.grace(FIRST_COMPILE_GRACE_S):
                                     res = step0(c) if vw == 0 else \
                                         step(c, chain.operand())

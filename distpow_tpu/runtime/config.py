@@ -240,13 +240,6 @@ class WorkerConfig:
     JaxCoordinator: str = ""
     JaxNumProcesses: int = 1
     JaxProcessId: int = 0
-    # Serving loop for the single-device XLA backend (docs/SERVING.md):
-    # "persistent" (default) drives the multi-segment on-device search
-    # loop with a polling drain — the host never blocks inside a
-    # per-launch result fetch; "serial" keeps the pre-persistent
-    # launch/fetch/relaunch loop (the bench.py --serving-loop baseline
-    # and the escape hatch).
-    SearchLoop: str = "persistent"
     # Dev-only: run the pallas/pallas-mesh kernels in interpret mode so
     # kernel-backed workers can serve off-TPU (CI, the CPU mesh demo).
     # Orders of magnitude slower than the XLA step on CPU — never set in

@@ -18,14 +18,11 @@ declaration distpow-lint's ``metrics-registry`` rule verifies every
 * ``search.mesh_hashes``   — the part of ``search.hashes`` whose
   dispatches spanned every device of the worker's mesh (the steps the
   mesh factories mark, parallel/mesh_search.py ``_on_mesh``); the
-  width-0 probe and single-device steps add nothing here
+  width-0 probe, single-device steps and one-device meshes add
+  nothing here
 * ``search.launches``      — device dispatches
 * ``search.cancelled``     — searches stopped by a cancel check
 * ``search.found``         — searches that returned a secret
-* ``search.blocking_syncs`` — result conversions issued WITHOUT
-  readiness confirmed (the serial drain's per-launch ``int(res)``;
-  the persistent loop's polling drain keeps this flat —
-  parallel/search.py, docs/SERVING.md)
 * ``search.persistent_steps`` — on-device sub-batches (segments)
   executed inside persistent-loop dispatches (early-exit means this
   can be far below the dispatched segment budget)
@@ -77,14 +74,14 @@ declaration distpow-lint's ``metrics-registry`` rule verifies every
 * ``compile_cache.errors`` (+ ``.read_errors`` / ``.write_errors`` /
   ``.keygen_errors``) — persistent XLA cache failures
   (runtime/compile_cache.py)
-* ``fallback.pallas_step`` — width segments the ``pallas`` backend
-  served through the fused XLA step because the kernel refused them
-  (backends/pallas_backend.py)
 * ``fallback.pallas_mesh_step`` — requests or width segments the
-  ``pallas-mesh`` backend served through the XLA mesh steps instead of
-  the kernel (backends/__init__.py)
+  ``pallas`` and ``pallas-mesh`` backends served through the XLA mesh
+  steps instead of the kernel (backends/__init__.py)
 * ``fallback.lane_demotions`` — launch lanes demoted to a slower lane
-  after a build or dispatch failure (sched/lanes.py)
+  after a build or dispatch failure (sched/lanes.py), and widths the
+  mesh backends served through the single-device persistent step
+  because the segment batch does not divide across the mesh
+  (backends/__init__.py)
 * ``fallback.warmup_failures`` — boot warmups that raised
   (nodes/worker.py); chip_smoke.py fails when any ``fallback.*``
   counter is above 0
@@ -238,7 +235,7 @@ Number = Union[int, float]
 # docstring list above and this set in sync (test_lint.py asserts it).
 KNOWN_COUNTERS = frozenset({
     "search.hashes", "search.launches", "search.cancelled", "search.found",
-    "search.blocking_syncs", "search.persistent_steps",
+    "search.persistent_steps",
     "search.halted_dispatches", "search.mesh_hashes",
     "worker.mine_rpcs", "worker.found_rpcs", "worker.cancel_rpcs",
     "worker.results_sent", "worker.forward_retries",
@@ -257,7 +254,7 @@ KNOWN_COUNTERS = frozenset({
     "coord.abandoned_resyncs",
     "compile_cache.errors", "compile_cache.read_errors",
     "compile_cache.write_errors", "compile_cache.keygen_errors",
-    "fallback.pallas_step", "fallback.pallas_mesh_step",
+    "fallback.pallas_mesh_step",
     "fallback.lane_demotions", "fallback.warmup_failures",
     "telemetry.dropped_events", "telemetry.dumps",
     "obs.scrapes", "obs.scrape_failures",

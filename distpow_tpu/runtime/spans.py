@@ -60,8 +60,8 @@ Span-name vocabulary (kept small and documented — docs/FORENSICS.md):
 ``powlib.mine``, ``coord.mine``, ``coord.fanout``,
 ``coord.first_result``, ``coord.cancel_storm``, ``coord.reassign``,
 ``fleet.hedge``, ``fleet.lease_expiry``, ``worker.solve``,
-``worker.result_forward``, ``sched.slot``, ``search.launch``,
-``search.poll``, ``search.segment``.
+``worker.result_forward``, ``sched.slot``, ``search.poll``,
+``search.segment``.
 """
 
 from __future__ import annotations

@@ -10,7 +10,7 @@ both gaps at the launch layer: each group resolves to a ranked **lane**
 * ``pallas`` — the hand-written per-model kernel (ops/md5_pallas.py),
   one kernel dispatch per slot lane sharing a single host sync; TPU
   hardware (or the interpret dev knob), pow2 geometry validated through
-  the same ``plan_launch_geometry`` the pallas backend plans with.
+  ``plan_launch_geometry``.
 * ``mesh`` — the vmapped slot step spread over every local device
   (parallel/mesh_search.py ``mesh_slot_search_step``): one launch
   covers ``n_dev x MESH_SPAN x batch`` candidates per slot, the
@@ -31,9 +31,8 @@ WorkerConfig (``override`` here) pins the ranking for operators and
 tests.  Every launch counts ``sched.lane_launches.<lane>`` per group
 served (runtime/metrics.py registry).
 
-The solo/persistent route shares the planner through
-``persistent_step_builder``: a multi-device worker with
-``SearchLoop="persistent"`` serves each dispatch through the mesh
+The solo route shares the planner through ``persistent_step_builder``:
+a multi-device ``jax`` worker serves each dispatch through the mesh
 persistent step (``mesh_persistent_factory``) and so does the fleet
 self-calibration that measures through ``backend.search`` — a mesh
 worker advertises its real multi-chip rate with zero coordinator
@@ -43,6 +42,7 @@ changes (docs/FLEET.md).
 from __future__ import annotations
 
 import logging
+import math
 from dataclasses import dataclass
 from typing import Dict, Optional, Set, Tuple
 
@@ -142,17 +142,83 @@ class _PallasGroupStep:
         return self._step(*ops)
 
 
+def plan_launch_geometry(target_chunks: int, tbc: int, tile: int,
+                         inner: int, launch_steps: int,
+                         max_launch: int) -> Tuple[int, int, int]:
+    """Pick the kernel launch geometry ``(batch, chunks, k)`` for one
+    dispatch — pure math, so the k-selection rules are unit-testable on
+    CPU.
+
+    * the batch rounds UP to a whole number of ``tile``-sized grids and
+      ``k`` (the launch multiplier) re-clamps to the rounded batch so
+      ``batch * k`` stays within ``max_launch``;
+    * for non-power-of-two tiles with ``inner > 1``, ``k`` is rounded
+      down to a power of two ONLY when that keeps (or makes, after a
+      marginal <=2% whole-tile batch growth) the inner loop effective —
+      when the growth conditions fail, the ORIGINAL k is kept: rounding
+      unconditionally cost non-pow2 tiles with a non-pow2 multiplier up
+      to ~2x launch amortization for nothing.
+    """
+    chunks = max(1, target_chunks)
+    batch = chunks * tbc
+    # round the batch up to a whole tile grid
+    if batch % tile:
+        batch = ((batch // tile) + 1) * tile
+        chunks = max(1, batch // tbc)
+    # re-clamp the launch multiplier to the ROUNDED batch: the driver
+    # computed launch_steps for the unrounded one, and rounded_batch * k
+    # must stay within the uint32 flat-index bound (_check_launch) and
+    # the dispatch budget
+    k = max(1, min(launch_steps, max_launch // batch))
+    # keep the tuned inner effective for non-power-of-two tiles (the
+    # sweep-best sublanes=24 geometries): the kernel shrinks inner until
+    # it divides the per-dispatch tile count, and a 24-sublane tile
+    # leaves 2^21 candidates at 683 tiles — prime — so with an odd
+    # launch multiplier the inner loop collapses all the way to 1, which
+    # kept those geometries unshippable.  Two
+    # bounded moves fix it: round k down to a power of two (so the
+    # dispatch tile count carries pow2 factors), then grow the batch by
+    # whole tiles until k*n_tiles divides inner — but ONLY when the
+    # growth is marginal (<=2%) and the k clamp is unaffected; otherwise
+    # keep the shrink-inner behavior (an uncapped version of this grew
+    # small width segments 4x and blew the dispatch budget the k clamp
+    # above enforces).  For all-power-of-two geometries
+    # every condition already holds: no-op.
+    if inner > 1 and (tile & (tile - 1)):
+        # the pow2 rounding only commits together with a batch that
+        # makes inner effective; when the growth conditions fail, the
+        # ORIGINAL k is kept (shrink-inner behavior)
+        k2 = 1 << (k.bit_length() - 1)
+        need = inner // math.gcd(k2, inner)
+        n = batch // tile
+        if n % need == 0:
+            k = k2
+        else:
+            cap = batch + max(tile, batch // 50)
+            grown = n + (need - n % need)
+            while grown * tile <= cap and (grown * tile) % tbc:
+                grown += need
+            gbatch = grown * tile
+            # the k in use must still fit the budget at the grown batch
+            # (compare in pow2-rounded space)
+            reclamp = max(1, min(launch_steps, max_launch // gbatch))
+            if (gbatch <= cap and gbatch % tbc == 0
+                    and 1 << (reclamp.bit_length() - 1) >= k2):
+                batch = gbatch
+                chunks = max(1, batch // tbc)
+                k = k2
+    return batch, chunks, k
+
+
 def build_pallas_group_step(gdef: tuple, batch: int,
                             caps: LaneCaps) -> _PallasGroupStep:
     """Build the pallas lane for one launch group, or raise ValueError
     when the kernel cannot express it (no tile for the model,
     multi-block tail, off-TPU without the interpret knob, or a batch
     that does not align to the kernel's pow2 tile grid as judged by
-    ``plan_launch_geometry`` — the same planner the pallas backend
-    uses).  The raise IS the demotion signal."""
+    ``plan_launch_geometry``).  The raise IS the demotion signal."""
     import jax.numpy as jnp
 
-    from ..backends.pallas_backend import plan_launch_geometry
     from ..models.registry import get_hash_model
     from ..ops.md5_pallas import (
         INTERPRET_XLA_FALLBACK,

@@ -5,7 +5,7 @@ tests in tests/test_pallas.py).
 For each model this drives the kernel step over several launch windows
 at several difficulties — hit and miss cases — recomputing the expected
 uint32 first-hit flat index with hashlib on the host, then runs one
-PallasBackend end-to-end solve against the Python reference search.
+``pallas`` backend end-to-end solve against the Python reference search.
 This is the hardware half of the kernel test strategy: the tile *math*
 is hashlib-pinned eagerly in tests/test_pallas.py; what only the chip
 can prove is the Mosaic-compiled integration — packing words through
@@ -98,9 +98,9 @@ def check_model(mname: str) -> None:
         print(f"[parity] {mname} d={difficulty}: 6 windows identical "
               f"({time.time() - t0:.0f}s incl. compile)", file=sys.stderr)
 
-    from distpow_tpu.backends.pallas_backend import PallasBackend
+    from distpow_tpu.backends import get_backend
 
-    backend = PallasBackend(hash_model=mname, batch_size=1 << 17)
+    backend = get_backend("pallas", hash_model=mname, batch_size=1 << 17)
     t0 = time.time()
     secret = backend.search(nonce, 3, list(range(256)))
     oracle = puzzle.python_search(nonce, 3, list(range(256)), algo=mname)

@@ -25,11 +25,6 @@
 # (scripts/wire_smoke.py, docs/RPC.md): wire-v2 negotiation, parallel
 # fan-out seams recorded, chaos on binary frames ridden out, and a
 # JSON-pinned client interoperating — ~20 s, pure CPU.
-# `--serving-smoke` runs the deterministic serving-loop smoke
-# (scripts/serving_smoke.py, docs/SERVING.md): the persistent loop must
-# match the serial driver's first hit with zero blocking host syncs,
-# and a mixed-hash (md5+sha1) batch through an in-process worker must
-# spend fewer launches than the per-model solo baseline — ~30 s, CPU.
 # `--slo-smoke` runs the deterministic SLO-gate smoke
 # (scripts/slo_smoke.py, docs/SLO.md): an open-loop Poisson burst on an
 # in-process cluster must pass the checked-in config/slo.json (exit 0)
@@ -81,7 +76,7 @@
 # ride the mesh lane and match the pure-python oracle byte-for-byte,
 # with sched.lane_launches.mesh and search.mesh_devices counting the
 # span — ~30 s, CPU.
-# Usage: scripts/ci.sh [--full|--nightly|--chaos|--lint|--race-audit|--bench-rehearsal|--sched-smoke|--wire-smoke|--serving-smoke|--slo-smoke|--soak-smoke|--mesh-smoke|--fleet-smoke|--forensics-smoke|--cluster-smoke|--ha-smoke]
+# Usage: scripts/ci.sh [--full|--nightly|--chaos|--lint|--race-audit|--bench-rehearsal|--sched-smoke|--wire-smoke|--slo-smoke|--soak-smoke|--mesh-smoke|--fleet-smoke|--forensics-smoke|--cluster-smoke|--ha-smoke]
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -131,13 +126,6 @@ if [ "${1:-}" = "--wire-smoke" ]; then
   echo "=== wire smoke (codec negotiation + parallel fan-out + chaos-on-binary) ==="
   JAX_PLATFORMS=cpu python scripts/wire_smoke.py
   echo "=== wire smoke OK ==="
-  exit 0
-fi
-
-if [ "${1:-}" = "--serving-smoke" ]; then
-  echo "=== serving smoke (persistent loop + mixed-hash batch, CPU platform) ==="
-  JAX_PLATFORMS=cpu python scripts/serving_smoke.py
-  echo "=== serving smoke OK ==="
   exit 0
 fi
 
@@ -228,7 +216,7 @@ case "${1:-}" in
            exit 0 ;;
   "")     python -m pytest tests/ -q -m "not slow and not veryslow" ;;
   *)      echo "unknown argument: $1" >&2
-          echo "usage: scripts/ci.sh [--full|--nightly|--chaos|--lint|--race-audit|--bench-rehearsal|--sched-smoke|--wire-smoke|--serving-smoke|--slo-smoke|--soak-smoke|--mesh-smoke|--fleet-smoke|--forensics-smoke|--cluster-smoke|--ha-smoke]" >&2
+          echo "usage: scripts/ci.sh [--full|--nightly|--chaos|--lint|--race-audit|--bench-rehearsal|--sched-smoke|--wire-smoke|--slo-smoke|--soak-smoke|--mesh-smoke|--fleet-smoke|--forensics-smoke|--cluster-smoke|--ha-smoke]" >&2
           exit 2 ;;
 esac
 

@@ -1,7 +1,7 @@
 """Pallas-vs-JAX e2e parity distribution on the real chip (VERDICT r3 #5).
 
 Runs N (default 12) fresh-nonce, warmed, diff-32 end-to-end solves
-through the JaxBackend and the PallasBackend — the same nonce set for
+through the ``jax`` and the ``pallas`` backends — the same nonce set for
 both, so the comparison is paired — and reports median / p90 wall-clock
 per backend plus the pallas/jax median ratio.  The acceptance bar from
 the verdict: pallas median <= 1.2x jax median (the kernel is a
@@ -84,8 +84,7 @@ def main() -> None:
 
     _enable_cache()
 
-    from distpow_tpu.backends import JaxBackend
-    from distpow_tpu.backends.pallas_backend import PallasBackend
+    from distpow_tpu.backends import JaxBackend, get_backend
 
     # interpret mode off-TPU so the script CPU-smokes (tiny n, low
     # difficulty); the acceptance numbers only mean anything on the chip.
@@ -97,7 +96,8 @@ def main() -> None:
                "platform": jax.devices()[0].platform}
     jt = solve_times(JaxBackend(batch_size=1 << 21), "jax", nonces,
                      difficulty)
-    pt = solve_times(PallasBackend(batch_size=1 << 21, interpret=not on_tpu),
+    pt = solve_times(get_backend("pallas", batch_size=1 << 21,
+                                 interpret=not on_tpu),
                      "pallas", nonces, difficulty)
     import math
 

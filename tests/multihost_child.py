@@ -33,10 +33,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from distpow_tpu.models import puzzle  # noqa: E402
 from distpow_tpu.models.registry import get_hash_model  # noqa: E402
 from distpow_tpu.parallel.mesh_search import (  # noqa: E402
-    _pallas_mesh_step_factory,
     make_mesh,
-    search_mesh,
+    mesh_persistent_factory,
+    pallas_mesh_persistent_factory,
 )
+from distpow_tpu.parallel.search import persistent_search  # noqa: E402
 
 # nonce chosen so the FIRST solution in enumeration order is
 # (tb=214, chunk=empty->width probe) — tb 214 lives on global device
@@ -44,13 +45,25 @@ from distpow_tpu.parallel.mesh_search import (  # noqa: E402
 # the oracle offline)
 NONCE = bytes.fromhex("045a")
 mesh = make_mesh(jax.devices())
-res = search_mesh(NONCE, 2, list(range(256)), mesh=mesh, batch_size=1 << 12)
+
+
+def mesh_search(nonce, model=None, factory=mesh_persistent_factory,
+                **kwargs):
+    """One solve of the full partition through the search driver, every
+    width past the probe on the global mesh."""
+    model = model or get_hash_model("md5")
+    return persistent_search(
+        nonce, 2, list(range(256)), model=model, batch_size=1 << 12,
+        step_builder=factory(nonce, 2, 0, 256, model, mesh, **kwargs))
+
+
+res = mesh_search(NONCE)
 assert res is not None
 assert puzzle.check_secret(NONCE, res.secret, 2)
 print(f"RESULT pid={pid} secret={res.secret.hex()} tb={res.thread_byte}",
       flush=True)
 
-# a solve through the pallas-mesh kernel factory (interpret mode on the
+# a solve through the chained pallas mesh step (interpret mode on the
 # CPU mesh).  Different nonce on purpose: NONCE's first solution is
 # width-0 (empty chunk), which both factories serve via the shared
 # single-device probe — it would never consult the kernel.  0x000c has
@@ -60,12 +73,8 @@ print(f"RESULT pid={pid} secret={res.secret.hex()} tb={res.thread_byte}",
 # 1), and only the kernel's pmin-ed global flat index crossing the
 # process boundary can deliver it to process 0.
 NONCE_P = bytes.fromhex("000c")
-pf = _pallas_mesh_step_factory(
-    NONCE_P, 2, 0, 256, get_hash_model("md5"), mesh, "workers",
-    interpret=True,
-)
-res_p = search_mesh(NONCE_P, 2, list(range(256)), mesh=mesh,
-                    batch_size=1 << 12, step_factory=pf)
+res_p = mesh_search(NONCE_P, factory=pallas_mesh_persistent_factory,
+                    interpret=True)
 assert res_p is not None
 assert puzzle.check_secret(NONCE_P, res_p.secret, 2)
 assert res_p.secret == bytes([144, 1]), res_p.secret.hex()
@@ -82,8 +91,7 @@ print(f"PALLAS pid={pid} secret={res_p.secret.hex()} "
 # proves the structurally-different model (pad10*1, XOR-absorb,
 # 50-limb state) rides the cross-process pmin collective
 NONCE_S = bytes.fromhex("000a")
-res_s = search_mesh(NONCE_S, 2, list(range(256)), mesh=mesh,
-                    model=get_hash_model("sha3_256"), batch_size=1 << 12)
+res_s = mesh_search(NONCE_S, model=get_hash_model("sha3_256"))
 assert res_s is not None
 assert puzzle.check_secret(NONCE_S, res_s.secret, 2, "sha3_256")
 assert res_s.secret == bytes([204, 1]), res_s.secret.hex()

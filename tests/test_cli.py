@@ -124,11 +124,11 @@ def test_worker_multihost_bootstrap_subprocess():
         "from distpow_tpu.cli.worker import maybe_init_distributed\n"
         f"maybe_init_distributed('127.0.0.1:{port}', 1, 0)\n"
         "assert jax.process_count() == 1\n"
-        "from distpow_tpu.parallel import search_mesh, make_mesh\n"
+        "from distpow_tpu.backends import JaxMeshBackend\n"
         "from distpow_tpu.models import puzzle\n"
-        "r = search_mesh(b'\\x01\\x02', 2, list(range(256)),\n"
-        "                mesh=make_mesh(jax.devices()), batch_size=1<<13)\n"
-        "assert puzzle.check_secret(b'\\x01\\x02', r.secret, 2)\n"
+        "s = JaxMeshBackend(batch_size=1<<13).search(\n"
+        "    b'\\x01\\x02', 2, list(range(256)))\n"
+        "assert puzzle.check_secret(b'\\x01\\x02', s, 2)\n"
         "jax.distributed.shutdown()\n"
         "print('MULTIHOST_BOOTSTRAP_OK')\n"
     )

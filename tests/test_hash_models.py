@@ -277,11 +277,12 @@ def test_sha384_spec_vector_and_truncation():
     # mining parity at a difficulty whose masks live in the truncated
     # digest's trailing words
     from distpow_tpu.models import puzzle
-    from distpow_tpu.parallel.search import search
+    from distpow_tpu.parallel.search import persistent_search
 
     tbs = list(range(256))
     oracle = puzzle.python_search(b"\x31\x41", 2, tbs, algo="sha384")
-    got = search(b"\x31\x41", 2, tbs, model=SHA384, batch_size=1 << 13)
+    got = persistent_search(b"\x31\x41", 2, tbs, model=SHA384,
+                            batch_size=1 << 13)
     assert got is not None and got.secret == oracle
 
 
@@ -472,12 +473,13 @@ def test_blake2b_search_matches_oracle():
     must carry across the absorb boundary)."""
     from distpow_tpu.models import puzzle
     from distpow_tpu.models.registry import BLAKE2B_256
-    from distpow_tpu.parallel.search import search
+    from distpow_tpu.parallel.search import persistent_search
 
     tbs = list(range(256))
     for nonce in (b"\x27\x18", bytes(range(130))):
         oracle = puzzle.python_search(nonce, 2, tbs, algo="blake2b_256")
-        got = search(nonce, 2, tbs, model=BLAKE2B_256, batch_size=1 << 13)
+        got = persistent_search(nonce, 2, tbs, model=BLAKE2B_256,
+                                batch_size=1 << 13)
         assert got is not None and got.secret == oracle
         assert hashlib.blake2b(nonce + got.secret,
                                digest_size=32).hexdigest().endswith("00")
@@ -487,11 +489,12 @@ def test_sha3_search_matches_oracle():
     """Mining parity end-to-end through the generic driver — the sponge
     padding hook (ops/packing.py) in its serving configuration."""
     from distpow_tpu.models import puzzle
-    from distpow_tpu.parallel.search import search
+    from distpow_tpu.parallel.search import persistent_search
 
     tbs = list(range(256))
     for nonce in (b"\x27\x18", b"\x01\x02\x03\x04"):
         oracle = puzzle.python_search(nonce, 2, tbs, algo="sha3_256")
-        got = search(nonce, 2, tbs, model=SHA3_256, batch_size=1 << 13)
+        got = persistent_search(nonce, 2, tbs, model=SHA3_256,
+                                batch_size=1 << 13)
         assert got is not None and got.secret == oracle
         assert hashlib.sha3_256(nonce + got.secret).hexdigest().endswith("00")

@@ -228,7 +228,7 @@ def test_mesh_persistent_chained_halt():
 
 @pytest.mark.parametrize("nonce", [b"\x90\x00\x00", b"\x90\x0a\x00"],
                          ids=["last-of-width-1", "first-of-width-2"])
-def test_persistent_search_mesh_builder_across_widths(nonce):
+def test_persistent_driver_mesh_builder_across_widths(nonce):
     """persistent_search over the mesh lane, with hits in the last
     dispatch of width 1 and the first of width 2: the oracle's secret,
     one halted dispatch behind the hit, and a second solve of the same
@@ -262,7 +262,7 @@ def test_persistent_search_mesh_builder_across_widths(nonce):
     assert compiles == []
 
 
-def test_persistent_search_mesh_builder_matches_oracle():
+def test_persistent_driver_mesh_builder_matches_oracle():
     """End to end: persistent_search driving the mesh lane finds the
     oracle's secret (same enumeration order => same first hit)."""
     nonce, ntz = b"\x61\x62\x63", 2

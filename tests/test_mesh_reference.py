@@ -1,14 +1,15 @@
-"""The four-chip worker's served forms against the plain reference, and
+"""The device backends' served forms against the plain reference, and
 the mesh work they count.
 
-``PallasMeshBackend`` (the kernel in interpret mode, over four of the
-conftest's virtual CPU devices) and the mesh persistent lane
-(``sched/lanes.py persistent_step_builder``) both drive the persistent
-driver, through the chained Pallas mesh step and the XLA mesh step.
-Each must return the reference's first solution (``models/puzzle.py
-python_search``) in both sharding regimes, and count every candidate
-but the one-device width-0 probe's under ``search.mesh_hashes`` as well
-as ``search.hashes``.  The chained Pallas mesh step's own contract
+``pallas-mesh`` (the kernel in interpret mode, over four of the
+conftest's virtual CPU devices), ``pallas`` (the same on one device),
+``jax-mesh`` (the XLA mesh step on four) and the mesh persistent lane
+(``sched/lanes.py persistent_step_builder``) all drive the persistent
+driver.  Each must return the reference's first solution
+(``models/puzzle.py python_search``) in both sharding regimes, halt the
+one dispatch chained behind its hit, and count every candidate but the
+one-device width-0 probe's under ``search.mesh_hashes`` as well as
+``search.hashes`` — none on one device.  The chained Pallas mesh step's own contract
 (halt behind a hit, the segment of a hit, the reference-order first of
 several solutions in one dispatch) is checked at small segments.
 """
@@ -20,7 +21,7 @@ import jax
 import numpy as np
 import pytest
 
-from distpow_tpu.backends import PallasMeshBackend
+from distpow_tpu.backends import PallasMeshBackend, get_backend
 from distpow_tpu.models import puzzle
 from distpow_tpu.models.registry import get_hash_model
 from distpow_tpu.ops.search_step import CHAIN_CLEAR, CHAIN_HALT, HALT
@@ -64,6 +65,16 @@ def _pallas_mesh(nonce, tbs):
     return backend.search(nonce, NTZ, tbs)
 
 
+def _pallas_one_device(nonce, tbs):
+    backend = get_backend("pallas", batch_size=BATCH, interpret=True)
+    return backend.search(nonce, NTZ, tbs)
+
+
+def _jax_mesh(nonce, tbs):
+    backend = get_backend("jax-mesh", batch_size=BATCH, mesh_devices=N_DEV)
+    return backend.search(nonce, NTZ, tbs)
+
+
 def _mesh_persistent(nonce, tbs):
     builder = persistent_step_builder(
         nonce, NTZ, tbs[0], len(tbs), get_hash_model("md5"),
@@ -74,8 +85,11 @@ def _mesh_persistent(nonce, tbs):
     return None if res is None else res.secret
 
 
-DRIVERS = {"pallas_mesh": _pallas_mesh,
-           "persistent_search": _mesh_persistent}
+# driver -> (serve, devices its steps span)
+DRIVERS = {"pallas_mesh": (_pallas_mesh, N_DEV),
+           "persistent_search": (_mesh_persistent, N_DEV),
+           "pallas": (_pallas_one_device, 1),
+           "jax_mesh": (_jax_mesh, N_DEV)}
 
 
 @pytest.mark.parametrize("regime", list(REGIMES))
@@ -83,25 +97,32 @@ DRIVERS = {"pallas_mesh": _pallas_mesh,
 def test_mesh_serving_matches_the_reference_and_counts_mesh_work(
         driver, regime):
     tbs = REGIMES[regime]
+    serve, n_dev = DRIVERS[driver]
     for i, nonce in enumerate(_nonces(2500)):
         tried = []
         want = puzzle.python_search(nonce, NTZ, tbs,
                                     on_progress=tried.append)
         hashes0 = REGISTRY.get("search.hashes")
         mesh0 = REGISTRY.get("search.mesh_hashes")
+        halted0 = REGISTRY.get("search.halted_dispatches")
         SPANS.reset()
         with SPANS.bind(9000 + i, "w-mesh"):
-            got = DRIVERS[driver](nonce, tbs)
+            got = serve(nonce, tbs)
         assert got == want, (nonce.hex(), got, want)
         assert _position(got, tbs) == tried[0]
         hashes = REGISTRY.get("search.hashes") - hashes0
         mesh = REGISTRY.get("search.mesh_hashes") - mesh0
+        # the hit lies past the width-0 probe, so the one dispatch in
+        # flight behind it is chained to it and halts
+        assert len(got) > 1
+        assert REGISTRY.get("search.halted_dispatches") - halted0 == 1
         # the width-0 probe: one candidate per thread byte, on one device
-        assert mesh == hashes - len(tbs) > 0
+        assert hashes > len(tbs)
+        assert mesh == (hashes - len(tbs) if n_dev > 1 else 0)
         segs = [s for s in SPANS.spans_for(9000 + i)
                 if s["name"] == "search.segment"]
         assert [s["attrs"]["devices"] for s in segs] == \
-            [1] + [N_DEV] * (len(segs) - 1)
+            [1] + [n_dev] * (len(segs) - 1)
 
 
 # The chained Pallas mesh step at small segments: 1024 candidates a
@@ -188,3 +209,29 @@ def test_pallas_mesh_chunk_split_returns_the_reference_first():
     # up to the end of its segment; nothing of the dispatch behind it
     assert REGISTRY.get("search.hashes") - hashes0 == \
         2 + 510 + (sols[0] // SEG_GLOBAL + 1) * SEG_GLOBAL
+
+
+def test_every_device_backend_serves_through_the_persistent_driver(
+        monkeypatch):
+    """``jax``, ``jax-mesh``, ``pallas`` and ``pallas-mesh`` each answer
+    a request with one call of ``persistent_search``, the one search
+    driver, and return the reference's first solution."""
+    import distpow_tpu.parallel.search as search_mod
+
+    drive = search_mod.persistent_search
+    calls = []
+
+    def recorded(*args, **kwargs):
+        calls.append(kwargs["model"].name)
+        return drive(*args, **kwargs)
+
+    monkeypatch.setattr(search_mod, "persistent_search", recorded)
+    nonce, tbs = b"\x61\x62\x63", list(range(256))
+    want = puzzle.python_search(nonce, NTZ, tbs)
+    for name, kwargs in (("jax", {}), ("jax-mesh", {}),
+                         ("pallas", {"interpret": True}),
+                         ("pallas-mesh", {"interpret": True})):
+        del calls[:]
+        backend = get_backend(name, batch_size=BATCH, **kwargs)
+        assert backend.search(nonce, NTZ, tbs) == want, name
+        assert calls == ["md5"], name

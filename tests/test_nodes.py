@@ -939,17 +939,21 @@ def _raise_value_error(*args, **kwargs):
 
 
 def test_pallas_step_fallback_is_counted(monkeypatch):
-    from distpow_tpu.backends import pallas_backend
+    """The one-chip ``pallas`` backend counts a width the kernel refuses
+    under the same counter as ``pallas-mesh``: it is the same backend
+    over a one-device mesh."""
+    import distpow_tpu.parallel.mesh_search as mesh_search
+    from distpow_tpu.backends import get_backend
     from distpow_tpu.runtime.metrics import REGISTRY
 
-    monkeypatch.setattr(pallas_backend, "cached_pallas_search_step",
-                        _raise_value_error)
-    before = REGISTRY.get("fallback.pallas_step")
-    b = pallas_backend.PallasBackend(hash_model="md5", batch_size=1 << 12,
-                                     interpret=True)
+    monkeypatch.setattr(mesh_search, "pallas_mesh_persistent_factory",
+                        lambda *a, **k: _raise_value_error)
+    before = REGISTRY.get("fallback.pallas_mesh_step")
+    b = get_backend("pallas", hash_model="md5", batch_size=1 << 12,
+                    interpret=True)
     secret = b.search(b"\x61\x62", 2, list(range(256)))
     assert secret == puzzle.python_search(b"\x61\x62", 2, list(range(256)))
-    assert REGISTRY.get("fallback.pallas_step") > before
+    assert REGISTRY.get("fallback.pallas_mesh_step") > before
 
 
 @pytest.mark.parametrize("refuse", ["request", "width"])

@@ -1,15 +1,51 @@
 """Pallas kernel tests (interpret mode on CPU; the driver/bench exercise
 the compiled kernel on real TPU hardware)."""
 
+import inspect
+
 import jax
 import jax.numpy as jnp
 import pytest
 
-from distpow_tpu.backends.pallas_backend import PallasBackend
+from distpow_tpu.backends import get_backend
 from distpow_tpu.models import puzzle
-from distpow_tpu.models.registry import MD5
+from distpow_tpu.models.registry import MD5, get_hash_model
 from distpow_tpu.ops.md5_pallas import build_pallas_search_step
 from distpow_tpu.ops.search_step import SENTINEL, build_search_step
+from distpow_tpu.parallel.mesh_search import pallas_mesh_persistent_step
+
+
+_PALLAS_STEP_SIGNATURE = inspect.signature(pallas_mesh_persistent_step)
+
+
+def pallas(**kwargs):
+    """The ``pallas`` backend, its kernel in interpret mode."""
+    return get_backend("pallas", interpret=True, **kwargs)
+
+
+def _kernel_plan(monkeypatch, model_name, interpret, target_chunks,
+                 segments):
+    """What the step builder every pallas backend serves through
+    (``pallas_mesh_persistent_factory``, one device) hands the chained
+    kernel step for one width-1 dispatch of the full partition: the
+    step's arguments by name, and the builder's ``(chunks_each,
+    chunks_per_step)``."""
+    import distpow_tpu.parallel.mesh_search as mesh_search
+
+    sig = _PALLAS_STEP_SIGNATURE
+    seen = {}
+
+    def spy(*args):
+        seen.update(sig.bind(*args).arguments)
+        return lambda *a: None
+
+    monkeypatch.setattr(mesh_search, "pallas_mesh_persistent_step", spy)
+    builder = mesh_search.pallas_mesh_persistent_factory(
+        b"\x01", 8, 0, 256, get_hash_model(model_name),
+        mesh_search.make_mesh(jax.devices()[:1]), interpret=interpret)
+    _, chunks_each, chunks_per_step = builder(1, b"", target_chunks,
+                                              segments)
+    return seen, chunks_each, chunks_per_step
 
 
 def test_pallas_matches_xla_step():
@@ -43,7 +79,7 @@ def test_pallas_rejects_unsupported_configs():
         build_pallas_search_step(bytes(60), 4, 2, 0, 256, 128, interpret=True)
 
 
-def test_default_geometry_resolution_at_every_site():
+def test_default_geometry_resolution_at_every_site(monkeypatch):
     """The interpret-mode sublanes cap must hold at every resolution
     site (ops/md5_pallas.py default_geometry): serving gets the swept
     MODEL_GEOMETRY entry, interpret mode is capped at 8 (the serving
@@ -54,32 +90,16 @@ def test_default_geometry_resolution_at_every_site():
     assert default_geometry("sha256") == MODEL_GEOMETRY["sha256"]
     assert default_geometry("sha256", interpret=True)[0] == 8
     assert default_geometry("md5", interpret=True)[0] == 8
-    # PallasBackend resolves through the same helper
-    assert PallasBackend(hash_model="sha256").sublanes == \
-        MODEL_GEOMETRY["sha256"][0]
-    assert PallasBackend(hash_model="sha256", interpret=True).sublanes == 8
-    assert PallasBackend(hash_model="sha256", interpret=True,
-                         sublanes=16).sublanes == 16
-    # ...and so does the pallas-mesh step factory (the third site)
-    import jax
-    from distpow_tpu.models.registry import SHA256
-    from distpow_tpu.parallel.mesh_search import (
-        AXIS,
-        _pallas_mesh_step_factory,
-        make_mesh,
-    )
-
-    mesh = make_mesh(jax.devices()[:8])
-    f_serve = _pallas_mesh_step_factory(
-        b"\x01", 8, 0, 256, SHA256, mesh, AXIS)
-    f_interp = _pallas_mesh_step_factory(
-        b"\x01", 8, 0, 256, SHA256, mesh, AXIS, interpret=True)
-    assert f_serve.sublanes == MODEL_GEOMETRY["sha256"][0]
-    assert f_interp.sublanes == 8
+    # ...and so does the step builder both pallas backends serve
+    # through (the pallas mesh step factory)
+    for interpret, sublanes in ((False, MODEL_GEOMETRY["sha256"][0]),
+                                (True, 8)):
+        seen, _, _ = _kernel_plan(monkeypatch, "sha256", interpret, 1024, 1)
+        assert seen["sublanes"] == sublanes and seen["interpret"] is interpret
 
 
 def test_pallas_backend_end_to_end():
-    backend = PallasBackend(batch_size=1 << 15, sublanes=8, interpret=True)
+    backend = pallas(batch_size=1 << 15)
     nonce = b"\x0a\x0b\x0c"
     tbs = list(range(256))
     secret = backend.search(nonce, 2, tbs)
@@ -90,8 +110,7 @@ def test_pallas_backend_end_to_end():
 def test_pallas_backend_serves_sha1_with_kernel():
     # sha1 has a _TILE_FNS entry since round 3 — served by the kernel,
     # reference enumeration order
-    backend = PallasBackend(hash_model="sha1", batch_size=1 << 14,
-                            interpret=True)
+    backend = pallas(hash_model="sha1", batch_size=1 << 14)
     nonce = b"\x11\x22"
     secret = backend.search(nonce, 2, list(range(256)))
     assert secret == puzzle.python_search(nonce, 2, list(range(256)),
@@ -101,8 +120,7 @@ def test_pallas_backend_serves_sha1_with_kernel():
 def test_pallas_backend_serves_ripemd160_with_kernel():
     # fourth model (round 4): the two-line tile serves through the
     # kernel path in reference enumeration order
-    backend = PallasBackend(hash_model="ripemd160", batch_size=1 << 14,
-                            interpret=True)
+    backend = pallas(hash_model="ripemd160", batch_size=1 << 14)
     nonce = b"\x33\x44"
     secret = backend.search(nonce, 2, list(range(256)))
     assert secret == puzzle.python_search(nonce, 2, list(range(256)),
@@ -115,8 +133,7 @@ def test_pallas_backend_falls_back_for_sha512():
     # the unrolled limb-pair graph is pathological) — under
     # interpret=True the backend must still serve through the
     # transparent XLA fallback
-    backend = PallasBackend(hash_model="sha512", batch_size=1 << 13,
-                            interpret=True)
+    backend = pallas(hash_model="sha512", batch_size=1 << 13)
     nonce = b"\x55\x66"
     secret = backend.search(nonce, 2, list(range(256)))
     assert secret == puzzle.python_search(nonce, 2, list(range(256)),
@@ -125,25 +142,24 @@ def test_pallas_backend_falls_back_for_sha512():
 
 def test_pallas_backend_falls_back_for_model_without_kernel(monkeypatch):
     # a registry model WITHOUT a kernel entry -> transparent XLA
-    # fallback (all three shipped models have kernels now, so the
+    # fallback, counted (every registry model has a kernel, so the
     # branch is exercised by deleting one)
     from distpow_tpu.ops import md5_pallas
+    from distpow_tpu.runtime.metrics import REGISTRY
 
     monkeypatch.delitem(md5_pallas._TILE_FNS, "sha1")
-    backend = PallasBackend(hash_model="sha1", batch_size=1 << 14,
-                            interpret=True)
-    # different nonce from the kernel test above: the layout-keyed
-    # program cache would otherwise return the already-built kernel
-    # step without ever consulting the patched _TILE_FNS
+    backend = pallas(hash_model="sha1", batch_size=1 << 14)
+    before = REGISTRY.get("fallback.pallas_mesh_step")
     nonce = b"\x33\x44"
     secret = backend.search(nonce, 2, list(range(256)))
     assert secret == puzzle.python_search(nonce, 2, list(range(256)),
                                           algo="sha1")
+    assert REGISTRY.get("fallback.pallas_mesh_step") > before
 
 
 def test_pallas_backend_falls_back_for_long_nonce():
-    # two-block tail -> transparent XLA fallback inside the factory
-    backend = PallasBackend(batch_size=1 << 14, sublanes=8, interpret=True)
+    # two-block tail -> transparent XLA fallback inside the builder
+    backend = pallas(batch_size=1 << 14)
     nonce = bytes(range(60))
     secret = backend.search(nonce, 1, list(range(256)))
     assert secret is not None
@@ -441,16 +457,13 @@ def test_blake2b_tile_matches_hashlib_all_buckets():
 
 def test_sha512_interpret_mode_falls_back():
     """Both kernel constructors — the single-device builder AND the
-    mesh step factory (review r4: it bypassed the first guard) — must
-    refuse the limb-pair tiles under interpret=True (ValueError = the
+    mesh step factory the pallas backends serve through — must refuse
+    the limb-pair tiles under interpret=True (ValueError = the
     transparent-fallback signal every caller maps to the XLA step)."""
-    import jax
-
-    from distpow_tpu.models.registry import get_hash_model
     from distpow_tpu.ops.md5_pallas import build_pallas_search_step
     from distpow_tpu.parallel.mesh_search import (
-        _pallas_mesh_step_factory,
         make_mesh,
+        pallas_mesh_persistent_factory,
     )
 
     mesh = make_mesh(jax.devices())
@@ -461,9 +474,9 @@ def test_sha512_interpret_mode_falls_back():
                 sublanes=8, interpret=True,
             )
         with pytest.raises(ValueError, match="TPU-only"):
-            _pallas_mesh_step_factory(
+            pallas_mesh_persistent_factory(
                 b"\x01\x02", 3, 0, 256, get_hash_model(mname), mesh,
-                "devices", sublanes=8, interpret=True,
+                interpret=True,
             )
 
 
@@ -512,76 +525,44 @@ def test_sha256d_interpret_falls_back():
     doubled unrolled graph is pathological for XLA:CPU codegen): the
     builder refuses interpret mode and the backend transparently serves
     the fused XLA step instead."""
-    from distpow_tpu.backends.pallas_backend import PallasBackend
-    from distpow_tpu.models import puzzle
-
     with pytest.raises(ValueError, match="TPU-only"):
         build_pallas_search_step(
             b"\x01", 1, 2, 0, 256, 128, model_name="sha256d",
             interpret=True)
-    b = PallasBackend(hash_model="sha256d", interpret=True,
-                      batch_size=1 << 12)
+    b = pallas(hash_model="sha256d", batch_size=1 << 12)
     secret = b.search(b"\x05\x06\x07", 2, list(range(256)))
     assert secret is not None
     assert puzzle.check_secret(b"\x05\x06\x07", secret, 2, "sha256d")
 
 
 def test_backend_batch_rounding_keeps_inner_for_24_sublane_tiles(monkeypatch):
-    """Serving-side support for the sweep-best sublanes=24 geometries
-    (VERDICT r4 item 8): a 2^21 batch at tile 3072
-    is 683 tiles — prime — which would collapse the tuned inner to
-    unswept territory.  The factory must grow the batch by whole tiles
-    until the per-dispatch tile count divides inner, keeping chunk
-    accounting exact and the growth marginal."""
-    import math
+    """Serving-side support for the sweep-best sublanes=24 geometries: a
+    2^21 batch at tile 3072 is 683 tiles — prime — which would collapse
+    the tuned inner to unswept territory.  The step builder the pallas
+    backends serve through cuts a dispatch into segments of
+    SEGMENT_GRID_STEPS x inner whole tiles, so the inner loop holds at
+    serving size, chunk accounting stays exact, a dispatch overshoots
+    its plan by under one segment, and a small dispatch grows by under
+    one tile."""
+    from distpow_tpu.ops import md5_pallas
 
-    from distpow_tpu.backends.pallas_backend import PallasBackend
-
-    captured = {}
-
-    def fake_step(nonce, vw, difficulty, tb_lo, tbc, chunks, mname,
-                  extra, sublanes, interpret, k, inner):
-        captured.update(chunks=chunks, k=k, tbc=tbc, sublanes=sublanes,
-                        inner=inner)
-        return lambda c0: 0
-
-    monkeypatch.setattr(
-        "distpow_tpu.backends.pallas_backend.cached_pallas_search_step",
-        fake_step)
-    b = PallasBackend(hash_model="ripemd160", batch_size=1 << 21,
-                      sublanes=24, inner=1024)
-    factory = b._factory(b"\x01\x02\x03\x04", 8, 0, 256)
-    step, covered = factory(4, b"", (1 << 21) // 256, launch_steps=128)
-
+    monkeypatch.setitem(md5_pallas.MODEL_GEOMETRY, "ripemd160", (24, 1024))
     tile = 24 * 128
-    batch = captured["chunks"] * 256
-    assert batch % tile == 0, "not a whole tile grid"
-    n_tiles = batch // tile
-    k = captured["k"]
-    assert (n_tiles * k) % 1024 == 0, \
-        f"inner would shrink: {n_tiles} tiles x k={k} vs inner=1024"
-    # growth stays marginal (<= inner extra tiles; here well under 2%)
-    assert batch < (1 << 21) * 1.02
-    assert covered == captured["chunks"] * k
-    # power-of-two geometries are untouched by the rounding
-    captured.clear()
-    b2 = PallasBackend(hash_model="md5", batch_size=1 << 21)
-    f2 = b2._factory(b"\x01\x02\x03\x04", 8, 0, 256)
-    f2(4, b"", (1 << 21) // 256, launch_steps=8)
-    assert captured["chunks"] == (1 << 21) // 256
-    # and the no-op claim holds structurally: gcd math keeps pow2 counts
-    assert ((1 << 21) // (64 * 128) * 8) % b2.inner == 0 or \
-        math.gcd(8, b2.inner) == 8
-    # overgrowth is REJECTED (review r5: an uncapped version grew small
-    # segments 4x): a tiny k=1 launch at need=1024 would have to grow
-    # to 1024 tiles — far past the 2% cap — so the batch keeps the
-    # plain tile rounding and the kernel shrinks inner instead
-    captured.clear()
-    small = factory(4, b"", 1024, launch_steps=1)
-    batch_small = captured["chunks"] * 256
-    assert batch_small % tile == 0
-    assert batch_small <= 2 * 1024 * 256, \
-        f"small segment overgrown to {batch_small}"
+    for target_chunks, segments, inner in (((1 << 21) // 256, 128, 1024),
+                                           (1024, 1, None)):
+        seen, chunks_each, chunks_per_step = _kernel_plan(
+            monkeypatch, "ripemd160", False, target_chunks, segments)
+        planned = target_chunks * 256 * segments
+        seg = seen["seg_local"]
+        assert seen["sublanes"] == 24 and seg % tile == 0
+        assert seen["grid"] * seen["inner"] * tile == seg
+        assert chunks_each * 256 == seg
+        assert chunks_per_step == chunks_each * seen["segments"]
+        assert 0 <= seen["segments"] * seg - planned < seg
+        if inner is not None:
+            assert seen["inner"] == inner, "inner would shrink"
+        else:
+            assert seg - planned < tile, f"small segment overgrown to {seg}"
 
 
 @pytest.mark.veryslow
@@ -684,7 +665,7 @@ def test_pallas_mesh_matches_jax_mesh_all_partitions():
     """pallas-mesh must be bit-identical to jax-mesh in both sharding
     regimes (tb-split, chunk-split) and on sub-partitions — both return
     the minimal TRUE global flat index, so the decoded secrets match
-    exactly (parallel/mesh_search.py _dyn_pallas_mesh_step)."""
+    exactly (parallel/mesh_search.py pallas_mesh_persistent_step)."""
     from distpow_tpu.backends import JaxMeshBackend, PallasMeshBackend
 
     b = PallasMeshBackend(batch_size=1 << 14, interpret=True)
@@ -767,7 +748,7 @@ class TestPlanLaunchGeometry:
     original k is kept otherwise."""
 
     def test_pow2_tile_is_untouched(self):
-        from distpow_tpu.backends.pallas_backend import plan_launch_geometry
+        from distpow_tpu.sched.lanes import plan_launch_geometry
 
         # tile 8x128=1024 is a power of two: the inner-loop fixup never
         # runs and k keeps the driver's requested (odd) multiplier
@@ -776,7 +757,7 @@ class TestPlanLaunchGeometry:
         assert (batch, chunks, k) == (2048 * 256, 2048, 5)
 
     def test_batch_rounds_up_to_whole_tiles(self):
-        from distpow_tpu.backends.pallas_backend import plan_launch_geometry
+        from distpow_tpu.sched.lanes import plan_launch_geometry
 
         # 2^21 candidates at a 24-sublane (3072) tile: 682.67 tiles
         # rounds up to a whole grid and chunks re-derive from it
@@ -787,7 +768,7 @@ class TestPlanLaunchGeometry:
         assert chunks == batch // 256
 
     def test_pow2_rounding_commits_with_marginal_growth(self):
-        from distpow_tpu.backends.pallas_backend import plan_launch_geometry
+        from distpow_tpu.sched.lanes import plan_launch_geometry
 
         # the sweep-best serving shape: 683 (prime) tiles, inner=4 —
         # a whole-tile growth of ~0.15% makes the pow2 k effective
@@ -798,7 +779,7 @@ class TestPlanLaunchGeometry:
         assert batch <= 8192 * 256 * 1.03  # growth stayed marginal
 
     def test_growth_rejected_keeps_original_k(self):
-        from distpow_tpu.backends.pallas_backend import plan_launch_geometry
+        from distpow_tpu.sched.lanes import plan_launch_geometry
 
         # 53 tiles at tbc=160: the next inner-compatible whole-tile
         # batch is not tbc-aligned within the <=2% cap, so the growth
@@ -810,7 +791,7 @@ class TestPlanLaunchGeometry:
         assert batch == 53 * 3072  # and the batch stayed unrounded
 
     def test_budget_clamp_holds_through_every_path(self):
-        from distpow_tpu.backends.pallas_backend import plan_launch_geometry
+        from distpow_tpu.sched.lanes import plan_launch_geometry
 
         for target_chunks in (1000, 2000, 8192):
             for tbc in (96, 160, 256):

@@ -1,4 +1,5 @@
-"""Search-driver tests: single-device and mesh, vs the python oracle."""
+"""Search-driver tests: the persistent driver on one device and on the
+mesh, and the backends that drive it, vs the python oracle."""
 
 import threading
 
@@ -6,22 +7,45 @@ import jax
 import pytest
 
 from distpow_tpu.models import puzzle
-from distpow_tpu.models.registry import SHA256
+from distpow_tpu.models.registry import get_hash_model
 from distpow_tpu.parallel import partition
-from distpow_tpu.parallel.mesh_search import make_mesh, search_mesh
-from distpow_tpu.parallel.search import search
+from distpow_tpu.parallel.mesh_search import make_mesh, mesh_persistent_factory
+from distpow_tpu.parallel.search import persistent_search
 
 
 NONCES = [b"\x01\x02\x03\x04", b"\x02\x02\x02\x02", b"\xfe\xff"]
+# how the driver is handed its steps: the single-device persistent step,
+# or the XLA mesh persistent step over every conftest device
+ROUTES = ["one-device", "mesh"]
+
+
+def solve(nonce, difficulty, tbs, route="one-device", model=None, **kw):
+    """``persistent_search`` over ``route``.  A mesh width whose segment
+    batch does not divide across the devices keeps the single-device
+    step, as the mesh backends do."""
+    builder = None
+    if route == "mesh":
+        tb_lo, tbc = partition.contiguous_bounds(tbs)
+        mesh_builder = mesh_persistent_factory(
+            bytes(nonce), difficulty, tb_lo, tbc,
+            model or get_hash_model("md5"), make_mesh(jax.devices()))
+
+        def builder(*width):
+            try:
+                return mesh_builder(*width)
+            except ValueError:
+                return None
+
+    return persistent_search(nonce, difficulty, tbs, model=model,
+                             step_builder=builder, **kw)
 
 
 def test_public_search_name_survives_submodule_import_order():
-    """README surface: ``from distpow_tpu.parallel import search`` must
-    yield the FUNCTION even after something imports the same-named
-    submodule first (backends/__init__ does).  The PEP 562 version
-    regressed here — the import system's ``parallel.search = <module>``
-    setattr shadowed the lazy getattr (caught by the r4 verify drive);
-    the module-class property is order-independent."""
+    """README surface: ``from distpow_tpu.parallel import
+    persistent_search`` must yield the FUNCTION whatever imported the
+    package's submodules first (backends/__init__ imports
+    ``parallel.search``), and importing a submodule must not raise an
+    ImportWarning."""
     import subprocess
     import sys as _sys
 
@@ -29,9 +53,9 @@ def test_public_search_name_survives_submodule_import_order():
         "import warnings; warnings.simplefilter('error', ImportWarning)\n"
         "import distpow_tpu.backends\n"  # imports parallel.search module
         "import distpow_tpu.parallel.search\n"  # must not ImportWarning
-        "from distpow_tpu.parallel import search, search_mesh, make_mesh\n"
-        "assert callable(search), type(search)\n"
-        "assert callable(search_mesh) and callable(make_mesh)\n"
+        "from distpow_tpu.parallel import persistent_search, make_mesh\n"
+        "assert callable(persistent_search), type(persistent_search)\n"
+        "assert callable(make_mesh)\n"
         "print('SURFACE_OK')\n"
     )
     out = subprocess.run(
@@ -42,131 +66,106 @@ def test_public_search_name_survives_submodule_import_order():
     assert "SURFACE_OK" in out.stdout
 
 
+@pytest.mark.parametrize("route", ROUTES)
 @pytest.mark.parametrize("nonce", NONCES)
 @pytest.mark.parametrize("difficulty", [1, 2, 3])
-def test_search_matches_python_oracle_full_range(nonce, difficulty):
+def test_search_matches_python_oracle_full_range(nonce, difficulty, route):
     tbs = list(range(256))
     oracle = puzzle.python_search(nonce, difficulty, tbs)
-    got = search(nonce, difficulty, tbs, batch_size=1 << 14)
+    got = solve(nonce, difficulty, tbs, route, batch_size=1 << 14)
     assert got is not None
     assert got.secret == oracle
     assert puzzle.check_secret(nonce, got.secret, difficulty)
 
 
-def test_search_sub_partition():
+@pytest.mark.parametrize("nonce, tbs", [
     # a single worker's shard in a 4-worker config (worker.go:301-316)
-    nonce = b"\x05\x06\x07\x08"
-    bits = partition.worker_bits(4)
-    tbs = partition.thread_bytes(2, bits)
+    (b"\x05\x06\x07\x08", partition.thread_bytes(2, partition.worker_bits(4))),
+    (b"\x21\x22\x23", partition.thread_bytes(2, partition.worker_bits(4))),
+    (b"\x05\x06\x07\x08", list(range(64, 128))),  # pow2 sub-partition
+    # tb_count == 1 exercises the degenerate lane mapping
+    (b"\x09", [7]),
+    (b"\x05\x06\x07\x08", [7]),
+    (b"\x05\x06\x07\x08", [3, 4, 5]),  # contiguous non-pow2 (static regime)
+], ids=["worker-shard", "worker-shard-2", "pow2-sub", "single",
+        "single-2", "non-pow2"])
+def test_search_partitions(nonce, tbs):
     oracle = puzzle.python_search(nonce, 2, tbs)
-    got = search(nonce, 2, tbs, batch_size=1 << 13)
+    got = solve(nonce, 2, tbs, batch_size=1 << 13)
     assert got is not None and got.secret == oracle
     assert got.secret[0] in tbs
 
 
-def test_search_difficulty4_deep():
+@pytest.mark.parametrize("route", ROUTES)
+def test_search_difficulty4_deep(route):
     # difficulty 4 typically needs tens of thousands of candidates; pushes
-    # into width >= 2 chunks
+    # into width >= 2 chunks — the multi-segment loop must preserve
+    # enumeration order across segment boundaries and the width cursor
     nonce = b"\x11\x22\x33\x44"
     tbs = list(range(256))
-    got = search(nonce, 4, tbs, batch_size=1 << 16)
+    got = solve(nonce, 4, tbs, route, batch_size=1 << 16)
     assert got is not None
     assert puzzle.check_secret(nonce, got.secret, 4)
     oracle = puzzle.python_search(nonce, 4, tbs)
     assert got.secret == oracle
 
 
-def test_search_single_thread_byte():
-    # tb_count == 1 exercises the degenerate lane mapping
-    nonce = b"\x09"
-    got = search(nonce, 2, [7], batch_size=1 << 12)
-    oracle = puzzle.python_search(nonce, 2, [7])
-    assert got is not None and got.secret == oracle
-    assert got.secret[0] == 7
-
-
 def test_search_cancellation():
     ev = threading.Event()
     ev.set()
-    got = search(b"\x01", 30, list(range(256)), cancel_check=ev.is_set)
+    got = solve(b"\x01", 30, list(range(256)), cancel_check=ev.is_set)
     assert got is None
 
 
-def test_search_max_hashes_budget():
-    got = search(
-        b"\x01", 30, list(range(256)), batch_size=1 << 12, max_hashes=1 << 14
-    )
+@pytest.mark.parametrize("route", ROUTES)
+def test_search_max_hashes_budget(route):
+    got = solve(b"\x01", 30, list(range(256)), route, batch_size=1 << 12,
+                max_hashes=1 << 14)
     assert got is None
 
 
-def test_search_unsatisfiable_difficulty_returns_on_cancel():
-    got = search(b"\x01", 33, list(range(256)), cancel_check=lambda: True)
-    assert got is None
-    got = search(b"\x01", 33, list(range(256)), max_hashes=100)
-    assert got is None
-
-
-def test_search_unsatisfiable_difficulty_without_gate_raises():
-    """Bare library callers get a ValueError instead of an un-endable
-    wait (VERDICT r3 item 7); the worker path always passes a
-    cancel_check, so serving behavior (block-until-cancel, reference
-    parity with worker.go:246-256) is unchanged."""
-    with pytest.raises(ValueError, match="unsatisfiable"):
-        search(b"\x01", 33, list(range(256)))
-
-    from distpow_tpu.backends import native_miner
-
-    backend = native_miner.NativeBackend()
-    with pytest.raises(ValueError, match="unsatisfiable"):
-        backend.search(b"\x01", 33, list(range(256)))
-
-
-def test_search_sha256_model():
-    nonce = b"\x0a\x0b"
+@pytest.mark.parametrize("gate", ["cancel", "budget", "none"])
+def test_search_unsatisfiable_difficulty_gates(gate):
+    """An unsatisfiable difficulty returns None on either gate; bare
+    library callers with neither get a ValueError instead of an
+    un-endable wait (the worker path always passes a cancel_check, so
+    serving blocks until cancelled, reference parity with
+    worker.go:246-256)."""
     tbs = list(range(256))
-    oracle = puzzle.python_search(nonce, 2, tbs, algo="sha256")
-    got = search(nonce, 2, tbs, model=SHA256, batch_size=1 << 13)
-    assert got is not None and got.secret == oracle
+    if gate == "cancel":
+        assert solve(b"\x01", 33, tbs, cancel_check=lambda: True) is None
+    elif gate == "budget":
+        assert solve(b"\x01", 33, tbs, max_hashes=100) is None
+    else:
+        with pytest.raises(ValueError, match="unsatisfiable"):
+            solve(b"\x01", 33, tbs)
+
+        from distpow_tpu.backends import native_miner
+
+        backend = native_miner.NativeBackend()
+        with pytest.raises(ValueError, match="unsatisfiable"):
+            backend.search(b"\x01", 33, tbs)
 
 
-def test_search_sha1_model():
-    """Third registry model end-to-end through the generic driver — the
-    layers below the registry are hash-agnostic, so enumeration-order
-    parity with the python oracle must hold for free."""
-    from distpow_tpu.models.registry import SHA1
-
-    nonce = b"\x0c\x0d"
+@pytest.mark.parametrize("algo, nonce", [
+    ("sha256", b"\x0a\x0b"),
+    ("sha256", b"\x31\x32\x33\x34"),
+    # the layers below the registry are hash-agnostic, so
+    # enumeration-order parity with the python oracle holds for free
+    ("sha1", b"\x0c\x0d"),
+    ("sha1", b"\x31\x32\x33\x34"),
+    ("ripemd160", b"\x0a\x0b"),
+    # 3 blocks: the long-nonce host-absorption path
+    ("ripemd160", bytes(range(200))),
+    # 128-byte blocks and a 16-byte length field
+    ("sha512", b"\x0a\x0b"),
+    ("blake2b_256", b"\x31\x32\x33\x34"),
+])
+def test_search_models(algo, nonce):
     tbs = list(range(256))
-    oracle = puzzle.python_search(nonce, 2, tbs, algo="sha1")
-    got = search(nonce, 2, tbs, model=SHA1, batch_size=1 << 13)
-    assert got is not None and got.secret == oracle
-
-
-def test_search_ripemd160_model():
-    """Fourth registry model (round 4) end-to-end through the generic
-    driver, including the long-nonce host-absorption path."""
-    from distpow_tpu.models.registry import RIPEMD160
-
-    nonce = b"\x0a\x0b"
-    tbs = list(range(256))
-    oracle = puzzle.python_search(nonce, 2, tbs, algo="ripemd160")
-    got = search(nonce, 2, tbs, model=RIPEMD160, batch_size=1 << 13)
-    assert got is not None and got.secret == oracle
-    long_nonce = bytes(range(200))  # 3 blocks allows host absorption
-    oracle2 = puzzle.python_search(long_nonce, 2, tbs, algo="ripemd160")
-    got2 = search(long_nonce, 2, tbs, model=RIPEMD160, batch_size=1 << 13)
-    assert got2 is not None and got2.secret == oracle2
-
-
-def test_search_sha512_model():
-    """Fifth registry model (round 4): 128-byte blocks and a 16-byte
-    length field through the generic driver — the interface-generality
-    case."""
-    from distpow_tpu.models.registry import SHA512
-
-    tbs = list(range(256))
-    oracle = puzzle.python_search(b"\x0a\x0b", 2, tbs, algo="sha512")
-    got = search(b"\x0a\x0b", 2, tbs, model=SHA512, batch_size=1 << 13)
+    oracle = puzzle.python_search(nonce, 2, tbs, algo=algo)
+    got = solve(nonce, 2, tbs, model=get_hash_model(algo),
+                batch_size=1 << 13)
     assert got is not None and got.secret == oracle
 
 
@@ -181,7 +180,7 @@ def test_search_sha512_boundary_layouts():
     for L in (111, 112, 140):
         nonce = bytes(range(L))
         o = puzzle.python_search(nonce, 1, tbs, algo="sha512")
-        g = search(nonce, 1, tbs, model=SHA512, batch_size=1 << 12)
+        g = solve(nonce, 1, tbs, model=SHA512, batch_size=1 << 12)
         assert g is not None and g.secret == o, L
 
 
@@ -196,24 +195,21 @@ def test_search_all_constant_tail_block():
     for model, algo in ((SHA256, "sha256"), (SHA1, "sha1")):
         nonce = bytes(range(59))  # 59 + 1 + 4 = 64 <= 64 < 64 + 9
         o = puzzle.python_search(nonce, 1, tbs, algo=algo)
-        g = search(nonce, 1, tbs, model=model, batch_size=1 << 12)
+        g = solve(nonce, 1, tbs, model=model, batch_size=1 << 12)
         assert g is not None and g.secret == o, algo
 
 
 def test_mesh_search_sha1_model():
     """sha1 through the shard_map mesh step (the stacked-window vma fix
     in sha1_jax._compress_loop is only exercised under shard_map)."""
-    import jax
-
-    from distpow_tpu.models.registry import SHA1
-    from distpow_tpu.parallel.mesh_search import make_mesh, search_mesh
+    from distpow_tpu.backends import JaxMeshBackend
 
     nonce = b"\x05\x06"
     tbs = list(range(256))
     oracle = puzzle.python_search(nonce, 2, tbs, algo="sha1")
-    got = search_mesh(nonce, 2, tbs, model=SHA1,
-                      mesh=make_mesh(jax.devices()), batch_size=1 << 13)
-    assert got is not None and got.secret == oracle
+    got = JaxMeshBackend(hash_model="sha1", batch_size=1 << 13).search(
+        nonce, 2, tbs)
+    assert got == oracle
 
 
 @pytest.mark.slow
@@ -221,26 +217,18 @@ def test_mesh_search_new_models():
     """ripemd160, sha512, and blake2b through the shard_map mesh step:
     the stacked-window sha512 loop form must carry cleanly under
     shard_map's varying-axis types, the two-line ripemd compression
-    must shard like any other, and blake2b's fori carry must stay
+    must shard like any other, and blake2b's carry must stay
     vma-uniform although half its initial limbs are replicated IV
-    constants (the r5 multichip-dryrun regression — blake2b_jax.py's
-    varying-zero promotion)."""
-    import jax
-
-    from distpow_tpu.models.registry import RIPEMD160, SHA512, get_hash_model
-    from distpow_tpu.parallel.mesh_search import make_mesh, search_mesh
-
-    mesh = make_mesh(jax.devices())
+    constants (blake2b_jax.py's varying-zero promotion)."""
     tbs = list(range(256))
-    for model, algo in ((RIPEMD160, "ripemd160"), (SHA512, "sha512"),
-                        (get_hash_model("blake2b_256"), "blake2b_256"),
-                        # composed finalize under shard_map: the second
-                        # compression's constant init/message words are
-                        # varying-promoted (sha256d_jax.sha256d_finalize)
-                        (get_hash_model("sha256d"), "sha256d")):
+    for algo in ("ripemd160", "sha512", "blake2b_256",
+                 # composed finalize under shard_map: the second
+                 # compression's constant init/message words are
+                 # varying-promoted (sha256d_jax.sha256d_finalize)
+                 "sha256d"):
         oracle = puzzle.python_search(b"\x0a\x0b", 2, tbs, algo=algo)
-        got = search_mesh(b"\x0a\x0b", 2, tbs, model=model, mesh=mesh,
-                          batch_size=1 << 13)
+        got = solve(b"\x0a\x0b", 2, tbs, "mesh",
+                    model=get_hash_model(algo), batch_size=1 << 13)
         assert got is not None and got.secret == oracle, algo
 
 
@@ -249,36 +237,31 @@ def test_search_long_nonce_multi_block():
     nonce = bytes(range(256))[:100]
     tbs = list(range(256))
     oracle = puzzle.python_search(nonce, 2, tbs)
-    got = search(nonce, 2, tbs, batch_size=1 << 13)
+    got = solve(nonce, 2, tbs, batch_size=1 << 13)
     assert got is not None and got.secret == oracle
 
 
 @pytest.mark.parametrize("difficulty", [2, 3])
 def test_mesh_search_matches_single_device(difficulty):
+    from distpow_tpu.backends import JaxMeshBackend
+
     nonce = b"\x01\x02\x03\x04"
     tbs = list(range(256))
-    mesh = make_mesh(jax.devices())
     oracle = puzzle.python_search(nonce, difficulty, tbs)
-    got = search_mesh(
-        nonce, difficulty, tbs, mesh=mesh, batch_size=1 << 14
-    )
-    assert got is not None
-    assert got.secret == oracle
+    got = JaxMeshBackend(batch_size=1 << 14).search(nonce, difficulty, tbs)
+    assert got == oracle == solve(nonce, difficulty, tbs,
+                                  batch_size=1 << 14).secret
 
 
 def test_mesh_search_sub_partition_and_chunk_split():
-    mesh = make_mesh(jax.devices())
+    from distpow_tpu.backends import JaxMeshBackend
+
+    backend = JaxMeshBackend(batch_size=1 << 13)
     nonce = b"\x03\x01\x04\x01"
-    # tb-split: 64 tbs over 8 devices
-    tbs = partition.thread_bytes(1, 2)
-    oracle = puzzle.python_search(nonce, 2, tbs)
-    got = search_mesh(nonce, 2, tbs, mesh=mesh, batch_size=1 << 13)
-    assert got is not None and got.secret == oracle
-    # chunk-split: fewer tbs than devices
-    tbs = [5, 6, 7]
-    oracle = puzzle.python_search(nonce, 2, tbs)
-    got = search_mesh(nonce, 2, tbs, mesh=mesh, batch_size=1 << 13)
-    assert got is not None and got.secret == oracle
+    # 64 tbs over 8 devices; then fewer tbs than devices
+    for tbs in (partition.thread_bytes(1, 2), [5, 6, 7]):
+        oracle = puzzle.python_search(nonce, 2, tbs)
+        assert backend.search(nonce, 2, tbs) == oracle
 
 
 def test_launch_steps_partition_independent():
@@ -294,17 +277,27 @@ def test_launch_steps_partition_independent():
             assert len(ks) == 1, (batch_size, vw, ks)
 
 
-def test_search_small_launch_budget_matches_oracle():
-    """Multi-sub-batch dispatches (launch_steps > 1) preserve reference
-    enumeration order across sub-batch boundaries."""
-    nonce = b"\x0a\x0b\x0c\x0d"
-    for d in (2, 3):
+@pytest.mark.parametrize("nonce, difficulties, budget", [
+    (b"\x0a\x0b\x0c\x0d", (2, 3), dict(batch_size=1 << 13,
+                                         launch_candidates=1 << 16)),
+    # a tiny per-dispatch budget forces MANY multi-segment dispatches
+    # through the pipeline
+    (b"\x41\x42", (3,), dict(batch_size=1 << 10,
+                              launch_candidates=1 << 12)),
+], ids=["segments-16", "segments-4"])
+def test_search_small_launch_budget_matches_oracle(nonce, difficulties,
+                                                   budget):
+    """Multi-segment dispatches preserve reference enumeration order
+    across segment boundaries, and the FIFO drain hands back the
+    enumeration-order first hit."""
+    from distpow_tpu.runtime.metrics import REGISTRY
+
+    steps = REGISTRY.get("search.persistent_steps")
+    for d in difficulties:
         oracle = puzzle.python_search(nonce, d, list(range(256)))
-        got = search(
-            nonce, d, list(range(256)), batch_size=1 << 13,
-            launch_candidates=1 << 16,
-        )
+        got = solve(nonce, d, list(range(256)), **budget)
         assert got is not None and got.secret == oracle
+    assert REGISTRY.get("search.persistent_steps") > steps
 
 
 def test_warmup_covers_sub_partitions_with_launch_steps():
@@ -324,54 +317,60 @@ def test_warmup_covers_sub_partitions_with_launch_steps():
 
 
 def test_mesh_warmup_covers_all_pow2_partitions():
-    """Boot warmup must pre-compile both mesh regimes, and batch_local
-    must be partition-independent even when the configured batch size is
-    not divisible by tbc * n_dev (e.g. 10_000 on 8 devices), so every
-    pow2 partition's first Mine is pure dispatch."""
+    """Boot warmup must pre-compile the mesh programs, and the per-device
+    batch must be partition-independent even when the configured batch
+    size is not divisible by tbc * n_dev (e.g. 10_000 on 8 devices), so
+    every pow2 partition's first Mine is pure dispatch."""
     from distpow_tpu.backends import JaxMeshBackend
-    from distpow_tpu.parallel.mesh_search import _dyn_mesh_step
+    from distpow_tpu.parallel.mesh_search import mesh_persistent_step
 
     b = JaxMeshBackend(batch_size=10_000)
-    b.warmup([4], [0, 1])
-    misses = _dyn_mesh_step.cache_info().misses
+    b.warmup([4], [0, 1, 2])
+    misses = mesh_persistent_step.cache_info().misses
     n_dev = int(b._get_mesh().devices.size)
     for tbs in (list(range(256)),               # tb-split
-                list(range(max(1, n_dev // 2))),  # chunk-split, warmed tbc
-                [7],                             # chunk-split, other tbc
+                list(range(max(1, n_dev // 2))),  # fewer tbs, warmed tbc
+                [7],                             # fewer tbs, other tbc
                 list(range(4, 6))):
         secret = b.search(b"\x00\x01\x02\x03", 2, tbs)
         assert secret is not None
         assert puzzle.check_secret(b"\x00\x01\x02\x03", secret, 2)
-    assert _dyn_mesh_step.cache_info().misses == misses, \
+    assert mesh_persistent_step.cache_info().misses == misses, \
         "serving recompiled a program warmup should have covered"
 
 
 def test_non_pow2_mesh_warns_at_boot_and_request_time(caplog):
-    """A non-power-of-two mesh serves through per-request nonce-keyed
-    compiles; both the boot warmup skip and the request-time compile must
-    SAY so (VERDICT r2 weak #5) — a 6-device dev mesh should never stall
-    silently."""
+    """On a mesh whose device count is not a power of two the segment
+    batch does not divide across the devices, so each width is served
+    through the single-device persistent step; both the boot warmup and
+    the request must SAY so, and the request counts it under
+    ``fallback.lane_demotions`` — a 6-device dev mesh should never fall
+    back silently."""
     import logging
 
     from distpow_tpu.backends import JaxMeshBackend
+    from distpow_tpu.runtime.metrics import REGISTRY
 
     b = JaxMeshBackend(batch_size=1 << 13, mesh_devices=6)
     with caplog.at_level(logging.WARNING):
         b.warmup([4], [0, 1])
-        assert any("not a power of two" in r.message for r in caplog.records)
+        assert any("does not divide across 6 devices (not a power of two)"
+                   in r.message for r in caplog.records)
         caplog.clear()
+        demotions = REGISTRY.get("fallback.lane_demotions")
         secret = b.search(b"\x09\x08", 2, list(range(256)))
-        assert any("nonce-keyed static mesh program" in r.message
+        assert any("through the single-device persistent step" in r.message
                    for r in caplog.records)
+    assert REGISTRY.get("fallback.lane_demotions") > demotions
     assert secret is not None
     assert puzzle.check_secret(b"\x09\x08", secret, 2)
 
 
 def test_mesh_search_cancellation():
-    mesh = make_mesh(jax.devices())
-    got = search_mesh(
-        b"\x01", 30, list(range(256)), mesh=mesh, cancel_check=lambda: True
-    )
+    from distpow_tpu.backends import JaxMeshBackend
+
+    got = JaxMeshBackend().search(b"\x01", 30, list(range(256)),
+                                  cancel_check=lambda: True)
     assert got is None
 
 
@@ -407,10 +406,10 @@ def _fuzz_against_oracle(models_algos, seed, n, max_difficulty=3,
                 else _fuzz_configs(rng, n, max_difficulty)):
             # The oracle generator is infinite, so it gets a candidate
             # budget (an unbounded call could never return None and the
-            # exhausted arm would be dead — review r4).  The driver's
-            # max_hashes is LAUNCH-QUANTIZED (pipelined in-flight
-            # launches all count), so an exact shared budget can give
-            # up one launch earlier than the oracle; the contract
+            # exhausted arm would be dead).  The driver's max_hashes is
+            # DISPATCH-QUANTIZED (it is checked between dispatches), so
+            # an exact shared budget can give up one dispatch earlier
+            # than the oracle; the contract
             # tested is therefore budget-aware in each direction:
             # - oracle found after p candidates  => the driver, allowed
             #   p plus generous launch slack, finds the SAME secret;
@@ -436,8 +435,8 @@ def _fuzz_against_oracle(models_algos, seed, n, max_difficulty=3,
                                                 difficulty, algo))
 
             if oracle is None:
-                got = search(nonce, difficulty, tbs, model=model,
-                             batch_size=1 << 12, max_hashes=budget)
+                got = solve(nonce, difficulty, tbs, model=model,
+                            batch_size=1 << 12, max_hashes=budget)
                 # pipelined launches legally overshoot max_hashes, so a
                 # find PAST the budget is legitimate; a find the driver
                 # claims was within it while the oracle saw none is the
@@ -449,9 +448,9 @@ def _fuzz_against_oracle(models_algos, seed, n, max_difficulty=3,
                 ), case
             else:
                 slack = (1 << 15) + 4 * (1 << 12)
-                got = search(nonce, difficulty, tbs, model=model,
-                             batch_size=1 << 12,
-                             max_hashes=counted[0] + slack)
+                got = solve(nonce, difficulty, tbs, model=model,
+                            batch_size=1 << 12,
+                            max_hashes=counted[0] + slack)
                 assert got is not None and (
                     got.secret == oracle
                     # a wrapped alias may legitimately pre-empt the
@@ -588,40 +587,42 @@ def test_search_differential_fuzz_registry_nightly():
 
 def test_early_exits_account_all_dispatched_work():
     """Every exit path — cancel mid-pipeline, found mid-pipeline — must
-    leave search.hashes equal to the TOTAL dispatched candidates,
-    including launches still in flight (the device completes them
-    either way; round 4).  A fake step factory pins launch sizes so the
-    expected totals are exact, independent of the real launch
-    multiplier."""
+    leave search.hashes equal to the work the device does: on a cancel
+    the dispatches still in flight count their span (the device runs
+    them out), and behind a hit the chained dispatches count nothing
+    (they halt on the device) and count under
+    ``search.halted_dispatches``.  A fake step builder pins dispatch
+    sizes so the expected totals are exact."""
     import jax.numpy as jnp
 
-    from distpow_tpu.ops.search_step import SENTINEL
+    from distpow_tpu.ops.search_step import HALT, SENTINEL
     from distpow_tpu.runtime.metrics import REGISTRY
 
     dispatched = [0]
 
-    def make_factory(hit_on_launch=None):
+    def make_builder(hit_on_launch=None):
         launches = [0]
 
-        def factory(vw, extra, target_chunks, launch_steps=1):
+        def builder(vw, extra, target_chunks, segments):
             # 5 divides every early segment's chunk count exactly
             # (width1: 255, width2: 65280, width3: 16711680), so no
-            # launch straddles a segment end and the fake's per-launch
-            # count matches the driver's min(chunks, hi - chunk0) clamp
-            # on every launch (review r4)
-            chunks = 5 if vw else 1
-
-            def step(chunk0):
+            # dispatch straddles a segment end and the fake's count
+            # matches the driver's in-segment clamp on every dispatch
+            def step(chunk0, prev):
                 launches[0] += 1
-                dispatched[0] += chunks * 256
-                if hit_on_launch is not None and launches[0] == hit_on_launch:
-                    return jnp.uint32(0)  # flat index 0 of this launch
-                return jnp.uint32(SENTINEL)
+                if hit_on_launch is not None and launches[0] > hit_on_launch:
+                    return jnp.asarray([HALT, 0], jnp.uint32)
+                dispatched[0] += 5 * 256
+                if launches[0] == hit_on_launch:
+                    # flat index 0 of this dispatch, in its one segment
+                    return jnp.asarray([0, 1], jnp.uint32)
+                return jnp.asarray([SENTINEL, 1], jnp.uint32)
 
-            return step, chunks
-        return factory
+            return step, 5, 5
 
-    # cancel mid-segment with a launch in flight
+        return builder
+
+    # cancel mid-segment with dispatches in flight
     calls = [0]
 
     def cancel_after(n):
@@ -630,34 +631,38 @@ def test_early_exits_account_all_dispatched_work():
             return calls[0] > n
         return check
 
-    dispatched[0] = 0
+    dispatched[0] = 256  # the width-0 probe
     before = REGISTRY.get("search.hashes")
-    got = search(b"\x01", 30, list(range(256)), batch_size=1 << 10,
-                 cancel_check=cancel_after(6),
-                 step_factory=make_factory())
+    got = persistent_search(b"\x01", 30, list(range(256)),
+                            batch_size=1 << 10, cancel_check=cancel_after(6),
+                            step_builder=make_builder())
     assert got is None
-    assert REGISTRY.get("search.hashes") - before == dispatched[0] > 0
+    assert REGISTRY.get("search.hashes") - before == dispatched[0] > 256
 
-    # found mid-pipeline: the undrained trailing launch still counts.
-    # hit on launch 4 (width 1, flat index 0 -> chunk 1, tb 0 solves
-    # nothing real, so use difficulty 0 where everything solves)
-    dispatched[0] = 0
+    # found mid-pipeline: the hit's dispatch counts, the one chained
+    # behind it halts.  At 3 nibbles nonce 570400 has no width-0
+    # solution and its (tb 0, chunk 16) solves: flat index 0 of the 4th
+    # dispatch (chunks 16-20)
+    dispatched[0] = 256
     before = REGISTRY.get("search.hashes")
-    got = search(b"\x01", 0, list(range(256)), batch_size=1 << 10,
-                 step_factory=make_factory(hit_on_launch=4))
-    assert got is not None
-    assert REGISTRY.get("search.hashes") - before == dispatched[0] > 0
+    halted = REGISTRY.get("search.halted_dispatches")
+    got = persistent_search(bytes.fromhex("570400"), 3, list(range(256)),
+                            batch_size=1 << 10, max_width=1,
+                            step_builder=make_builder(hit_on_launch=4))
+    assert got is not None and got.secret == bytes([0, 16])
+    assert got.hashes_tried == dispatched[0]
+    assert REGISTRY.get("search.hashes") - before == dispatched[0] > 256
+    assert REGISTRY.get("search.halted_dispatches") - halted == 1
 
 
 @pytest.mark.slow
 def test_mesh_search_differential_fuzz():
     """Seeded mesh fuzz: random power-of-two partitions (including
-    sub-runs, single bytes, and fewer-tbs-than-devices chunk-split
-    configs) through shard_map vs the hashlib oracle — the mesh twin of
+    sub-runs, single bytes, and fewer-tbs-than-devices configs) through
+    the mesh persistent step vs the hashlib oracle — the mesh twin of
     test_search_differential_fuzz_*."""
     import random
 
-    mesh = make_mesh(jax.devices())
     rng = random.Random(0xD1CE)
     lens = [0, 2, 55, 63, 64, 100, 112]
     for _ in range(10):
@@ -667,13 +672,11 @@ def test_mesh_search_differential_fuzz():
         lo = rng.randrange(0, 256 - size + 1, size)
         tbs = list(range(lo, lo + size))
         oracle = puzzle.python_search(nonce, difficulty, tbs)
-        got = search_mesh(nonce, difficulty, tbs, mesh=mesh,
-                          batch_size=1 << 12)
+        got = solve(nonce, difficulty, tbs, "mesh", batch_size=1 << 12)
         case = (nonce.hex()[:12], difficulty, lo, size)
         # same wrapped-alias tolerance as _fuzz_against_oracle: a
-        # segment-overrun launch may legitimately return a verified
-        # non-canonical secret (search.py batch-boundary note), and
-        # launch quantization here depends on the device count
+        # segment-overrun dispatch may legitimately return a verified
+        # non-canonical secret (search.py batch-boundary note)
         assert got is not None, case
         assert got.secret == oracle or (
             got.chunk and got.chunk[-1] == 0

@@ -1,132 +1,27 @@
-"""Persistent serving-loop suite (docs/SERVING.md, ISSUE 6).
+"""Persistent serving-loop suite (docs/SERVING.md).
 
-Three layers:
+The driver's first-hit parity with the python oracle across widths,
+partitions and hash models lives in tests/test_search.py.  Here:
 
-* **golden parity** — the persistent driver must return the SAME
-  first-hit secret (reference enumeration order, byte-identical) as the
-  solo serial driver and the python oracle, across chunk widths,
-  partitions (full / sub / single-byte / non-power-of-two) and hash
-  models.  This is the acceptance bar that lets the persistent loop be
-  the serving default.
 * **chained halt** — a dispatch behind a hit, or behind a halted one,
   exits at its first on-device loop check; results at width
-  boundaries match the oracle; halted dispatches count no work;
-  cancel latency is bounded; and the polling drain never issues a
-  blocking result conversion (``search.blocking_syncs`` stays flat
-  while the serial driver's counter moves) nor a device-array index.
-* **backend plumbing** — ``JaxBackend(loop=...)`` selects the driver,
-  warmup compiles the persistent programs, and the config default
-  serves persistent.
+  boundaries match the oracle on one device and on the mesh; halted
+  dispatches count no work; cancel latency is bounded; and the polling
+  drain never issues a device-array index.
+* **backend warmup** — after warmup a served solve compiles nothing.
 """
 
 import threading
 import time
 
+import jax
 import pytest
 
 from distpow_tpu.models import puzzle
-from distpow_tpu.parallel import partition
-from distpow_tpu.parallel.search import (
-    StopFlag,
-    persistent_search,
-    search,
-)
+from distpow_tpu.models.registry import get_hash_model
+from distpow_tpu.parallel.mesh_search import make_mesh, mesh_persistent_factory
+from distpow_tpu.parallel.search import StopFlag, persistent_search
 from distpow_tpu.runtime.metrics import REGISTRY
-
-
-NONCES = [b"\x01\x02\x03\x04", b"\x02\x02\x02\x02", b"\xfe\xff"]
-
-
-# -- golden parity -----------------------------------------------------------
-
-@pytest.mark.parametrize("nonce", NONCES)
-@pytest.mark.parametrize("difficulty", [1, 2, 3])
-def test_persistent_matches_serial_and_oracle_full_range(nonce, difficulty):
-    tbs = list(range(256))
-    oracle = puzzle.python_search(nonce, difficulty, tbs)
-    serial = search(nonce, difficulty, tbs, batch_size=1 << 14)
-    persistent = persistent_search(nonce, difficulty, tbs,
-                                   batch_size=1 << 14)
-    assert persistent is not None and serial is not None
-    assert persistent.secret == serial.secret == oracle
-
-
-def test_persistent_parity_deep_widths():
-    # difficulty 4 pushes into width >= 2 chunks — the multi-segment
-    # while_loop must preserve enumeration order across segment
-    # boundaries and across the width cursor
-    nonce = b"\x11\x22\x33\x44"
-    tbs = list(range(256))
-    got = persistent_search(nonce, 4, tbs, batch_size=1 << 16)
-    assert got is not None
-    assert got.secret == puzzle.python_search(nonce, 4, tbs)
-
-
-@pytest.mark.parametrize("tbs", [
-    list(range(64, 128)),            # pow2 sub-partition (sharded worker)
-    [7],                             # single thread byte
-    [3, 4, 5],                       # contiguous non-pow2 (static regime)
-], ids=["pow2-sub", "single", "non-pow2"])
-def test_persistent_parity_partitions(tbs):
-    nonce = b"\x05\x06\x07\x08"
-    oracle = puzzle.python_search(nonce, 2, tbs)
-    got = persistent_search(nonce, 2, tbs, batch_size=1 << 13)
-    assert got is not None and got.secret == oracle
-    assert got.secret[0] in tbs
-
-
-def test_persistent_parity_worker_shard():
-    nonce = b"\x21\x22\x23"
-    bits = partition.worker_bits(4)
-    tbs = partition.thread_bytes(2, bits)
-    oracle = puzzle.python_search(nonce, 2, tbs)
-    got = persistent_search(nonce, 2, tbs, batch_size=1 << 13)
-    assert got is not None and got.secret == oracle
-
-
-@pytest.mark.parametrize("model_name", ["sha1", "sha256", "blake2b_256"])
-def test_persistent_parity_models(model_name):
-    from distpow_tpu.models.registry import get_hash_model
-
-    model = get_hash_model(model_name)
-    nonce = b"\x31\x32\x33\x34"
-    tbs = list(range(256))
-    oracle = puzzle.python_search(nonce, 2, tbs, algo=model_name)
-    serial = search(nonce, 2, tbs, model=model, batch_size=1 << 13)
-    got = persistent_search(nonce, 2, tbs, model=model,
-                            batch_size=1 << 13)
-    assert got is not None and serial is not None
-    assert got.secret == serial.secret == oracle
-
-
-def test_persistent_small_launch_budget_matches_oracle():
-    # a tiny per-dispatch budget forces MANY multi-segment dispatches
-    # through the pipeline — the FIFO drain must still hand back the
-    # enumeration-order first hit
-    nonce = b"\x41\x42"
-    tbs = list(range(256))
-    got = persistent_search(nonce, 3, tbs, batch_size=1 << 10,
-                            launch_candidates=1 << 12)
-    assert got is not None
-    assert got.secret == puzzle.python_search(nonce, 3, tbs)
-    assert REGISTRY.get("search.persistent_steps") > 0
-
-
-# -- budget / unsatisfiable gates (contract parity with search()) ------------
-
-def test_persistent_max_hashes_budget():
-    got = persistent_search(b"\x01", 30, list(range(256)),
-                            batch_size=1 << 12, max_hashes=1 << 14)
-    assert got is None
-
-
-def test_persistent_unsatisfiable_gates():
-    assert persistent_search(b"\x01", 33, list(range(256)),
-                             cancel_check=lambda: True) is None
-    assert persistent_search(b"\x01", 33, list(range(256)),
-                             max_hashes=100) is None
-    with pytest.raises(ValueError, match="unsatisfiable"):
-        persistent_search(b"\x01", 33, list(range(256)))
 
 
 # -- chained halt / polling drain --------------------------------------------
@@ -185,9 +80,11 @@ def test_persistent_parity_at_width_boundary(nonce):
     tbs = list(range(256))
     oracle = puzzle.python_search(nonce, 4, tbs)
     assert len(oracle) == (2 if nonce == BOUNDARY_NONCES[0] else 3)
-    serial = search(nonce, 4, tbs, **BOUNDARY)
+    builder = mesh_persistent_factory(nonce, 4, 0, 256, get_hash_model("md5"),
+                                      make_mesh(jax.devices()))
+    mesh = persistent_search(nonce, 4, tbs, step_builder=builder, **BOUNDARY)
     persistent = persistent_search(nonce, 4, tbs, **BOUNDARY)
-    assert persistent.secret == serial.secret == oracle
+    assert persistent.secret == mesh.secret == oracle
 
 
 @pytest.mark.parametrize("depth", [2, 3])
@@ -274,42 +171,12 @@ def test_persistent_cancel_latency_bounded():
     assert latency < 10.0, f"cancel took {latency:.1f}s"
 
 
-def test_persistent_never_blocks_serial_does():
-    nonce, tbs = b"\x61\x62", list(range(256))
-    b0 = REGISTRY.get("search.blocking_syncs")
-    serial = search(nonce, 3, tbs, batch_size=1 << 10,
-                    launch_candidates=1 << 12)
-    b1 = REGISTRY.get("search.blocking_syncs")
-    persistent = persistent_search(nonce, 3, tbs, batch_size=1 << 10,
-                                   launch_candidates=1 << 12)
-    b2 = REGISTRY.get("search.blocking_syncs")
-    assert serial.secret == persistent.secret
-    assert b1 - b0 >= 1, "serial drain stopped counting blocking syncs"
-    assert b2 == b1, "persistent drain issued a blocking conversion"
-
-
-# -- backend plumbing --------------------------------------------------------
-
-def test_jax_backend_loop_selection_and_default():
-    from distpow_tpu.backends import JaxBackend, get_backend
-
-    assert JaxBackend().loop == "persistent"  # the serving default
-    assert get_backend("jax", loop="serial").loop == "serial"
-    with pytest.raises(ValueError, match="unknown search loop"):
-        JaxBackend(loop="bogus")
-    nonce, tbs = b"\x71\x72", list(range(256))
-    per = JaxBackend(batch_size=1 << 13).search(nonce, 2, tbs)
-    ser = JaxBackend(batch_size=1 << 13, loop="serial").search(
-        nonce, 2, tbs)
-    assert per == ser == puzzle.python_search(nonce, 2, tbs)
-
+# -- backend warmup ----------------------------------------------------------
 
 def test_jax_backend_persistent_warmup_compiles_and_serves(monkeypatch):
     """After warmup a served solve compiles nothing: the warmed
     programs, dispatched behind CHAIN_HALT, are the ones a solve
     chains through its widths."""
-    import jax
-
     from distpow_tpu.backends import JaxBackend
     from distpow_tpu.sched import lanes
 
@@ -335,10 +202,3 @@ def test_jax_backend_persistent_warmup_compiles_and_serves(monkeypatch):
     finally:
         jax.monitoring.unregister_event_duration_listener(listen)
     assert compiles == []
-
-
-def test_worker_config_search_loop_plumbs_to_backend():
-    from distpow_tpu.runtime.config import WorkerConfig
-
-    assert WorkerConfig().SearchLoop == "persistent"
-    assert WorkerConfig(SearchLoop="serial").SearchLoop == "serial"

@@ -191,30 +191,39 @@ def test_failed_mine_records_error_outcomes_and_no_round_spans():
         s.close()
 
 
-@pytest.mark.parametrize("driver", ["search", "persistent_search"])
-def test_search_drivers_record_segments_and_launch_waits(driver):
+@pytest.mark.parametrize("driver", ["persistent_search", "backend"])
+def test_search_drivers_record_segments_and_launch_waits(driver,
+                                                         monkeypatch):
+    """The search driver records one ``search.segment`` span per width,
+    with its dispatch count and the devices its step spans, and
+    ``search.poll`` waits with no attributes — called directly and
+    through the backend a worker serves with."""
+    from distpow_tpu.backends import JaxBackend
+    from distpow_tpu.sched import lanes
+
+    # one device, as on a one-chip worker: the mesh lane stays off
+    monkeypatch.setattr(lanes, "detect_caps",
+                        lambda: lanes.LaneCaps("cpu", 1))
     search_mod = importlib.import_module("distpow_tpu.parallel.search")
     rec = SPANS
     rec.reset()
     nonce = b"\x72\x01\x02\x03"
+    kw = dict(batch_size=1 << 10, launch_candidates=1 << 12)
     with rec.bind(777, "w-test"):
-        r = getattr(search_mod, driver)(
-            nonce, 3, list(range(256)), batch_size=1 << 10,
-            launch_candidates=1 << 12)
-    assert r is not None and puzzle.check_secret(nonce, r.secret, 3)
+        if driver == "backend":
+            secret = JaxBackend(batch_size=kw["batch_size"],
+                                max_launch=kw["launch_candidates"]).search(
+                nonce, 3, list(range(256)))
+        else:
+            secret = search_mod.persistent_search(
+                nonce, 3, list(range(256)), **kw).secret
+    assert puzzle.check_secret(nonce, secret, 3)
     spans = rec.spans_for(777)
     segs = [x for x in spans if x["name"] == "search.segment"]
-    assert [x["attrs"]["width"] for x in segs] == \
-        list(range(len(r.secret)))
+    assert [x["attrs"]["width"] for x in segs] == list(range(len(secret)))
     assert all(x["node"] == "w-test" and x["attrs"]["dispatches"] >= 1
                for x in segs)
     # one device serves every width here (tests/test_mesh_reference.py
     # covers the mesh steps)
     assert all(x["attrs"]["devices"] == 1 for x in segs)
-    if driver == "search":
-        launches = [x for x in spans if x["name"] == "search.launch"]
-        assert sum(x["attrs"]["dispatches"] for x in segs) >= len(launches)
-        assert launches and all(x["attrs"]["n_cand"] > 0 for x in launches)
-    else:
-        assert all(x["attrs"] == {} for x in spans
-                   if x["name"] == "search.poll")
+    assert all(x["attrs"] == {} for x in spans if x["name"] == "search.poll")

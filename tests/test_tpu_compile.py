@@ -5,9 +5,9 @@ described, not attached: what it refuses (a misaligned slice, too much
 fast memory, a program that does not fit) would otherwise surface on
 the chip.  These compile the main path's programs at the shapes
 chip_smoke.py serves: the md5 / sha256 / sha1 / sha512 kernels at
-their MODEL_GEOMETRY, the md5 persistent XLA serving step at batch
-2^20, and the md5 pallas-mesh steps over a 2x2 mesh: the relaunch step
-and the chained persistent step the backend serves through.
+their MODEL_GEOMETRY in the chained step the ``pallas`` backend serves
+through on one chip, the md5 persistent XLA serving step at batch
+2^20, and the md5 chained pallas-mesh step over a 2x2 mesh.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and xdist workers that
@@ -73,39 +73,47 @@ def _layout(model):
 
 
 @pytest.mark.parametrize("model_name", ["md5", "sha256", "sha1", "sha512"])
-def test_kernel_compiles_at_serving_geometry(one_chip, model_name):
-    """The pallas backend's serving launch for a width-4 segment
-    (backends/pallas_backend.py plan_launch_geometry, the model's
-    cost-scaled launch budget)."""
+def test_kernel_compiles_at_serving_geometry(topo, model_name):
+    """The ``pallas`` backend's served width-4 program on one chip: the
+    chained kernel step over a one-device mesh at the model's
+    MODEL_GEOMETRY, one dispatch of the model's cost-scaled budget cut
+    into segments as parallel/mesh_search.py
+    ``pallas_mesh_persistent_factory`` cuts it."""
     import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
 
-    from distpow_tpu.backends.pallas_backend import plan_launch_geometry
     from distpow_tpu.models.registry import get_hash_model
     from distpow_tpu.ops.md5_pallas import LANES, MODEL_GEOMETRY, \
-        _dyn_pallas_step
+        SEGMENT_GRID_STEPS
     from distpow_tpu.ops.search_step import mask_words_for, step_operands
+    from distpow_tpu.parallel.mesh_search import AXIS, make_mesh, \
+        pallas_mesh_persistent_step
     from distpow_tpu.parallel.search import launch_steps_for, \
         scaled_launch_candidates
 
     model = get_hash_model(model_name)
+    mesh = make_mesh(topo.devices[:1])
     sublanes, inner = MODEL_GEOMETRY[model_name]
     tile = sublanes * LANES
     budget = scaled_launch_candidates(model.cost_ops)
-    k = launch_steps_for(WIDTH, BATCH // TBC, TBC, budget)
-    batch, _, k = plan_launch_geometry(BATCH // TBC, TBC, tile, inner, k,
-                                       budget)
-    tiles = batch * k // tile
+    dispatch = BATCH * launch_steps_for(WIDTH, BATCH // TBC, TBC, budget)
+    seg_local = -(-min(SEGMENT_GRID_STEPS * tile * inner, dispatch)
+                  // tile) * tile
+    tiles = seg_local // tile
     while tiles % inner:
         inner //= 2
     spec, tb_w, tb_s, chunk_ws = _layout(model)
     mw = mask_words_for(DIFFICULTY, model)
-    step = _dyn_pallas_step(tb_w, tb_s, chunk_ws, tiles // inner, sublanes,
-                            False, inner, mw, model_name)
+    step = pallas_mesh_persistent_step(
+        mesh, AXIS, model_name, tb_w, tb_s, chunk_ws, tiles // inner,
+        sublanes, inner, False, mw, True, 0, seg_local,
+        -(-dispatch // seg_local), len(spec.base_words[0]))
     init, base, masks = step_operands(spec, DIFFICULTY, model)
-    args = _shapes([jnp.uint32(0), init, base[0], masks,
-                    jnp.zeros((2,), jnp.uint32)], one_chip)
-    compiled = step.lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    u2 = jnp.zeros((2,), jnp.uint32)
+    args = _shapes([init, base[0], masks, u2, jnp.uint32(0), u2],
+                   NamedSharding(mesh, PartitionSpec()))
+    text = step.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and "while" in text
 
 
 def test_persistent_xla_step_compiles_at_batch_2_20(one_chip):
@@ -132,43 +140,6 @@ def test_persistent_xla_step_compiles_at_batch_2_20(one_chip):
     args = _shapes([init, base, masks, u32, u32, u32, prev], one_chip)
     compiled = step.lower(*args).compile()
     assert compiled.memory_analysis() is not None
-
-
-def test_pallas_mesh_step_compiles_on_2x2(topo):
-    """The pallas-mesh backend's width-4 program over four chips: the
-    kernel on every device and the found-index all-reduce."""
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    from distpow_tpu.models.registry import get_hash_model
-    from distpow_tpu.ops.md5_pallas import LANES, MODEL_GEOMETRY
-    from distpow_tpu.ops.search_step import mask_words_for, step_operands
-    from distpow_tpu.parallel.mesh_search import AXIS, \
-        _dyn_pallas_mesh_step, make_mesh
-    from distpow_tpu.parallel.search import DEFAULT_LAUNCH_CANDIDATES
-
-    model = get_hash_model("md5")
-    mesh = make_mesh(topo.devices)
-    n_dev = len(topo.devices)
-    sublanes, inner = MODEL_GEOMETRY["md5"]
-    tbl = TBC // n_dev
-    batch_local = (BATCH // TBC) * tbl
-    k = DEFAULT_LAUNCH_CANDIDATES // (batch_local * n_dev)
-    tiles = batch_local * k // (sublanes * LANES)
-    while tiles % inner:
-        inner //= 2
-    spec, tb_w, tb_s, chunk_ws = _layout(model)
-    mw = mask_words_for(DIFFICULTY, model)
-    step = _dyn_pallas_mesh_step(
-        mesh, AXIS, "md5", tb_w, tb_s, chunk_ws, tiles // inner, sublanes,
-        inner, False, mw, True, n_dev.bit_length() - 1, batch_local, k)
-    init, base, masks = step_operands(spec, DIFFICULTY, model)
-    args = _shapes([init, base[0], masks, jnp.zeros((2,), jnp.uint32),
-                    jnp.uint32(0)],
-                   NamedSharding(mesh, PartitionSpec()))
-    text = step.lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
-    assert "all-reduce" in text
 
 
 def test_pallas_mesh_persistent_step_compiles_on_2x2(topo):

@@ -103,30 +103,26 @@ def test_inner_grace_does_not_leak_into_outer_block(dog):
 
 
 def test_search_driver_hang_detected():
-    """A device fetch that never returns must trip the watchdog through
-    parallel.search's own instrumentation (the beat in drain_one)."""
-    from distpow_tpu.ops.search_step import SENTINEL
-    from distpow_tpu.parallel.search import search
+    """A dispatch whose result never becomes ready must trip the
+    watchdog through parallel.search's own instrumentation: the drain's
+    poll wait deliberately does not beat."""
+    from distpow_tpu.parallel.search import persistent_search
 
     unblock = threading.Event()
 
-    def factory(vw, extra, target_chunks, launch_steps=1):
-        def step(chunk0):
-            class HungResult:
-                def __int__(self):  # a device_get that never completes
-                    unblock.wait()
-                    return SENTINEL  # a miss, so the released thread
-                    # drains cleanly instead of fabricating a hit
+    class HungResult:
+        def is_ready(self):  # a dispatch that never completes
+            return False
 
-            return HungResult()
-
-        return step, max(1, target_chunks)
+    def builder(vw, extra, target_chunks, segments):
+        return (lambda chunk0, prev: HungResult()), target_chunks, \
+            target_chunks * segments
 
     WATCHDOG.start(0.3, on_hang=lambda s: None)
     try:
         t = threading.Thread(
-            target=lambda: search(
-                b"\x01", 2, list(range(256)), step_factory=factory,
+            target=lambda: persistent_search(
+                b"\x01", 2, list(range(256)), step_builder=builder,
                 pipeline_depth=1, batch_size=1 << 10,
                 cancel_check=unblock.is_set,
             ),
@@ -136,48 +132,48 @@ def test_search_driver_hang_detected():
         assert WATCHDOG.fired.wait(3.0), \
             "watchdog did not detect the hung drain"
     finally:
-        unblock.set()  # release the blocked thread before stopping
+        unblock.set()  # the poll wait honors the cancel and returns
         t.join(timeout=5.0)
         WATCHDOG.stop()
 
 
 def test_slow_first_launch_compile_not_killed(monkeypatch):
-    """A cold layout's FIRST launch pays the XLA compile — one gap that
-    can far exceed the hang timeout (sha512 unrolled: >22 min
-    observed).  The driver wraps that launch in a grace window, so an
+    """A cold layout's FIRST dispatch pays the XLA compile — one gap
+    that can far exceed the hang timeout (sha512 unrolled: >22 min
+    observed).  The driver wraps that dispatch in a grace window, so an
     armed watchdog must ride out a slow first compile and still serve
     the result."""
     import importlib
 
+    import jax.numpy as jnp
+
+    from distpow_tpu.ops.search_step import SENTINEL
+
     search_mod = importlib.import_module("distpow_tpu.parallel.search")
-    search = search_mod.search
 
     # shrink the grace so the test can also prove it expires (below)
     monkeypatch.setattr(search_mod, "FIRST_COMPILE_GRACE_S", 5.0)
 
     calls = {"n": 0}
 
-    def factory(vw, extra, target_chunks, launch_steps=1):
-        from distpow_tpu.ops.search_step import SENTINEL
-
-        def step(chunk0):
+    def builder(vw, extra, target_chunks, segments):
+        def step(chunk0, prev):
             calls["n"] += 1
             if calls["n"] == 1:
                 time.sleep(0.8)  # "compile": 4x the base timeout
+            # nonce 570400 at 3 nibbles: no width-0 solution, and
+            # (tb 0, chunk 16) solves
+            return jnp.asarray([0 if chunk0 == 16 else SENTINEL, 1],
+                               jnp.uint32)
 
-            class Result:
-                def __int__(self):
-                    return 0 if chunk0 == 0 else SENTINEL
-
-            return Result()
-
-        return step, max(1, target_chunks)
+        return step, 1, 1
 
     WATCHDOG.start(0.2, on_hang=lambda s: None)
     try:
-        res = search(b"\x01", 0, list(range(256)), step_factory=factory,
-                     pipeline_depth=1, batch_size=1 << 10)
-        assert res is not None  # difficulty 0: first candidate wins
+        res = search_mod.persistent_search(
+            bytes.fromhex("570400"), 3, list(range(256)),
+            step_builder=builder, pipeline_depth=1, batch_size=1 << 10)
+        assert res is not None and res.secret == bytes([0, 16])
         assert not WATCHDOG.fired.is_set(), \
             "watchdog killed a healthy slow first compile"
     finally:
