@@ -302,7 +302,7 @@ def timed_span(backend) -> float:
             batch_size=backend.batch_size,
             launch_candidates=backend.max_launch,
             max_hashes=SPAN_CANDIDATES,
-            step_builder=backend._step_builder(SPAN_NONCE, SPAN_NTZ, 0, 256))
+            step_builder=backend._step_builder)
 
     for timed in (False, True):
         h0 = REGISTRY.get("search.hashes")

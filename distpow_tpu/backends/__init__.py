@@ -90,26 +90,27 @@ def _warm_layouts(make_builder, model, nonce_lens, widths, batch_size,
     """Warm the programs ``persistent_search`` dispatches for every
     (partition, nonce length, mask-word bucket, width).
 
-    ``make_builder(nonce, difficulty, tb_lo, tbc)`` is the backend's
-    ``step_builder`` factory.  Everything else is derived the way the
-    driver derives it (parallel/search.py): ``target_chunks`` from
-    ``effective_batch`` with the same ``tbc``, each width's segment
-    count from ``launch_steps_for`` with the same budget, the width-0
-    probe from ``cached_search_step``, and the single-device persistent
-    step wherever the builder (or its absence) declines a width — which
-    is what makes the warmed compile keys byte-identical to the ones
-    serving dispatches.  Each persistent warm dispatch runs behind
-    ``CHAIN_HALT``: its on-device loop exits at its first check, so
-    warming compiles the real program at near-zero device cost.
+    ``make_builder(operands)`` is the backend's ``step_builder``.
+    Everything else is derived the way the driver derives it
+    (parallel/search.py): the request's operands bound before the probe
+    (``RequestOperands``, so the pack has the served shape and
+    placement), ``target_chunks`` from ``effective_batch`` with the same
+    ``tbc``, each width's segment count from ``launch_steps_for`` with
+    the same budget, and the single-device persistent step wherever the
+    builder (or its absence) declines a width — which is what makes the
+    warmed compile keys byte-identical to the ones serving dispatches.
+    Each persistent warm dispatch runs behind ``CHAIN_HALT``: its
+    on-device loop exits at its first check, so warming compiles the
+    real program at near-zero device cost.
     """
     import numpy as np
 
-    from ..ops.search_step import (
-        CHAIN_HALT,
-        cached_persistent_step,
-        cached_search_step,
+    from ..ops.search_step import CHAIN_HALT
+    from ..parallel.search import (
+        RequestOperands,
+        effective_batch,
+        launch_steps_for,
     )
-    from ..parallel.search import effective_batch, launch_steps_for
     from ..runtime.watchdog import FIRST_COMPILE_GRACE_S, WATCHDOG
 
     # one beat per compiled program, and a grace window around each
@@ -124,22 +125,20 @@ def _warm_layouts(make_builder, model, nonce_lens, widths, batch_size,
             for L in nonce_lens:
                 nonce = bytes(int(L))
                 for difficulty in WARMUP_DIFFICULTIES:
-                    builder = make_builder(nonce, difficulty, 0, tbc)
+                    operands = RequestOperands(nonce, difficulty, 0, tbc,
+                                               model)
+                    builder = make_builder(operands)
                     for vw in map(int, widths):
                         WATCHDOG.beat()
                         k = launch_steps_for(vw, target, tbc, max_launch)
                         with WATCHDOG.grace(FIRST_COMPILE_GRACE_S):
                             if vw == 0:
-                                int(cached_search_step(
-                                    nonce, 0, difficulty, 0, tbc, 1,
-                                    model.name, b"", 1)(1))
+                                int(operands.probe()(1))
                                 continue
                             plan = (builder(vw, b"", target, k)
                                     if builder is not None else None)
                             bound = plan[0] if plan is not None else \
-                                cached_persistent_step(
-                                    nonce, vw, difficulty, 0, tbc, target,
-                                    model.name, b"", k)
+                                operands.persistent_step(vw, b"", target, k)
                             np.asarray(bound(1, CHAIN_HALT))
 
 
@@ -163,14 +162,12 @@ class JaxBackend:
         self.batch_size = batch_size
         self.max_launch = _resolve_max_launch(max_launch, self.model)
 
-    def _step_builder(self, nonce: bytes, difficulty: int, tb_lo: int,
-                      tbc: int):
+    def _step_builder(self, operands):
         """The ``step_builder`` ``search`` hands the driver; None
         selects the single-device persistent step for every width."""
         from ..sched.lanes import persistent_step_builder
 
-        return persistent_step_builder(nonce, difficulty, tb_lo, tbc,
-                                       self.model)
+        return persistent_step_builder(operands)
 
     def warmup(self, nonce_lens: Sequence[int], widths: Sequence[int]) -> None:
         """Pre-compile the layout-keyed programs these nonce lengths hit.
@@ -181,15 +178,12 @@ class JaxBackend:
         of that length at any difficulty (one program per mask-word
         bucket, WARMUP_DIFFICULTIES) and any power-of-two partition.
         """
-        _warm_layouts(lambda *_: None, self.model, nonce_lens, widths,
+        _warm_layouts(lambda _: None, self.model, nonce_lens, widths,
                       self.batch_size, self.max_launch)
 
     def search(self, nonce, difficulty, thread_bytes, cancel_check=None):
-        from ..parallel.partition import contiguous_bounds
         from ..parallel.search import persistent_search
 
-        nonce = bytes(nonce)
-        tb_lo, tbc = contiguous_bounds(thread_bytes)
         res = persistent_search(
             nonce,
             difficulty,
@@ -198,7 +192,7 @@ class JaxBackend:
             batch_size=self.batch_size,
             cancel_check=cancel_check,
             launch_candidates=self.max_launch,
-            step_builder=self._step_builder(nonce, difficulty, tb_lo, tbc),
+            step_builder=self._step_builder,
         )
         return None if res is None else res.secret
 
@@ -234,13 +228,11 @@ class JaxMeshBackend(JaxBackend):
             self._mesh = make_mesh(devs)
         return self._mesh
 
-    def _step_builder(self, nonce: bytes, difficulty: int, tb_lo: int,
-                      tbc: int):
+    def _step_builder(self, operands):
         from ..parallel.mesh_search import mesh_persistent_factory
         from ..runtime.metrics import REGISTRY as metrics
 
-        mesh_builder = mesh_persistent_factory(
-            nonce, difficulty, tb_lo, tbc, self.model, self._get_mesh())
+        mesh_builder = mesh_persistent_factory(operands, self._get_mesh())
 
         def builder(vw, extra, target_chunks, segments):
             try:
@@ -296,16 +288,14 @@ class PallasMeshBackend(JaxMeshBackend):
         super().__init__(*args, **kwargs)
         self.interpret = interpret
 
-    def _step_builder(self, nonce: bytes, difficulty: int, tb_lo: int,
-                      tbc: int):
+    def _step_builder(self, operands):
         from ..parallel.mesh_search import pallas_mesh_persistent_factory
         from ..runtime.metrics import REGISTRY as metrics
 
-        xla_builder = super()._step_builder(nonce, difficulty, tb_lo, tbc)
+        xla_builder = super()._step_builder(operands)
         try:
             pallas_builder = pallas_mesh_persistent_factory(
-                nonce, difficulty, tb_lo, tbc, self.model, self._get_mesh(),
-                interpret=self.interpret,
+                operands, self._get_mesh(), interpret=self.interpret,
             )
         except ValueError as exc:
             metrics.inc("fallback.pallas_mesh_step")

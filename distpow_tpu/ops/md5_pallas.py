@@ -906,7 +906,7 @@ def build_pallas_search_step(
     init = jnp.asarray(spec.init_state, jnp.uint32)
     base = jnp.asarray(spec.base_words[0], jnp.uint32)
     # only the significant trailing mask words enter the kernel (their
-    # count is the compile key, same discipline as step_operands)
+    # count is the compile key, same discipline as search_step.pack_operands)
     masks_arr = jnp.asarray(masks[model.digest_words - mw:], jnp.uint32)
     part = jnp.asarray([tb_lo, tb_count.bit_length() - 1], jnp.uint32)
 

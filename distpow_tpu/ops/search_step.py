@@ -16,21 +16,23 @@ Two compilation regimes:
   program — maximum constant folding, one compile per (nonce, difficulty,
   partition, batch).  Used where one configuration is re-dispatched many
   times (bench, graft entry).
-* ``cached_search_step`` (the serving path) binds a *layout-keyed* dynamic
-  program: the nonce's packed words, the absorbed prefix state, and the
-  difficulty masks are runtime operands, and the thread-byte partition is
-  two runtime scalars (``tb_lo``, ``log2 tb_count``).  The compile key is
-  only (model, tail layout, batch), where the tail layout depends on the
-  nonce length *mod block size* and the chunk width — so a worker that has
-  compiled (nonce_len=4, width=2) once serves EVERY 4-byte-nonce request at
-  ANY difficulty and ANY partition with zero recompiles.  The constant
-  words cost nothing extra at runtime: they are loop-invariant scalars XLA
+* the serving steps bind a *layout-keyed* dynamic program to one runtime
+  operand, the request's pack (``pack_operands``): the thread-byte
+  partition, the absorbed prefix state, the difficulty masks and each
+  width's constant tail words, in one ``uint32`` buffer that each program
+  slices at offsets static in it.  The compile key is only (model, tail
+  layout, batch), where the tail layout depends on the nonce length *mod
+  block size* and the chunk width — so a worker that has compiled
+  (nonce_len=4, width=2) once serves EVERY 4-byte-nonce request at ANY
+  difficulty and ANY partition with zero recompiles.  The constant words
+  cost nothing extra at runtime: they are loop-invariant scalars XLA
   hoists out of the batch dimension.
 """
 
 from __future__ import annotations
 
 import functools
+from dataclasses import dataclass
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -215,7 +217,7 @@ def fold_dyn_masks(model, state, masks, mask_words: Optional[int] = None):
     """Hit mask against runtime-operand difficulty masks.
 
     ``masks`` holds the ``mask_words`` significant masks for the LAST
-    digest words (``step_operands`` slices them); None = all words.
+    digest words (``pack_operands`` packs only those); None = all words.
     """
     d = model.digest_words
     k = d if mask_words is None else mask_words
@@ -225,6 +227,70 @@ def fold_dyn_masks(model, state, masks, mask_words: Optional[int] = None):
     return acc == 0
 
 
+# The request pack (docs/SERVING.md "Operand binding").  Words:
+#   [0] tb_lo, [1] log2(tbc) (0 where tbc is not a power of two), [2] tbc,
+#   then the absorbed prefix state, then the ``mask_words`` significant
+#   difficulty masks, then each row's constant tail words (n_blocks rows
+#   of words_per_block + param_words).
+PACK_HEADER = 3
+# The rows of a request's dense pack: widths 0-4, no constant high bytes.
+DENSE_ROWS = tuple((vw, b"") for vw in range(5))
+
+
+@dataclass(frozen=True, eq=False)
+class OperandPack:
+    """One request's operands in one host ``uint32`` buffer, ``words``.
+
+    Row ``i`` holds the constant tail words of ``specs[i]`` from word
+    ``base_offs[i]``.  The buffer's length and every offset follow from
+    the layout alone (the nonce length, through each row's tail layout,
+    the model and the mask-word bucket), never from the nonce's bytes,
+    so the programs that slice it stay layout-keyed."""
+
+    words: np.ndarray
+    model_name: str
+    tbc: int
+    mask_words: int
+    specs: Tuple[TailSpec, ...]
+    base_offs: Tuple[int, ...]
+
+
+def pack_operands(nonce: bytes, difficulty: int, tb_lo: int, tbc: int,
+                  model: HashModel, rows=DENSE_ROWS) -> OperandPack:
+    """Pack ``(nonce, difficulty, tb_lo, tbc)`` for the chunk widths
+    ``rows``, a tuple of ``(vw, extra_const_chunk)``.  Numpy only: no
+    device is touched until the caller places ``words``."""
+    specs = tuple(build_tail_spec(nonce, vw, model, extra)
+                  for vw, extra in rows)
+    mw = mask_words_for(difficulty, model)
+    masks = nibble_masks(difficulty, model)[model.digest_words - mw:]
+    pow2 = tbc & (tbc - 1) == 0
+    words = [tb_lo, tbc.bit_length() - 1 if pow2 else 0, tbc,
+             *specs[0].init_state, *masks]
+    offs = []
+    for spec in specs:
+        offs.append(len(words))
+        for row in spec.base_words:
+            words.extend(row)
+    # distpow: ok hot-path-host-sync -- packs Python ints on the host;
+    # no device value is read
+    return OperandPack(np.array(words, np.uint32), model.name, tbc, mw,
+                       specs, tuple(offs))
+
+
+def unpack_operands(pack, model: HashModel, mask_words: int, base_off: int,
+                    n_blocks: int):
+    """``(tb_lo, log_tbc, tbc, init, base[n_blocks, W], masks)``: one
+    row's operands, sliced from a placed pack at offsets static in the
+    calling program."""
+    s = len(model.init_state)
+    w = model.words_per_block + model.param_words
+    init = pack[PACK_HEADER:PACK_HEADER + s]
+    masks = pack[PACK_HEADER + s:PACK_HEADER + s + mask_words]
+    base = pack[base_off:base_off + n_blocks * w].reshape(n_blocks, w)
+    return pack[0], pack[1], pack[2], init, base, masks
+
+
 @functools.lru_cache(maxsize=None)
 def _dyn_search_step(
     model_name: str,
@@ -232,15 +298,13 @@ def _dyn_search_step(
     tb_loc,
     chunk_locs,
     batch: int,
-    static_tbc,  # None => power-of-two partition passed as log2 operand
-    launch_steps: int = 1,
-    mask_words: int = 0,  # 0 => all digest words significant
+    static_tbc,  # None => power-of-two partition, log2 read from the pack
+    launch_steps: int,
+    mask_words: int,
+    base_off: int,
 ):
-    """Layout-keyed jitted step with nonce/difficulty/partition as operands.
-
-    Signature of the returned jitted fn (all uint32):
-    ``(init_state[S], base_words[n_blocks,W], masks[D], tb_lo,
-    log_tbc_or_nothing, chunk0) -> uint32``.
+    """Layout-keyed jitted step with nonce/difficulty/partition in the
+    pack: ``(pack, chunk0) -> uint32``, the row at ``base_off``.
 
     ``launch_steps`` sub-batches of ``batch`` candidates run inside one
     dispatch via ``fori_loop`` (see ``build_search_step``); the returned
@@ -248,108 +312,91 @@ def _dyn_search_step(
     """
     model = get_hash_model(model_name)
     _check_launch(batch, launch_steps)
-    mw = mask_words or model.digest_words
 
-    if static_tbc is None:
+    def step(pack, chunk0):
+        tb_lo, log_tbc, _, init, base, masks = unpack_operands(
+            pack, model, mask_words, base_off, n_blocks)
 
-        def sub(tb_lo, log_tbc, init, base, masks, chunk0, f):
-            chunk = jnp.uint32(chunk0) + (f >> log_tbc)
-            tb = tb_lo + (f & ((jnp.uint32(1) << log_tbc) - jnp.uint32(1)))
+        def sub(f):
+            if static_tbc is None:
+                chunk = jnp.uint32(chunk0) + (f >> log_tbc)
+                tb = tb_lo + (f & ((jnp.uint32(1) << log_tbc)
+                                   - jnp.uint32(1)))
+            else:
+                chunk = jnp.uint32(chunk0) + f // jnp.uint32(static_tbc)
+                tb = tb_lo + f % jnp.uint32(static_tbc)
             state = eval_dyn_candidates(
                 model, n_blocks, tb_loc, chunk_locs, init, base, tb, chunk
             )
-            hit = fold_dyn_masks(model, state, masks, mw)
+            hit = fold_dyn_masks(model, state, masks, mask_words)
             return jnp.min(jnp.where(hit, f, jnp.uint32(SENTINEL)))
 
-        def step(init, base, masks, tb_lo, log_tbc, chunk0):
-            f0 = jnp.arange(batch, dtype=jnp.uint32)
-            if launch_steps == 1:
-                return sub(tb_lo, log_tbc, init, base, masks, chunk0, f0)
+        f0 = jnp.arange(batch, dtype=jnp.uint32)
+        if launch_steps == 1:
+            return sub(f0)
 
-            def body(i, best):
-                f = i.astype(jnp.uint32) * jnp.uint32(batch) + f0
-                return jnp.minimum(
-                    best, sub(tb_lo, log_tbc, init, base, masks, chunk0, f)
-                )
+        def body(i, best):
+            return jnp.minimum(
+                best, sub(i.astype(jnp.uint32) * jnp.uint32(batch) + f0))
 
-            return jax.lax.fori_loop(
-                0, launch_steps, body, jnp.uint32(SENTINEL)
-            )
-
-    else:
-
-        def sub(tb_lo, init, base, masks, chunk0, f):
-            chunk = jnp.uint32(chunk0) + f // jnp.uint32(static_tbc)
-            tb = tb_lo + f % jnp.uint32(static_tbc)
-            state = eval_dyn_candidates(
-                model, n_blocks, tb_loc, chunk_locs, init, base, tb, chunk
-            )
-            hit = fold_dyn_masks(model, state, masks, mw)
-            return jnp.min(jnp.where(hit, f, jnp.uint32(SENTINEL)))
-
-        def step(init, base, masks, tb_lo, chunk0):
-            f0 = jnp.arange(batch, dtype=jnp.uint32)
-            if launch_steps == 1:
-                return sub(tb_lo, init, base, masks, chunk0, f0)
-
-            def body(i, best):
-                f = i.astype(jnp.uint32) * jnp.uint32(batch) + f0
-                return jnp.minimum(best, sub(tb_lo, init, base, masks, chunk0, f))
-
-            return jax.lax.fori_loop(
-                0, launch_steps, body, jnp.uint32(SENTINEL)
-            )
+        return jax.lax.fori_loop(0, launch_steps, body, jnp.uint32(SENTINEL))
 
     return named_jit(step, f"{model_name}_launch_step")
 
 
 @functools.lru_cache(maxsize=None)
 def _dyn_search_step_w0(model_name: str, n_blocks: int, tb_loc, chunk_locs,
-                        mask_words: int = 0):
+                        mask_words: int, base_off: int):
     """Width-0 probe program: scan ALL 256 thread bytes, mask the ones
     outside the runtime partition.
 
     Width 0 has exactly ``tb_count`` candidates, so keying the batch on
     the partition would recompile per worker split — defeating warmup.
     Instead one fixed-shape (256) program serves every partition: the
-    partition is the runtime pair (tb_lo, tbc) and out-of-partition hits
-    are masked off.  Returns the partition-local flat index (tb - tb_lo)
-    of the first hit, or SENTINEL — identical contract to the general
-    step at width 0.
+    partition is the pack's (tb_lo, tbc) and out-of-partition hits are
+    masked off.  Returns the partition-local flat index (tb - tb_lo) of
+    the first hit, or SENTINEL — identical contract to the general step
+    at width 0.
     """
     model = get_hash_model(model_name)
-    mw = mask_words or model.digest_words
 
-    def step(init, base, masks, tb_lo, tbc, chunk0):
+    def step(pack, chunk0):
         del chunk0  # width 0: no chunk bytes
+        tb_lo, _, tbc, init, base, masks = unpack_operands(
+            pack, model, mask_words, base_off, n_blocks)
         tb = jnp.arange(256, dtype=jnp.uint32)
         state = eval_dyn_candidates(
             model, n_blocks, tb_loc, chunk_locs, init, base, tb,
             jnp.uint32(0),
         )
-        hit = fold_dyn_masks(model, state, masks, mw)
+        hit = fold_dyn_masks(model, state, masks, mask_words)
         hit = hit & (tb >= tb_lo) & (tb < tb_lo + tbc)
         return jnp.min(jnp.where(hit, tb - tb_lo, jnp.uint32(SENTINEL)))
 
     return named_jit(step, f"{model_name}_probe_step")
 
 
-def step_operands(spec: TailSpec, difficulty: int, model: HashModel):
-    """Device operands binding one (nonce, difficulty) onto a dyn step.
-
-    The masks operand carries only the ``mask_words_for(difficulty)``
-    significant trailing words — its LENGTH is part of the jit compile
-    key, matching the ``mask_words`` the program was built with."""
-    masks = nibble_masks(difficulty, model)
-    mw = mask_words_for(difficulty, model)
-    return (
-        jnp.asarray(spec.init_state, jnp.uint32),
-        jnp.asarray(spec.base_words, jnp.uint32),
-        jnp.asarray(masks[model.digest_words - mw:], jnp.uint32),
-    )
+def _layout_key(pack: OperandPack, row: int):
+    spec = pack.specs[row]
+    return spec.n_blocks, spec.tb_loc, spec.chunk_locs
 
 
-@functools.lru_cache(maxsize=512)
+def bind_probe(pack: OperandPack, placed):
+    """The width-0 probe over ``placed``, a placement of ``pack`` whose
+    row 0 is width 0: ``bound(chunk0) -> uint32``."""
+    w0 = _dyn_search_step_w0(pack.model_name, *_layout_key(pack, 0),
+                             pack.mask_words, pack.base_offs[0])
+
+    def bound0(chunk0):
+        return w0(placed, chunk0)
+
+    return bound0
+
+
+def _static_tbc(tbc: int):
+    return None if tbc & (tbc - 1) == 0 else tbc
+
+
 def cached_search_step(
     nonce: bytes,
     width: int,
@@ -361,43 +408,24 @@ def cached_search_step(
     extra_const_chunk: bytes = b"",
     launch_steps: int = 1,
 ):
-    """Serving-path step: binds request operands onto a layout-keyed
-    dynamic program (see module docstring).  Same contract as
-    ``build_search_step``: one dispatch covers ``launch_steps *
-    chunks_per_step * tb_count`` candidates."""
+    """A layout-keyed program (cached by layout, see module docstring)
+    bound to one pack of this request's operands, placed on the default
+    device.  Same contract as ``build_search_step``: one dispatch covers
+    ``launch_steps * chunks_per_step * tb_count`` candidates."""
     model = get_hash_model(model_name)
-    spec = build_tail_spec(bytes(nonce), width, model, extra_const_chunk)
-    init, base, masks = step_operands(spec, difficulty, model)
-    mw = mask_words_for(difficulty, model)
-    tb_lo_op = jnp.uint32(tb_lo)
-
+    pack = pack_operands(bytes(nonce), difficulty, tb_lo, tb_count, model,
+                         ((width, extra_const_chunk),))
+    placed = jax.device_put(pack.words)
     if width == 0:
-        w0 = _dyn_search_step_w0(
-            model_name, spec.n_blocks, spec.tb_loc, spec.chunk_locs, mw
-        )
-        tbc_op = jnp.uint32(tb_count)
-
-        def bound0(chunk0):
-            return w0(init, base, masks, tb_lo_op, tbc_op, chunk0)
-
-        return bound0
-
-    batch = chunks_per_step * tb_count
-    pow2 = tb_count & (tb_count - 1) == 0
+        return bind_probe(pack, placed)
     dyn = _dyn_search_step(
-        model_name, spec.n_blocks, spec.tb_loc, spec.chunk_locs, batch,
-        None if pow2 else tb_count, launch_steps, mw,
+        model_name, *_layout_key(pack, 0), chunks_per_step * tb_count,
+        _static_tbc(tb_count), launch_steps, pack.mask_words,
+        pack.base_offs[0],
     )
-    if pow2:
-        log_tbc = jnp.uint32(tb_count.bit_length() - 1)
 
-        def bound(chunk0):
-            return dyn(init, base, masks, tb_lo_op, log_tbc, chunk0)
-
-    else:
-
-        def bound(chunk0):
-            return dyn(init, base, masks, tb_lo_op, chunk0)
+    def bound(chunk0):
+        return dyn(placed, chunk0)
 
     return bound
 
@@ -416,9 +444,10 @@ def persistent_search_step(
     tb_loc,
     chunk_locs,
     batch: int,
-    static_tbc,  # None => power-of-two partition passed as log2 operand
+    static_tbc,  # None => power-of-two partition, log2 read from the pack
     segments: int,
-    mask_words: int = 0,
+    mask_words: int,
+    base_off: int,
 ):
     """Persistent-loop serving step: a multi-segment on-device search
     (docs/SERVING.md).
@@ -442,52 +471,42 @@ def persistent_search_step(
     dispatch queued behind it on the device costs one loop check,
     without the host having to learn of the hit first.
 
-    Signature of the returned jitted fn (all uint32):
-    ``(init[S], base[n_blocks, W], masks[D], tb_lo, log_tbc_or_nothing,
-    chunk0, prev[2]) -> uint32[2]`` — ``[0]`` is the first-hit flat
-    index over the full ``segments * batch`` span (reference enumeration
-    order; segments scan in order and each segment folds its own
-    minimum), SENTINEL, or HALT; ``[1]`` is the number of segments
-    actually executed (the driver's evaluated-work accounting, and the
+    Signature of the returned jitted fn (all uint32): ``(pack, chunk0,
+    prev[2]) -> uint32[2]``, reading the row at ``base_off`` of the
+    request's pack — ``[0]`` is the first-hit flat index over the full
+    ``segments * batch`` span (reference enumeration order; segments
+    scan in order and each segment folds its own minimum), SENTINEL, or
+    HALT; ``[1]`` is the number of segments actually executed (the
+    driver's evaluated-work accounting, and the
     ``search.persistent_steps`` instrument).
     """
     model = get_hash_model(model_name)
     _check_launch(batch, segments)
-    mw = mask_words or model.digest_words
 
-    def sub(init, base, masks, tb_lo, log_tbc, chunk0, f):
-        if static_tbc is None:
-            chunk = jnp.uint32(chunk0) + (f >> log_tbc)
-            tb = tb_lo + (f & ((jnp.uint32(1) << log_tbc) - jnp.uint32(1)))
-        else:
-            chunk = jnp.uint32(chunk0) + f // jnp.uint32(static_tbc)
-            tb = tb_lo + f % jnp.uint32(static_tbc)
-        state = eval_dyn_candidates(
-            model, n_blocks, tb_loc, chunk_locs, init, base, tb, chunk
-        )
-        hit = fold_dyn_masks(model, state, masks, mw)
-        return jnp.min(jnp.where(hit, f, jnp.uint32(SENTINEL)))
+    def step(pack, chunk0, prev):
+        # sliced outside the loop: loop-invariant operands of its body
+        tb_lo, log_tbc, _, init, base, masks = unpack_operands(
+            pack, model, mask_words, base_off, n_blocks)
+        f0 = jnp.arange(batch, dtype=jnp.uint32)
 
-    def make_step(take_log_tbc: bool):
-        def step(init, base, masks, tb_lo, log_tbc, chunk0, prev):
-            f0 = jnp.arange(batch, dtype=jnp.uint32)
+        def search_segment(seg):
+            f = seg * jnp.uint32(batch) + f0
+            if static_tbc is None:
+                chunk = jnp.uint32(chunk0) + (f >> log_tbc)
+                tb = tb_lo + (f & ((jnp.uint32(1) << log_tbc)
+                                   - jnp.uint32(1)))
+            else:
+                chunk = jnp.uint32(chunk0) + f // jnp.uint32(static_tbc)
+                tb = tb_lo + f % jnp.uint32(static_tbc)
+            state = eval_dyn_candidates(
+                model, n_blocks, tb_loc, chunk_locs, init, base, tb, chunk
+            )
+            hit = fold_dyn_masks(model, state, masks, mask_words)
+            return jnp.min(jnp.where(hit, f, jnp.uint32(SENTINEL)))
 
-            def search_segment(seg):
-                f = seg * jnp.uint32(batch) + f0
-                return sub(init, base, masks, tb_lo, log_tbc, chunk0, f)
+        return chained_segments(prev, segments, search_segment)
 
-            return chained_segments(prev, segments, search_segment)
-
-        if take_log_tbc:
-            return step
-
-        def step_static(init, base, masks, tb_lo, chunk0, prev):
-            return step(init, base, masks, tb_lo, jnp.uint32(0), chunk0, prev)
-
-        return step_static
-
-    return named_jit(make_step(static_tbc is None),
-                     f"{model_name}_persistent_step")
+    return named_jit(step, f"{model_name}_persistent_step")
 
 
 def chained_segments(prev, segments: int, search_segment):
@@ -515,54 +534,25 @@ def chained_segments(prev, segments: int, search_segment):
     return jnp.stack([jnp.where(go, best, jnp.uint32(HALT)), seg])
 
 
-@functools.lru_cache(maxsize=512)
-def cached_persistent_step(
-    nonce: bytes,
-    width: int,
-    difficulty: int,
-    tb_lo: int,
-    tb_count: int,
-    chunks_per_step: int,
-    model_name: str,
-    extra_const_chunk: bytes = b"",
-    segments: int = 1,
-):
-    """Serving-path persistent step: binds request operands onto the
-    layout-keyed multi-segment program, exactly as ``cached_search_step``
-    binds the width-0 probe's program.  Returns ``bound(chunk0, prev) ->
-    uint32[2]`` covering up to ``segments * chunks_per_step * tb_count``
-    candidates per dispatch (early exit on a hit; no work behind a
-    ``prev`` that hit or halted, or behind ``CHAIN_HALT``).  Width 0 has
-    a single 256-candidate probe and no chunk axis — the driver serves
-    it through ``cached_search_step`` instead.
-    """
-    if width == 0:
-        raise ValueError(
-            "width 0 has no persistent form; use cached_search_step"
-        )
-    model = get_hash_model(model_name)
-    spec = build_tail_spec(bytes(nonce), width, model, extra_const_chunk)
-    init, base, masks = step_operands(spec, difficulty, model)
-    mw = mask_words_for(difficulty, model)
-    tb_lo_op = jnp.uint32(tb_lo)
-    batch = chunks_per_step * tb_count
-    pow2 = tb_count & (tb_count - 1) == 0
+def bind_persistent_step(pack: OperandPack, placed, row: int,
+                         chunks_per_step: int, segments: int):
+    """The single-device persistent step for ``pack``'s row ``row``
+    (a width above 0) over ``placed``, a single-device placement of the
+    pack: ``bound(chunk0, prev) -> uint32[2]`` covering up to ``segments
+    * chunks_per_step * tbc`` candidates a dispatch (early exit on a
+    hit; no work behind a ``prev`` that hit or halted, or behind
+    ``CHAIN_HALT``).  Width 0 has a single 256-candidate probe and no
+    chunk axis: ``bind_probe`` serves it."""
+    if pack.specs[row].width == 0:
+        raise ValueError("width 0 has no persistent form; use bind_probe")
     dyn = persistent_search_step(
-        model_name, spec.n_blocks, spec.tb_loc, spec.chunk_locs, batch,
-        None if pow2 else tb_count, segments, mw,
+        pack.model_name, *_layout_key(pack, row), chunks_per_step * pack.tbc,
+        _static_tbc(pack.tbc), segments, pack.mask_words,
+        pack.base_offs[row],
     )
-    if pow2:
-        log_tbc = jnp.uint32(tb_count.bit_length() - 1)
 
-        def bound(chunk0, prev):
-            return dyn(init, base, masks, tb_lo_op, log_tbc, chunk0,
-                       chain_operand(prev))
-
-    else:
-
-        def bound(chunk0, prev):
-            return dyn(init, base, masks, tb_lo_op, chunk0,
-                       chain_operand(prev))
+    def bound(chunk0, prev):
+        return dyn(placed, chunk0, chain_operand(prev))
 
     return bound
 

@@ -31,7 +31,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.registry import HashModel, get_hash_model
-from ..ops.packing import build_tail_spec
 from ..ops.search_step import (
     SENTINEL,
     _check_launch,
@@ -39,9 +38,8 @@ from ..ops.search_step import (
     chained_segments,
     eval_dyn_candidates,
     fold_dyn_masks,
-    mask_words_for,
     named_jit,
-    step_operands,
+    unpack_operands,
 )
 from .compat import shard_map as _shard_map
 
@@ -87,6 +85,7 @@ def pallas_mesh_persistent_step(
     seg_local: int,
     segments: int,
     base_width: int,
+    base_off: int,
 ):
     """The Pallas kernel on every device of the mesh, in the chained
     multi-segment loop of the persistent steps (``chained_segments``).
@@ -105,11 +104,11 @@ def pallas_mesh_persistent_step(
     an earlier one.)  The first hit of the first segment that has one is
     therefore the dispatch's reference-order first.
 
-    Returned fn: ``(init[S], base[base_width], masks[mask_words],
-    part[2]=(tb_lo, log_tbc), chunk0, prev[2]) -> uint32[2]``, the
-    signature of ``mesh_persistent_step`` with the partition as one
-    operand: the first-hit global flat index, SENTINEL or HALT, then the
-    segments run.  Behind a ``prev`` that hit or halted it runs none.
+    Returned fn: ``(pack, chunk0, prev[2]) -> uint32[2]``, the
+    signature of ``mesh_persistent_step``, reading the one-block row at
+    ``base_off`` of the request's pack: the first-hit global flat index,
+    SENTINEL or HALT, then the segments run.  Behind a ``prev`` that hit
+    or halted it runs none.
     """
     from ..ops.md5_pallas import _TILE_FNS, _dyn_pallas_step
 
@@ -129,13 +128,17 @@ def pallas_mesh_persistent_step(
         return jax.core.eval_jaxpr(kernel_jaxpr.jaxpr, kernel_jaxpr.consts,
                                    *operands)[0]
 
+    model = get_hash_model(model_name)
     one = jnp.uint32(1)
     seg_global = seg_local << log_ndev
     _check_launch(seg_global, segments)
 
-    def body(init, base, masks, part, chunk0, prev):
+    def body(pack, chunk0, prev):
         d = jax.lax.axis_index(axis).astype(jnp.uint32)
-        tb_lo, log_tbc = part[0], part[1]
+        tb_lo, log_tbc, _, init, base, masks = unpack_operands(
+            pack, model, mask_words, base_off, 1)
+        base = base[0]
+        part = pack[:2]
         # global chunks one segment spans
         seg_chunks = jnp.uint32(seg_global) >> log_tbc
         if tb_split:
@@ -164,7 +167,7 @@ def pallas_mesh_persistent_step(
     # annotation; the per-segment pmin is what makes the loop carry
     # replicated
     sharded = _shard_map(
-        body, mesh=mesh, in_specs=(P(),) * 6, out_specs=P(), check_vma=False,
+        body, mesh=mesh, in_specs=(P(),) * 3, out_specs=P(), check_vma=False,
     )
     return named_jit(sharded, f"{model_name}_pallas_mesh_persistent_step")
 
@@ -266,9 +269,10 @@ def mesh_persistent_step(
     tb_loc,
     chunk_locs,
     batch_local: int,
-    static_tbc,  # None => power-of-two partition passed as log2 operand
+    static_tbc,  # None => power-of-two partition, log2 read from the pack
     segments: int,
-    mask_words: int = 0,
+    mask_words: int,
+    base_off: int,
 ):
     """Persistent-loop serving step spread over the device mesh — the
     solo/persistent route's ``mesh`` lane (docs/SERVING.md).
@@ -284,74 +288,56 @@ def mesh_persistent_step(
     same segment boundary and exits together, the module-docstring
     "first result wins, everyone stops" protocol applied inside one
     dispatch.  Returned fn signature matches the single-device step:
-    ``(init, base, masks, tb_lo, [log_tbc,] chunk0, prev[2]) ->
-    uint32[2]`` (first-hit global flat index, SENTINEL or HALT, then
-    segments executed).
+    ``(pack, chunk0, prev[2]) -> uint32[2]`` (first-hit global flat
+    index, SENTINEL or HALT, then segments executed).
     """
     model = get_hash_model(model_name)
     n_dev = int(mesh.devices.size)
     batch_global = batch_local * n_dev
     _check_launch(batch_global, segments)
     one = jnp.uint32(1)
-    mw = mask_words or model.digest_words
 
-    def make_step(take_log_tbc: bool):
-        def step(init, base, masks, tb_lo, log_tbc, chunk0, prev):
-            d = jax.lax.axis_index(axis).astype(jnp.uint32)
-            f0 = d * jnp.uint32(batch_local) + jnp.arange(
-                batch_local, dtype=jnp.uint32
+    def step(pack, chunk0, prev):
+        tb_lo, log_tbc, _, init, base, masks = unpack_operands(
+            pack, model, mask_words, base_off, n_blocks)
+        d = jax.lax.axis_index(axis).astype(jnp.uint32)
+        f0 = d * jnp.uint32(batch_local) + jnp.arange(
+            batch_local, dtype=jnp.uint32
+        )
+
+        def search_segment(seg):
+            f = seg * jnp.uint32(batch_global) + f0
+            if static_tbc is None:
+                chunk = jnp.uint32(chunk0) + (f >> log_tbc)
+                tb = tb_lo + (f & ((one << log_tbc) - one))
+            else:
+                chunk = jnp.uint32(chunk0) + f // jnp.uint32(static_tbc)
+                tb = tb_lo + f % jnp.uint32(static_tbc)
+            state_w = eval_dyn_candidates(
+                model, n_blocks, tb_loc, chunk_locs, init, base, tb,
+                chunk,
             )
+            hit = fold_dyn_masks(model, state_w, masks, mask_words)
+            found = jnp.min(jnp.where(hit, f, jnp.uint32(SENTINEL)))
+            return jax.lax.pmin(found, axis)
 
-            def search_segment(seg):
-                f = seg * jnp.uint32(batch_global) + f0
-                if static_tbc is None:
-                    chunk = jnp.uint32(chunk0) + (f >> log_tbc)
-                    tb = tb_lo + (f & ((one << log_tbc) - one))
-                else:
-                    chunk = jnp.uint32(chunk0) + f // jnp.uint32(static_tbc)
-                    tb = tb_lo + f % jnp.uint32(static_tbc)
-                state_w = eval_dyn_candidates(
-                    model, n_blocks, tb_loc, chunk_locs, init, base, tb,
-                    chunk,
-                )
-                hit = fold_dyn_masks(model, state_w, masks, mw)
-                found = jnp.min(jnp.where(hit, f, jnp.uint32(SENTINEL)))
-                return jax.lax.pmin(found, axis)
+        return chained_segments(prev, segments, search_segment)
 
-            return chained_segments(prev, segments, search_segment)
-
-        if take_log_tbc:
-            return step
-
-        def step_static(init, base, masks, tb_lo, chunk0, prev):
-            return step(init, base, masks, tb_lo, jnp.uint32(0), chunk0,
-                        prev)
-
-        return step_static
-
-    n_in = 7 if static_tbc is None else 6
     # check_vma=False: the per-segment pmin inside the while_loop body is
     # what makes the carry replicated; the VMA/replication typing of a
     # collective inside a loop carry differs across JAX versions
     sharded = _shard_map(
-        make_step(static_tbc is None), mesh=mesh, in_specs=(P(),) * n_in,
-        out_specs=P(), check_vma=False,
+        step, mesh=mesh, in_specs=(P(),) * 3, out_specs=P(),
+        check_vma=False,
     )
     return named_jit(sharded, f"{model_name}_mesh_persistent_step")
 
 
-def mesh_persistent_factory(
-    nonce: bytes,
-    difficulty: int,
-    tb_lo: int,
-    tbc: int,
-    model: HashModel,
-    mesh: Mesh,
-    axis: str = AXIS,
-):
+def mesh_persistent_factory(operands, mesh: Mesh, axis: str = AXIS):
     """Persistent step builder over the mesh — the ``step_builder`` hook
     of ``parallel.search.persistent_search`` (the solo/persistent
-    route's mesh lane).
+    route's mesh lane), for one request's ``operands``
+    (``parallel.search.RequestOperands``).
 
     Returns ``builder(vw, extra, target_chunks, segments) ->
     (bound(chunk0, prev), chunks_each, chunks_per_step)`` with the
@@ -362,24 +348,26 @@ def mesh_persistent_factory(
     the single-device persistent step, same per-width contract as the
     pallas mesh factory.
 
-    Bound operands are pre-placed replicated on the mesh at bind time
-    (``jax.device_put`` with a replicated ``NamedSharding``), and so are
-    the constant chain operands (``chain_operand``), placed like the
-    step's own replicated results: steady-state dispatches move only
-    the chunk cursor, and the first dispatch of a solve compiles
-    nothing its successors did not.
+    The request's pack is placed replicated on the mesh here, once, in
+    one ``jax.device_put`` from the host; each width's step reads its
+    own row of it, and the constant chain operands (``chain_operand``)
+    sit where the step's own replicated results do: steady-state
+    dispatches move only the chunk cursor, and the first dispatch of a
+    solve compiles nothing its successors did not.
     """
     from .compat import NamedSharding
 
+    model = operands.model
+    tbc = operands.tbc
     n_dev = int(mesh.devices.size)
     repl = NamedSharding(mesh, P())
     pow2 = tbc & (tbc - 1) == 0
+    operands.placed(repl)
 
-    @functools.lru_cache(maxsize=32)
     def builder(vw: int, extra: bytes, target_chunks: int, segments: int):
         if vw == 0:
             raise ValueError(
-                "width 0 has no persistent form; use cached_search_step"
+                "width 0 has no persistent form; use the probe"
             )
         batch_global = target_chunks * tbc
         if batch_global % n_dev:
@@ -387,31 +375,17 @@ def mesh_persistent_factory(
                 f"segment batch {batch_global} (chunks={target_chunks}, "
                 f"tbc={tbc}) does not divide across {n_dev} devices"
             )
-        spec = build_tail_spec(bytes(nonce), vw, model, extra)
-        mw = mask_words_for(difficulty, model)
+        pack, placed, row = operands.rows(vw, extra, repl)
+        spec = pack.specs[row]
         dyn = mesh_persistent_step(
             mesh, axis, model.name, spec.n_blocks, spec.tb_loc,
             spec.chunk_locs, batch_global // n_dev,
-            None if pow2 else tbc, segments, mw,
+            None if pow2 else tbc, segments, pack.mask_words,
+            pack.base_offs[row],
         )
-        init, base, masks = step_operands(spec, difficulty, model)
-        init, base, masks = (jax.device_put(init, repl),
-                             jax.device_put(base, repl),
-                             jax.device_put(masks, repl))
-        tb_lo_op = jax.device_put(jnp.uint32(tb_lo), repl)
-        if pow2:
-            log_tbc = jax.device_put(
-                jnp.uint32(tbc.bit_length() - 1), repl)
 
-            def bound(chunk0, prev):
-                return dyn(init, base, masks, tb_lo_op, log_tbc, chunk0,
-                           chain_operand(prev, repl))
-
-        else:
-
-            def bound(chunk0, prev):
-                return dyn(init, base, masks, tb_lo_op, chunk0,
-                           chain_operand(prev, repl))
+        def bound(chunk0, prev):
+            return dyn(placed, chunk0, chain_operand(prev, repl))
 
         return _on_mesh(bound, mesh), target_chunks, target_chunks * segments
 
@@ -419,11 +393,7 @@ def mesh_persistent_factory(
 
 
 def pallas_mesh_persistent_factory(
-    nonce: bytes,
-    difficulty: int,
-    tb_lo: int,
-    tbc: int,
-    model: HashModel,
+    operands,
     mesh: Mesh,
     axis: str = AXIS,
     interpret: bool = False,
@@ -431,7 +401,8 @@ def pallas_mesh_persistent_factory(
 ):
     """Persistent step builder over the mesh backed by the Pallas kernel
     (``pallas_mesh_persistent_step``): the ``step_builder`` the
-    ``pallas-mesh`` backend hands ``parallel.search.persistent_search``.
+    ``pallas-mesh`` backend hands ``parallel.search.persistent_search``,
+    for one request's ``operands``.
 
     Returns ``builder(vw, extra, target_chunks, segments) ->
     (bound(chunk0, prev), chunks_each, chunks_per_step)``, the contract
@@ -448,14 +419,16 @@ def pallas_mesh_persistent_factory(
     Raises ValueError where the kernel cannot express the request
     (``_pallas_mesh_geometry``) or, from the builder, a width (a
     multi-block tail); the backend serves those through
-    ``mesh_persistent_factory``.  Operands and the chain's constants are
-    placed replicated on the mesh at bind time, like the step's own
-    results, so a dispatch behind ``CHAIN_HALT`` compiles the program
-    the solve's dispatches run.
+    ``mesh_persistent_factory``.  The request's pack is placed
+    replicated on the mesh here, as there, and the chain's constants
+    sit like the step's own results, so a dispatch behind
+    ``CHAIN_HALT`` compiles the program the solve's dispatches run.
     """
     from ..ops.md5_pallas import LANES, SEGMENT_GRID_STEPS
     from .compat import NamedSharding
 
+    model = operands.model
+    tbc = operands.tbc
     n_dev = int(mesh.devices.size)
     sublanes, inner = _pallas_mesh_geometry(model, n_dev, tbc, interpret)
     tile = sublanes * LANES
@@ -464,10 +437,10 @@ def pallas_mesh_persistent_factory(
     tb_split = tbc >= n_dev
     log_ndev = n_dev.bit_length() - 1
     repl = NamedSharding(mesh, P())
+    operands.placed(repl)
 
-    @functools.lru_cache(maxsize=32)
     def builder(vw: int, extra: bytes, target_chunks: int, segments: int):
-        spec = build_tail_spec(bytes(nonce), vw, model, extra)
+        spec = operands.spec(vw, extra)
         if spec.n_blocks != 1:
             raise ValueError("pallas kernel requires a single-block tail")
         per_dev = (target_chunks * tbc * segments) >> log_ndev
@@ -477,23 +450,18 @@ def pallas_mesh_persistent_factory(
         inner_eff = inner
         while tiles % inner_eff:
             inner_eff //= 2
+        pack, placed, row = operands.rows(vw, extra, repl)
         _, tb_w, tb_s = spec.tb_loc
         dyn = pallas_mesh_persistent_step(
             mesh, axis, model.name, tb_w, tb_s,
             tuple((w, s) for _, w, s in spec.chunk_locs), tiles // inner_eff,
-            sublanes, inner_eff, interpret,
-            mask_words_for(difficulty, model), tb_split, log_ndev, seg_local,
-            n_seg, len(spec.base_words[0]),
+            sublanes, inner_eff, interpret, pack.mask_words, tb_split,
+            log_ndev, seg_local, n_seg, len(spec.base_words[0]),
+            pack.base_offs[row],
         )
-        init, base, masks = step_operands(spec, difficulty, model)
-        init, base, masks, part = (
-            jax.device_put(a, repl) for a in (
-                init, base[0], masks,
-                jnp.asarray([tb_lo, tbc.bit_length() - 1], jnp.uint32)))
 
         def bound(chunk0, prev):
-            return dyn(init, base, masks, part, chunk0,
-                       chain_operand(prev, repl))
+            return dyn(placed, chunk0, chain_operand(prev, repl))
 
         chunks_each = (seg_local << log_ndev) // tbc
         return _on_mesh(bound, mesh), chunks_each, chunks_each * n_seg

@@ -43,16 +43,21 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Callable, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from ..models import puzzle
 from ..models.registry import HashModel, get_hash_model
+from ..ops.packing import build_tail_spec
 from ..ops.search_step import (
     CHAIN_CLEAR,
     CHAIN_HALT,
+    DENSE_ROWS,
     SENTINEL,
-    cached_persistent_step,
-    cached_search_step,
+    OperandPack,
+    bind_persistent_step,
+    bind_probe,
+    pack_operands,
 )
 from ..runtime.metrics import REGISTRY as metrics
 from ..runtime.spans import SPANS
@@ -261,6 +266,89 @@ def _unsatisfiable_wait(model: HashModel, difficulty: int, cancel_check,
         time.sleep(0.01)
 
 
+class RequestOperands:
+    """One request's operands, bound once (docs/SERVING.md "Operand
+    binding").
+
+    Widths 0-4 read one host pack (``ops/search_step.py
+    pack_operands``), built at the request's first placement and placed
+    at most once per device form: ``placed(sharding)`` under a mesh
+    step's replicated sharding, ``placed()`` on one device.  The
+    one-device programs (the width-0 probe, the default persistent
+    step) read a placement already made through its first shard, so a
+    request that the mesh steps serve places its pack once.  A width
+    past 4 fixes its high chunk bytes per 2^32-candidate segment, so
+    each such segment packs and places a one-row pack of its own, behind
+    the previous segment's device work.  Each placement runs in a
+    ``search.bind`` span and counts once under
+    ``search.operand_binds``.
+    """
+
+    def __init__(self, nonce: bytes, difficulty: int, tb_lo: int, tbc: int,
+                 model: HashModel):
+        self.nonce = bytes(nonce)
+        self.difficulty = difficulty
+        self.tb_lo = tb_lo
+        self.tbc = tbc
+        self.model = model
+        self._dense: Optional[OperandPack] = None
+        self._placed: dict = {}
+
+    def _pack(self, rows) -> OperandPack:
+        return pack_operands(self.nonce, self.difficulty, self.tb_lo,
+                             self.tbc, self.model, rows)
+
+    def _dense_pack(self) -> OperandPack:
+        if self._dense is None:
+            self._dense = self._pack(DENSE_ROWS)
+        return self._dense
+
+    def _place(self, make_pack, sharding):
+        with SPANS.span("search.bind"):
+            pack = make_pack()
+            placed = jax.device_put(pack.words, sharding)
+        metrics.inc("search.operand_binds")
+        return pack, placed
+
+    def placed(self, sharding=None):
+        """The dense pack under ``sharding``; None: on one device."""
+        arr = self._placed.get(sharding)
+        if arr is None:
+            if sharding is None and self._placed:
+                arr = next(iter(self._placed.values())).addressable_data(0)
+            else:
+                arr = self._place(self._dense_pack, sharding)[1]
+            self._placed[sharding] = arr
+        return arr
+
+    def spec(self, vw: int, extra: bytes = b""):
+        """Width ``vw``'s tail layout, with no placement."""
+        if extra:
+            return build_tail_spec(self.nonce, vw, self.model, extra)
+        return self._dense_pack().specs[vw]
+
+    def rows(self, vw: int, extra: bytes = b"", sharding=None):
+        """``(pack, placed, row)``: the placed pack that holds width
+        ``vw`` with constant high chunk bytes ``extra``, and its row."""
+        if extra:
+            pack, placed = self._place(lambda: self._pack(((vw, extra),)),
+                                       sharding)
+            return pack, placed, 0
+        placed = self.placed(sharding)
+        return self._dense, placed, vw
+
+    def probe(self):
+        """The width-0 probe, ``bound(chunk0) -> uint32``."""
+        return bind_probe(*self.rows(0)[:2])
+
+    def persistent_step(self, vw: int, extra: bytes, chunks_per_step: int,
+                        segments: int):
+        """The default one-device persistent step of width ``vw``
+        (``ops/search_step.py bind_persistent_step``)."""
+        return bind_persistent_step(*self.rows(vw, extra), chunks_per_step,
+                                    segments)
+
+
 # Host-side poll cadence while a launch result is not yet ready.  Short
 # enough that drain latency adds negligibly to a launch's wall-clock
 # (launches are 0.1-0.25 s of device work by budget), long enough that
@@ -334,7 +422,8 @@ def persistent_search(
     (docs/SERVING.md):
 
     * each dispatch is a multi-segment on-device loop
-      (``cached_persistent_step``) that early-exits on the first hit,
+      (``ops/search_step.py persistent_search_step``) that early-exits
+      on the first hit,
       so the per-dispatch candidate budget no longer trades hit
       latency against round-trip amortization;
     * the dispatches of a solve form one chain (:class:`StopFlag`):
@@ -356,22 +445,27 @@ def persistent_search(
       running out their segment budget in the background) without
       shrinking launches.
 
-    The width-0 probe (256 candidates, ``cached_search_step``) stays
-    outside the chain: its result is a scalar.  With the default depth
-    of 2 it is drained before a second persistent dispatch is issued,
-    so at most one width-1 dispatch runs behind a probe hit.
+    The width-0 probe (256 candidates, ``bind_probe``) stays outside
+    the chain: its result is a scalar.  With the default depth of 2 it
+    is drained before a second persistent dispatch is issued, so at
+    most one width-1 dispatch runs behind a probe hit.
+
+    The request's operands are bound once, before the probe
+    (:class:`RequestOperands`): one host pack for widths 0-4, placed
+    once per device form, which each width's step slices.
 
     ``step_builder`` is the device-form hook (the backends, and
-    sched/lanes.py ``persistent_step_builder``): called per width
-    segment as
-    ``step_builder(vw, extra, target_chunks, k)`` it may return
-    ``(step, chunks_each, chunks_per_step)`` — a drop-in for the
-    default single-device persistent step with the identical
-    ``(chunk0, prev) -> uint32[2]`` contract and first-hit order over
-    the same global candidate span — or None to keep the default for
-    that width.  The mesh steps serve every dispatch across the local
-    devices this way, and the Pallas mesh step runs the kernel;
-    enumeration order (and so results) stays byte-identical.
+    sched/lanes.py ``persistent_step_builder``): called once, with the
+    request's :class:`RequestOperands`, it returns None (the default
+    single-device persistent step for every width) or a builder, called
+    per width segment as ``builder(vw, extra, target_chunks, k)``,
+    which may return ``(step, chunks_each, chunks_per_step)`` — a
+    drop-in for the default single-device persistent step with the
+    identical ``(chunk0, prev) -> uint32[2]`` contract and first-hit
+    order over the same global candidate span — or None to keep the
+    default for that width.  The mesh steps serve every dispatch across
+    the local devices this way, and the Pallas mesh step runs the
+    kernel; enumeration order (and so results) stays byte-identical.
     """
     model = model or get_hash_model("md5")
     if launch_candidates is None:
@@ -383,6 +477,8 @@ def persistent_search(
                                    max_hashes)
     target_chunks = max(1, effective_batch(batch_size) // tbc)
     chain = StopFlag()
+    operands = RequestOperands(nonce, difficulty, tb_lo, tbc, model)
+    builder = None
 
     hashes = 0
     # FIFO of in-flight dispatches over the whole solve:
@@ -511,22 +607,23 @@ def persistent_search(
                         k = launch_steps_for(vw, target_chunks, tbc,
                                              launch_candidates)
                         if vw == 0:
-                            step0 = cached_search_step(
-                                nonce, 0, difficulty, tb_lo, tbc, 1,
-                                model.name, extra, 1,
-                            )
+                            # the request's operands are bound here, once:
+                            # the step builder places them where its
+                            # steps read them, and the probe reads that
+                            # placement too
+                            builder = (step_builder(operands)
+                                       if step_builder is not None else None)
+                            step0 = operands.probe()
                             step, chunks_per_step, chunks_each = \
                                 None, 1, 1
                         else:
-                            plan = (step_builder(vw, extra, target_chunks, k)
-                                    if step_builder is not None else None)
+                            plan = (builder(vw, extra, target_chunks, k)
+                                    if builder is not None else None)
                             if plan is not None:
                                 step, chunks_each, chunks_per_step = plan
                             else:
-                                step = cached_persistent_step(
-                                    nonce, vw, difficulty, tb_lo, tbc,
-                                    target_chunks, model.name, extra, k,
-                                )
+                                step = operands.persistent_step(
+                                    vw, extra, target_chunks, k)
                                 chunks_each = target_chunks
                                 chunks_per_step = target_chunks * k
                         # the mesh steps carry the mark (mesh_search.py

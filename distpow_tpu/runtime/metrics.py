@@ -30,6 +30,11 @@ declaration distpow-lint's ``metrics-registry`` rule verifies every
   behind a found answer, which the chained halt stopped on the device
   before any segment ran (about one per found solve at the default
   pipeline depth; docs/SERVING.md)
+* ``search.operand_binds`` — placements of a request's operand pack
+  (parallel/search.py ``RequestOperands``): one a request, on one
+  device or a mesh (the one-device programs read a mesh placement's
+  first shard), and one more per 2^32-candidate segment of a width
+  past 4; each inside a ``search.bind`` span
 * ``worker.mine_rpcs`` / ``worker.found_rpcs`` / ``worker.cancel_rpcs``
 * ``worker.results_sent``  — messages queued to the forwarder
 * ``worker.forward_retries`` — result deliveries retried after a
@@ -237,6 +242,7 @@ KNOWN_COUNTERS = frozenset({
     "search.hashes", "search.launches", "search.cancelled", "search.found",
     "search.persistent_steps",
     "search.halted_dispatches", "search.mesh_hashes",
+    "search.operand_binds",
     "worker.mine_rpcs", "worker.found_rpcs", "worker.cancel_rpcs",
     "worker.results_sent", "worker.forward_retries",
     "coord.mine_rpcs", "coord.fanouts", "coord.late_results",

@@ -61,7 +61,7 @@ Span-name vocabulary (kept small and documented — docs/FORENSICS.md):
 ``coord.first_result``, ``coord.cancel_storm``, ``coord.reassign``,
 ``fleet.hedge``, ``fleet.lease_expiry``, ``worker.solve``,
 ``worker.result_forward``, ``sched.slot``, ``search.poll``,
-``search.segment``.
+``search.segment``, ``search.bind``.
 """
 
 from __future__ import annotations

@@ -325,15 +325,12 @@ class BatchingScheduler:
         from ..parallel.search import persistent_search
         from .lanes import persistent_step_builder
 
-        tb_lo, tbc = contiguous_bounds(thread_bytes)
         res = persistent_search(
             nonce, difficulty, thread_bytes,
             model=model, batch_size=self.batch,
             cancel_check=cancel_check,
-            step_builder=persistent_step_builder(
-                nonce, difficulty, tb_lo, tbc, model,
-                override=self.lane,
-            ),
+            step_builder=lambda operands: persistent_step_builder(
+                operands, override=self.lane),
         )
         return None if res is None else res.secret
 

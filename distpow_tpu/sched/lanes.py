@@ -421,11 +421,10 @@ class LanePlanner:
         return _MeshGroupStep(dyn, mesh, local * n_dev)
 
 
-def persistent_step_builder(nonce: bytes, difficulty: int, tb_lo: int,
-                            tbc: int, model,
-                            caps: Optional[LaneCaps] = None,
+def persistent_step_builder(operands, caps: Optional[LaneCaps] = None,
                             override: str = "auto"):
-    """Lane plan for one solo/persistent request — the
+    """Lane plan for one solo/persistent request's ``operands``
+    (``parallel.search.RequestOperands``) — the
     ``parallel.search.persistent_search`` ``step_builder`` hook.
 
     Returns None when the single-device persistent step IS the plan
@@ -452,9 +451,8 @@ def persistent_step_builder(nonce: bytes, difficulty: int, tb_lo: int,
         mesh_persistent_factory
 
     mesh = make_mesh(jax.devices()[: caps.n_devices])
-    factory = mesh_persistent_factory(
-        bytes(nonce), difficulty, tb_lo, tbc, model, mesh, AXIS
-    )
+    factory = mesh_persistent_factory(operands, mesh, AXIS)
+    model = operands.model
     demoted = []
 
     def builder(vw: int, extra: bytes, target_chunks: int, segments: int):

@@ -32,7 +32,6 @@ N_DEVICES = int(os.environ.get("MESH_SMOKE_DEVICES", "4"))
 compat.request_cpu_devices(N_DEVICES)
 
 from distpow_tpu.models import puzzle  # noqa: E402
-from distpow_tpu.models.registry import get_hash_model  # noqa: E402
 from distpow_tpu.parallel.search import persistent_search  # noqa: E402
 from distpow_tpu.runtime.metrics import REGISTRY  # noqa: E402
 from distpow_tpu.sched.engine import BatchingScheduler  # noqa: E402
@@ -78,10 +77,16 @@ def main() -> int:
     # solo route: the persistent step builder binds the mesh
     # persistent step for the same span
     nonce = b"\x23\xa5\x5a"
-    sb = persistent_step_builder(nonce, NTZ, 0, 256, get_hash_model("md5"))
-    assert sb is not None, "persistent builder declined a 4-device host"
+    builders = []
+
+    def sb(operands):
+        builders.append(persistent_step_builder(operands))
+        return builders[-1]
+
     res = persistent_search(nonce, NTZ, THREADS, batch_size=1 << 12,
                             step_builder=sb)
+    assert builders[0] is not None, \
+        "persistent builder declined a 4-device host"
     want = puzzle.python_search(nonce, NTZ, THREADS)
     assert res is not None and res.secret == want, (
         f"mesh persistent route diverged from oracle: "

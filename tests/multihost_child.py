@@ -54,7 +54,7 @@ def mesh_search(nonce, model=None, factory=mesh_persistent_factory,
     model = model or get_hash_model("md5")
     return persistent_search(
         nonce, 2, list(range(256)), model=model, batch_size=1 << 12,
-        step_builder=factory(nonce, 2, 0, 256, model, mesh, **kwargs))
+        step_builder=lambda operands: factory(operands, mesh, **kwargs))
 
 
 res = mesh_search(NONCE)

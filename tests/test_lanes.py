@@ -34,7 +34,6 @@ from distpow_tpu.ops.search_step import (  # noqa: E402
     CHAIN_CLEAR,
     CHAIN_HALT,
     HALT,
-    cached_persistent_step,
     slot_search_step,
 )
 from distpow_tpu.parallel.mesh_search import (  # noqa: E402
@@ -43,7 +42,10 @@ from distpow_tpu.parallel.mesh_search import (  # noqa: E402
     mesh_persistent_factory,
     mesh_slot_search_step,
 )
-from distpow_tpu.parallel.search import persistent_search  # noqa: E402
+from distpow_tpu.parallel.search import (  # noqa: E402
+    RequestOperands,
+    persistent_search,
+)
 from distpow_tpu.runtime.metrics import REGISTRY  # noqa: E402
 from distpow_tpu.sched.engine import BatchingScheduler  # noqa: E402
 from distpow_tpu.sched.lanes import (  # noqa: E402
@@ -188,19 +190,20 @@ def test_mesh_persistent_step_parity_nonpow2_partition():
     mesh = make_mesh(jax.devices()[:4])
     for tbc in (256, 96):
         nonce, ntz, vw, chunks, segs = b"\x51\x52", 1, 2, 32, 4
-        factory = mesh_persistent_factory(nonce, ntz, 0, tbc, model,
-                                          mesh, AXIS)
+        factory = mesh_persistent_factory(
+            RequestOperands(nonce, ntz, 0, tbc, model), mesh, AXIS)
         bound, chunks_each, per_step = factory(vw, b"", chunks, segs)
         assert (chunks_each, per_step) == (chunks, chunks * segs)
-        oracle = cached_persistent_step(nonce, vw, ntz, 0, tbc, chunks,
-                                        "md5", b"", segs)
+        oracle = RequestOperands(nonce, ntz, 0, tbc, model).persistent_step(
+            vw, b"", chunks, segs)
         for c0 in (0, 64, 1 << 12):
             got = np.asarray(bound(c0, CHAIN_CLEAR))
             want = np.asarray(oracle(c0, CHAIN_CLEAR))
             np.testing.assert_array_equal(got, want,
                                           err_msg=f"tbc={tbc} c0={c0}")
     # indivisible global batch refuses cleanly (the demotion signal)
-    f6 = mesh_persistent_factory(b"\x51\x52", 1, 0, 6, model, mesh, AXIS)
+    f6 = mesh_persistent_factory(RequestOperands(b"\x51\x52", 1, 0, 6, model),
+                                 mesh, AXIS)
     with pytest.raises(ValueError, match="divide"):
         f6(2, b"", 1, 2)
 
@@ -213,8 +216,8 @@ def test_mesh_persistent_chained_halt():
 
     model = get_hash_model("md5")
     mesh = make_mesh(jax.devices())
-    factory = mesh_persistent_factory(b"\x51\x52", 2, 0, 256, model, mesh,
-                                      AXIS)
+    factory = mesh_persistent_factory(
+        RequestOperands(b"\x51\x52", 2, 0, 256, model), mesh, AXIS)
     bound, _, _ = factory(1, b"", 8, 4)
     first = bound(1, CHAIN_CLEAR)
     second = bound(33, first)
@@ -240,9 +243,8 @@ def test_persistent_driver_mesh_builder_across_widths(nonce):
     kw = dict(batch_size=1 << 10, launch_candidates=1 << 12)
 
     def solve():
-        sb = persistent_step_builder(nonce, ntz, 0, 256,
-                                     get_hash_model("md5"))
-        return persistent_search(nonce, ntz, tbs, step_builder=sb, **kw)
+        return persistent_search(nonce, ntz, tbs,
+                                 step_builder=persistent_step_builder, **kw)
 
     solve()  # compiles each width's mesh program
     compiles = []
@@ -266,11 +268,15 @@ def test_persistent_driver_mesh_builder_matches_oracle():
     """End to end: persistent_search driving the mesh lane finds the
     oracle's secret (same enumeration order => same first hit)."""
     nonce, ntz = b"\x61\x62\x63", 2
-    sb = persistent_step_builder(nonce, ntz, 0, 256,
-                                 get_hash_model("md5"))
-    assert sb is not None  # 8-device conftest mesh
+    builders = []
+
+    def sb(operands):
+        builders.append(persistent_step_builder(operands))
+        return builders[-1]
+
     res = persistent_search(nonce, ntz, list(range(256)),
                             batch_size=1 << 12, step_builder=sb)
+    assert builders[0] is not None  # 8-device conftest mesh
     assert res is not None
     assert res.secret == puzzle.python_search(nonce, ntz,
                                               list(range(256)))

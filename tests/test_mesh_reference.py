@@ -29,7 +29,7 @@ from distpow_tpu.parallel.mesh_search import (
     make_mesh,
     pallas_mesh_persistent_factory,
 )
-from distpow_tpu.parallel.search import persistent_search
+from distpow_tpu.parallel.search import RequestOperands, persistent_search
 from distpow_tpu.runtime.metrics import REGISTRY
 from distpow_tpu.runtime.spans import SPANS
 from distpow_tpu.sched.lanes import LaneCaps, persistent_step_builder
@@ -76,12 +76,16 @@ def _jax_mesh(nonce, tbs):
 
 
 def _mesh_persistent(nonce, tbs):
-    builder = persistent_step_builder(
-        nonce, NTZ, tbs[0], len(tbs), get_hash_model("md5"),
-        caps=LaneCaps(platform="cpu", n_devices=N_DEV))
-    assert builder is not None
+    builders = []
+
+    def builder(operands):
+        builders.append(persistent_step_builder(
+            operands, caps=LaneCaps(platform="cpu", n_devices=N_DEV)))
+        return builders[-1]
+
     res = persistent_search(nonce, NTZ, tbs, batch_size=BATCH,
                             step_builder=builder)
+    assert builders[0] is not None
     return None if res is None else res.secret
 
 
@@ -155,11 +159,15 @@ def _solutions(nonce, tbs, n=DISPATCH):
     return out
 
 
-def _chained_pallas_builder(nonce, tbs):
+def _chained_pallas_builder(operands):
     return pallas_mesh_persistent_factory(
-        nonce, NTZ, tbs[0], len(tbs), get_hash_model("md5"),
-        make_mesh(jax.devices()[:N_DEV]), interpret=True,
+        operands, make_mesh(jax.devices()[:N_DEV]), interpret=True,
         segment_candidates=SEG_LOCAL)
+
+
+def _operands(nonce, tbs):
+    return RequestOperands(nonce, NTZ, tbs[0], len(tbs),
+                           get_hash_model("md5"))
 
 
 @pytest.mark.parametrize("regime", list(REGIMES))
@@ -172,7 +180,7 @@ def test_pallas_mesh_persistent_step_chained_halt(regime):
     first = _solutions(nonce, tbs)[0]
     assert first >= SEG_GLOBAL
     bound, chunks_each, chunks_per_step = _chained_pallas_builder(
-        nonce, tbs)(2, b"", DISPATCH // 8 // len(tbs), 8)
+        _operands(nonce, tbs))(2, b"", DISPATCH // 8 // len(tbs), 8)
     assert chunks_each * len(tbs) == SEG_GLOBAL
     assert chunks_per_step * len(tbs) == DISPATCH
     assert np.asarray(bound(CHUNK0, CHAIN_HALT)).tolist() == [HALT, 0]
@@ -202,7 +210,7 @@ def test_pallas_mesh_chunk_split_returns_the_reference_first():
     halted0 = REGISTRY.get("search.halted_dispatches")
     got = persistent_search(
         nonce, NTZ, tbs, batch_size=DISPATCH // 8, launch_candidates=DISPATCH,
-        step_builder=_chained_pallas_builder(nonce, tbs))
+        step_builder=_chained_pallas_builder)
     assert got.secret == want
     assert REGISTRY.get("search.halted_dispatches") - halted0 == 1
     # the width-0 probe, width 1's 255 chunks, then the hit's dispatch

@@ -235,7 +235,6 @@ def test_dyn_step_compile_reuse_across_requests():
     """Different nonces, difficulties, and power-of-two partitions of the
     same (length, width, batch) must share one compiled program."""
     _dyn_search_step.cache_clear()
-    cached_search_step.cache_clear()
     cached_search_step(b"\x01\x02\x03\x04", 2, 3, 0, 256, 16, "md5")
     before = _dyn_search_step.cache_info()
     # same length/width/batch, different content/difficulty/partition:
@@ -254,7 +253,6 @@ def test_dyn_step_difficulty_bucket_sharing():
     word); difficulty 9+ selects a second bucket and still matches the
     static program."""
     _dyn_search_step.cache_clear()
-    cached_search_step.cache_clear()
     cached_search_step(b"\x31\x32\x33\x34", 2, 1, 0, 256, 16, "md5")
     before = _dyn_search_step.cache_info()
     for d in (2, 5, 8):
@@ -294,7 +292,6 @@ def test_w0_program_partition_independent():
     from distpow_tpu.ops.search_step import _dyn_search_step_w0
 
     _dyn_search_step_w0.cache_clear()
-    cached_search_step.cache_clear()
     nonce = b"\x0c\x0d"
     full = cached_search_step(nonce, 0, 1, 0, 256, 1, "md5")
     misses = _dyn_search_step_w0.cache_info().misses

@@ -13,6 +13,7 @@ from distpow_tpu.models.registry import MD5, get_hash_model
 from distpow_tpu.ops.md5_pallas import build_pallas_search_step
 from distpow_tpu.ops.search_step import SENTINEL, build_search_step
 from distpow_tpu.parallel.mesh_search import pallas_mesh_persistent_step
+from distpow_tpu.parallel.search import RequestOperands
 
 
 _PALLAS_STEP_SIGNATURE = inspect.signature(pallas_mesh_persistent_step)
@@ -41,7 +42,7 @@ def _kernel_plan(monkeypatch, model_name, interpret, target_chunks,
 
     monkeypatch.setattr(mesh_search, "pallas_mesh_persistent_step", spy)
     builder = mesh_search.pallas_mesh_persistent_factory(
-        b"\x01", 8, 0, 256, get_hash_model(model_name),
+        RequestOperands(b"\x01", 8, 0, 256, get_hash_model(model_name)),
         mesh_search.make_mesh(jax.devices()[:1]), interpret=interpret)
     _, chunks_each, chunks_per_step = builder(1, b"", target_chunks,
                                               segments)
@@ -475,7 +476,8 @@ def test_sha512_interpret_mode_falls_back():
             )
         with pytest.raises(ValueError, match="TPU-only"):
             pallas_mesh_persistent_factory(
-                b"\x01\x02", 3, 0, 256, get_hash_model(mname), mesh,
+                RequestOperands(b"\x01\x02", 3, 0, 256,
+                                get_hash_model(mname)), mesh,
                 interpret=True,
             )
 

@@ -23,21 +23,22 @@ def solve(nonce, difficulty, tbs, route="one-device", model=None, **kw):
     """``persistent_search`` over ``route``.  A mesh width whose segment
     batch does not divide across the devices keeps the single-device
     step, as the mesh backends do."""
-    builder = None
+    step_builder = None
     if route == "mesh":
-        tb_lo, tbc = partition.contiguous_bounds(tbs)
-        mesh_builder = mesh_persistent_factory(
-            bytes(nonce), difficulty, tb_lo, tbc,
-            model or get_hash_model("md5"), make_mesh(jax.devices()))
+        def step_builder(operands):
+            mesh_builder = mesh_persistent_factory(
+                operands, make_mesh(jax.devices()))
 
-        def builder(*width):
-            try:
-                return mesh_builder(*width)
-            except ValueError:
-                return None
+            def builder(*width):
+                try:
+                    return mesh_builder(*width)
+                except ValueError:
+                    return None
+
+            return builder
 
     return persistent_search(nonce, difficulty, tbs, model=model,
-                             step_builder=builder, **kw)
+                             step_builder=step_builder, **kw)
 
 
 def test_public_search_name_survives_submodule_import_order():
@@ -635,7 +636,7 @@ def test_early_exits_account_all_dispatched_work():
     before = REGISTRY.get("search.hashes")
     got = persistent_search(b"\x01", 30, list(range(256)),
                             batch_size=1 << 10, cancel_check=cancel_after(6),
-                            step_builder=make_builder())
+                            step_builder=lambda _: make_builder())
     assert got is None
     assert REGISTRY.get("search.hashes") - before == dispatched[0] > 256
 
@@ -648,7 +649,8 @@ def test_early_exits_account_all_dispatched_work():
     halted = REGISTRY.get("search.halted_dispatches")
     got = persistent_search(bytes.fromhex("570400"), 3, list(range(256)),
                             batch_size=1 << 10, max_width=1,
-                            step_builder=make_builder(hit_on_launch=4))
+                            step_builder=lambda _: make_builder(
+                                hit_on_launch=4))
     assert got is not None and got.secret == bytes([0, 16])
     assert got.hashes_tried == dispatched[0]
     assert REGISTRY.get("search.hashes") - before == dispatched[0] > 256

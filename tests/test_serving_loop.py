@@ -20,7 +20,11 @@ import pytest
 from distpow_tpu.models import puzzle
 from distpow_tpu.models.registry import get_hash_model
 from distpow_tpu.parallel.mesh_search import make_mesh, mesh_persistent_factory
-from distpow_tpu.parallel.search import StopFlag, persistent_search
+from distpow_tpu.parallel.search import (
+    RequestOperands,
+    StopFlag,
+    persistent_search,
+)
 from distpow_tpu.runtime.metrics import REGISTRY
 
 
@@ -38,11 +42,11 @@ def test_chained_halt_contract():
         CHAIN_HALT,
         HALT,
         SENTINEL,
-        cached_persistent_step,
     )
 
-    step = cached_persistent_step(b"\x51\x52", 1, 2, 0, 256, 4, "md5",
-                                  b"", 8)
+    md5 = get_hash_model("md5")
+    step = RequestOperands(b"\x51\x52", 2, 0, 256, md5).persistent_step(
+        1, b"", 4, 8)
     flag = StopFlag()
     assert flag.operand() is CHAIN_CLEAR and not flag.is_set()
     results = []
@@ -59,8 +63,8 @@ def test_chained_halt_contract():
     assert flag.is_set() and flag.operand() is CHAIN_HALT
     # behind a predecessor that searched all its segments and missed,
     # the successor searches too
-    miss = cached_persistent_step(b"\x51\x52", 1, 8, 0, 256, 4, "md5",
-                                  b"", 8)
+    miss = RequestOperands(b"\x51\x52", 8, 0, 256, md5).persistent_step(
+        1, b"", 4, 8)
     first = miss(1, CHAIN_CLEAR)
     assert np.asarray(first).tolist() == [SENTINEL, 8]
     assert np.asarray(miss(33, first)).tolist() == [SENTINEL, 8]
@@ -80,9 +84,9 @@ def test_persistent_parity_at_width_boundary(nonce):
     tbs = list(range(256))
     oracle = puzzle.python_search(nonce, 4, tbs)
     assert len(oracle) == (2 if nonce == BOUNDARY_NONCES[0] else 3)
-    builder = mesh_persistent_factory(nonce, 4, 0, 256, get_hash_model("md5"),
-                                      make_mesh(jax.devices()))
-    mesh = persistent_search(nonce, 4, tbs, step_builder=builder, **BOUNDARY)
+    mesh = persistent_search(
+        nonce, 4, tbs, step_builder=lambda operands: mesh_persistent_factory(
+            operands, make_mesh(jax.devices())), **BOUNDARY)
     persistent = persistent_search(nonce, 4, tbs, **BOUNDARY)
     assert persistent.secret == mesh.secret == oracle
 
@@ -94,20 +98,22 @@ def test_found_solve_counts_drained_work_and_halted_dispatches(depth):
     each halted dispatch counts once under search.halted_dispatches."""
     import numpy as np
 
-    from distpow_tpu.ops.search_step import HALT, cached_persistent_step
+    from distpow_tpu.ops.search_step import HALT
 
     nonce, tbs = BOUNDARY_NONCES[0], list(range(256))
     results = []
 
-    def recording_builder(vw, extra, target_chunks, k):
-        step = cached_persistent_step(nonce, vw, 4, 0, 256, target_chunks,
-                                      "md5", extra, k)
+    def recording_builder(operands):
+        def builder(vw, extra, target_chunks, k):
+            step = operands.persistent_step(vw, extra, target_chunks, k)
 
-        def recorded(chunk0, prev):
-            results.append(step(chunk0, prev))
-            return results[-1]
+            def recorded(chunk0, prev):
+                results.append(step(chunk0, prev))
+                return results[-1]
 
-        return recorded, target_chunks, target_chunks * k
+            return recorded, target_chunks, target_chunks * k
+
+        return builder
 
     h0 = REGISTRY.get("search.hashes")
     halted0 = REGISTRY.get("search.halted_dispatches")

@@ -65,11 +65,15 @@ def _shapes(arrays, sharding):
 
 
 def _layout(model):
-    from distpow_tpu.ops.packing import build_tail_spec
+    """The served request pack (widths 0-4), WIDTH's row in it and its
+    kernel layout."""
+    from distpow_tpu.ops.search_step import pack_operands
 
-    spec = build_tail_spec(NONCE, WIDTH, model, b"")
+    pack = pack_operands(NONCE, DIFFICULTY, 0, TBC, model)
+    spec = pack.specs[WIDTH]
     _, tb_w, tb_s = spec.tb_loc
-    return spec, tb_w, tb_s, tuple((w, s) for _, w, s in spec.chunk_locs)
+    return pack, spec, tb_w, tb_s, \
+        tuple((w, s) for _, w, s in spec.chunk_locs)
 
 
 @pytest.mark.parametrize("model_name", ["md5", "sha256", "sha1", "sha512"])
@@ -85,7 +89,6 @@ def test_kernel_compiles_at_serving_geometry(topo, model_name):
     from distpow_tpu.models.registry import get_hash_model
     from distpow_tpu.ops.md5_pallas import LANES, MODEL_GEOMETRY, \
         SEGMENT_GRID_STEPS
-    from distpow_tpu.ops.search_step import mask_words_for, step_operands
     from distpow_tpu.parallel.mesh_search import AXIS, make_mesh, \
         pallas_mesh_persistent_step
     from distpow_tpu.parallel.search import launch_steps_for, \
@@ -102,15 +105,14 @@ def test_kernel_compiles_at_serving_geometry(topo, model_name):
     tiles = seg_local // tile
     while tiles % inner:
         inner //= 2
-    spec, tb_w, tb_s, chunk_ws = _layout(model)
-    mw = mask_words_for(DIFFICULTY, model)
+    pack, spec, tb_w, tb_s, chunk_ws = _layout(model)
     step = pallas_mesh_persistent_step(
         mesh, AXIS, model_name, tb_w, tb_s, chunk_ws, tiles // inner,
-        sublanes, inner, False, mw, True, 0, seg_local,
-        -(-dispatch // seg_local), len(spec.base_words[0]))
-    init, base, masks = step_operands(spec, DIFFICULTY, model)
+        sublanes, inner, False, pack.mask_words, True, 0, seg_local,
+        -(-dispatch // seg_local), len(spec.base_words[0]),
+        pack.base_offs[WIDTH])
     u2 = jnp.zeros((2,), jnp.uint32)
-    args = _shapes([init, base[0], masks, u2, jnp.uint32(0), u2],
+    args = _shapes([pack.words, jnp.uint32(0), u2],
                    NamedSharding(mesh, PartitionSpec()))
     text = step.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text and "while" in text
@@ -122,22 +124,19 @@ def test_persistent_xla_step_compiles_at_batch_2_20(one_chip):
     import jax.numpy as jnp
 
     from distpow_tpu.models.registry import get_hash_model
-    from distpow_tpu.ops.search_step import mask_words_for, \
-        persistent_search_step, step_operands
+    from distpow_tpu.ops.search_step import persistent_search_step
     from distpow_tpu.parallel.search import DEFAULT_LAUNCH_CANDIDATES, \
         launch_steps_for
 
     model = get_hash_model("md5")
-    spec, *_ = _layout(model)
+    pack, spec, *_ = _layout(model)
     segments = launch_steps_for(WIDTH, BATCH // TBC, TBC,
                                 DEFAULT_LAUNCH_CANDIDATES)
-    mw = mask_words_for(DIFFICULTY, model)
     step = persistent_search_step("md5", spec.n_blocks, spec.tb_loc,
-                                  spec.chunk_locs, BATCH, None, segments, mw)
-    init, base, masks = step_operands(spec, DIFFICULTY, model)
-    u32 = jnp.uint32(0)
+                                  spec.chunk_locs, BATCH, None, segments,
+                                  pack.mask_words, pack.base_offs[WIDTH])
     prev = jnp.zeros((2,), jnp.uint32)  # the chain operand
-    args = _shapes([init, base, masks, u32, u32, u32, prev], one_chip)
+    args = _shapes([pack.words, jnp.uint32(0), prev], one_chip)
     compiled = step.lower(*args).compile()
     assert compiled.memory_analysis() is not None
 
@@ -153,7 +152,6 @@ def test_pallas_mesh_persistent_step_compiles_on_2x2(topo):
     from distpow_tpu.models.registry import get_hash_model
     from distpow_tpu.ops.md5_pallas import LANES, MODEL_GEOMETRY, \
         SEGMENT_GRID_STEPS
-    from distpow_tpu.ops.search_step import mask_words_for, step_operands
     from distpow_tpu.parallel.mesh_search import AXIS, make_mesh, \
         pallas_mesh_persistent_step
     from distpow_tpu.parallel.search import DEFAULT_LAUNCH_CANDIDATES
@@ -165,15 +163,14 @@ def test_pallas_mesh_persistent_step_compiles_on_2x2(topo):
     seg_local = SEGMENT_GRID_STEPS * sublanes * LANES * inner
     assert seg_local == 1 << 24
     segments = DEFAULT_LAUNCH_CANDIDATES // n_dev // seg_local
-    spec, tb_w, tb_s, chunk_ws = _layout(model)
-    mw = mask_words_for(DIFFICULTY, model)
+    pack, spec, tb_w, tb_s, chunk_ws = _layout(model)
     step = pallas_mesh_persistent_step(
         mesh, AXIS, "md5", tb_w, tb_s, chunk_ws, SEGMENT_GRID_STEPS,
-        sublanes, inner, False, mw, True, n_dev.bit_length() - 1, seg_local,
-        segments, len(spec.base_words[0]))
-    init, base, masks = step_operands(spec, DIFFICULTY, model)
+        sublanes, inner, False, pack.mask_words, True,
+        n_dev.bit_length() - 1, seg_local, segments,
+        len(spec.base_words[0]), pack.base_offs[WIDTH])
     u2 = jnp.zeros((2,), jnp.uint32)
-    args = _shapes([init, base[0], masks, u2, jnp.uint32(0), u2],
+    args = _shapes([pack.words, jnp.uint32(0), u2],
                    NamedSharding(mesh, PartitionSpec()))
     text = step.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text

@@ -122,7 +122,7 @@ def test_search_driver_hang_detected():
     try:
         t = threading.Thread(
             target=lambda: persistent_search(
-                b"\x01", 2, list(range(256)), step_builder=builder,
+                b"\x01", 2, list(range(256)), step_builder=lambda _: builder,
                 pipeline_depth=1, batch_size=1 << 10,
                 cancel_check=unblock.is_set,
             ),
@@ -172,7 +172,8 @@ def test_slow_first_launch_compile_not_killed(monkeypatch):
     try:
         res = search_mod.persistent_search(
             bytes.fromhex("570400"), 3, list(range(256)),
-            step_builder=builder, pipeline_depth=1, batch_size=1 << 10)
+            step_builder=lambda _: builder, pipeline_depth=1,
+            batch_size=1 << 10)
         assert res is not None and res.secret == bytes([0, 16])
         assert not WATCHDOG.fired.is_set(), \
             "watchdog killed a healthy slow first compile"
