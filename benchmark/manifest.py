@@ -48,6 +48,17 @@ def load_cell(name: str) -> Cell:
         config = json.load(f)
     mix = load_mix(os.path.join(root, "benchmark", "traffic",
                                 w["traffic"] + ".json"))
+    runs = config.get("worker", {}).get("HashModel", "md5")
+    if runs != config["hash_model"]:
+        raise ValueError(
+            f"{name}: configuration {w['config']!r} is judged as hash model "
+            f"{config['hash_model']!r}, but its worker runs HashModel "
+            f"{runs!r}")
+    if "pool" in mix and mix["pool_hash"] != config["hash_model"]:
+        raise ValueError(
+            f"{name}: configuration {w['config']!r} runs hash model "
+            f"{config['hash_model']!r}, but mix {w['traffic']!r} has its "
+            f"pool solved under pool_hash {mix['pool_hash']!r}")
     e2e = [m for m in manifest["end_to_end"] if _reports(m, name)]
     moved = {m["name"] for m in e2e}
     layer = [m for m in manifest["per_layer"]

@@ -146,11 +146,20 @@ class FirstSolution:
     """Search puzzles in reference order, ``batch`` candidates a call,
     two calls in flight."""
 
+    # the hash's own pieces; sha256_search.FirstSolution replaces them
+    _compile = staticmethod(_first_fn)
+    _width_consts = staticmethod(width_consts)
+    _zero_mask = staticmethod(zero_mask)
+
+    @staticmethod
+    def _nonce_word(nonce: bytes) -> int:
+        return int.from_bytes(nonce, "little")
+
     def __init__(self, batch: int = 1 << 25):
         if batch % 256:
             raise ValueError("batch must be a multiple of 256")
         self.batch = batch
-        self._fn = _first_fn(batch)
+        self._fn = self._compile(batch)
 
     def __call__(self, nonce: bytes, ntz: int,
                  max_position: Optional[int] = None) -> Optional[int]:
@@ -161,7 +170,7 @@ class FirstSolution:
         if len(nonce) != 4:
             raise ValueError("the message layout assumes a 4-byte nonce")
         u = np.uint32
-        word, mask = u(int.from_bytes(nonce, "little")), u(zero_mask(ntz))
+        word, mask = u(self._nonce_word(nonce)), u(self._zero_mask(ntz))
         per_call = self.batch // 256
         inflight: deque = deque()
 
@@ -171,12 +180,12 @@ class FirstSolution:
             return None if f >= self.batch else v0 * 256 + f + 1
 
         for w, lo, hi in segments():
-            p1, p2, m14 = (u(x) for x in width_consts(w))
+            p1, p2, m_len = (u(x) for x in self._width_consts(w))
             for v0 in range(lo, hi, per_call):
                 if max_position is not None and v0 * 256 >= max_position:
                     break
                 n_valid = min(per_call, hi - v0) * 256
-                inflight.append((self._fn(word, u(v0), p1, p2, m14, mask,
+                inflight.append((self._fn(word, u(v0), p1, p2, m_len, mask,
                                           u(n_valid)), v0))
                 if len(inflight) > 2:
                     pos = drain()

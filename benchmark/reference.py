@@ -11,13 +11,49 @@ encoding (the upstream worker's order).
 from __future__ import annotations
 
 import hashlib
-from typing import Optional
+from typing import Callable, Dict, Optional
 
-HASHES = {"md5": hashlib.md5}
+
+def _named(name: str) -> Callable[[bytes], str]:
+    def hexdigest(data: bytes) -> str:
+        try:
+            h = hashlib.new(name)
+        except ValueError:
+            raise ValueError(f"hash model {name!r}: this host's hashlib "
+                             f"has no {name}") from None
+        h.update(data)
+        return h.hexdigest()
+
+    return hexdigest
+
+
+def _blake2b_256(data: bytes) -> str:
+    return hashlib.blake2b(data, digest_size=32).hexdigest()
+
+
+def _sha256d(data: bytes) -> str:
+    """Bitcoin's proof-of-work digest: sha256 of sha256's raw digest."""
+    return hashlib.sha256(hashlib.sha256(data).digest()).hexdigest()
+
+
+# every hash model of the program's registry: name -> hex digest
+HASHES: Dict[str, Callable[[bytes], str]] = {
+    **{name: _named(name) for name in ("md5", "sha1", "sha256", "sha384",
+                                       "sha512", "sha3_256", "ripemd160")},
+    "blake2b_256": _blake2b_256,
+    "sha256d": _sha256d,
+}
+
+
+def hex_digest(hash_model: str, data: bytes) -> str:
+    if hash_model not in HASHES:
+        raise KeyError(f"no reference for hash model {hash_model!r}; "
+                       f"known: {sorted(HASHES)}")
+    return HASHES[hash_model](bytes(data))
 
 
 def solves(hash_model: str, nonce: bytes, secret: bytes, ntz: int) -> bool:
-    digest = HASHES[hash_model](bytes(nonce) + bytes(secret)).hexdigest()
+    digest = hex_digest(hash_model, bytes(nonce) + bytes(secret))
     return digest.endswith("0" * ntz)
 
 

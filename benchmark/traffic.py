@@ -32,6 +32,9 @@ A mix file holds:
                   stratum, all in orders drawn from the seed; so every
                   seed sends the same distribution of work.  The mix
                   ends when the pool does
+``pool_hash``     with a ``pool``: the hash model its first solutions
+                  were found under; a cell whose configuration runs
+                  another model is refused (``manifest.load_cell``)
 ``warm_pool``     the set-up's warm-up requests, in the pool's form
 ``warmup``        without a ``warm_pool``: ``{"requests": n}``
                   closed-loop requests, and/or ``{"bursts": [k, ...]}``
@@ -52,7 +55,7 @@ from typing import Callable, Iterator, List, Optional, Sequence
 
 MIX_KEYS = {"loop", "rate_hz", "n_keys", "zipf_s", "nonce_len",
             "difficulties", "warm_widths", "warmup", "pool", "pool_ntz",
-            "strata", "warm_pool", "why"}
+            "pool_hash", "strata", "warm_pool", "why"}
 
 
 @dataclass(frozen=True)
@@ -81,6 +84,8 @@ def load_mix(path: str) -> dict:
             raise ValueError(f"{path}: a pool is asked at pool_ntz alone")
         if not mix["pool"] or len(mix["pool"]) % mix["strata"]:
             raise ValueError(f"{path}: the pool fills its strata evenly")
+        if "pool_hash" not in mix:
+            raise ValueError(f"{path}: a pool names its pool_hash")
     return mix
 
 

@@ -139,6 +139,80 @@ def test_a_new_config_mix_and_layer_need_only_new_files(cpu, capsys):
     assert res["metrics"]["answered_share"]["value"] == 100.0
 
 
+def test_a_second_hash_model_needs_only_new_files(cpu, capsys):
+    """A sha256d configuration, a pool mix solved under sha256d and a
+    layer, added as files and manifest entries alone, run correct and
+    are judged by the sha256d reference.  The jax backend, not python:
+    a traced run needs device operations to read."""
+    from benchmark import harness, make_pool, manifest, md5_search, reference
+
+    root = cpu
+    bench = os.path.join(root, "benchmark")
+    with open(os.path.join(bench, "configs", "sha256d-py.json"), "w") as f:
+        json.dump({"name": "sha256d-py", "workers": 1, "chips": 1,
+                   "hash_model": "sha256d", "tracing_server": False,
+                   "worker": {"Backend": "jax", "HashModel": "sha256d",
+                              "BatchSize": 4096},
+                   "coordinator": {}, "source": "test", "reduced": []}, f)
+    made = make_pool.make(
+        lambda nonce, ntz: make_pool.brute_first(nonce, ntz, "sha256d"),
+        2, 8, 60.0, 4, hash_model="sha256d")
+    with open(os.path.join(bench, "traffic", "pool-d2.json"), "w") as f:
+        json.dump({"loop": "closed", "nonce_len": 4,
+                   "difficulties": [[2, 1.0]], "warm_widths": [0, 1, 2],
+                   "pool_ntz": 2, "pool_hash": "sha256d", "strata": 4,
+                   "pool": made["pool"], "warm_pool": made["warm_pool"]}, f)
+    with open(os.path.join(bench, "layers", "at_first_share.py"), "w") as f:
+        f.write("from benchmark import reference\n\n\n"
+                "def read(r):\n"
+                "    pos = sum(reference.enum_position(q.secret)"
+                " for q in r.valid)\n"
+                "    return 100.0 * r.credited_hashes / pos\n")
+    cell = "sha256d-py.pool-d2"
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        man = json.load(f)
+    man["configs"].append({"name": "sha256d-py", "source": "test",
+                           "file": "benchmark/configs/sha256d-py.json",
+                           "reduced": [], "why": "test"})
+    man["workloads"].append({"name": cell, "config": "sha256d-py",
+                             "traffic": "pool-d2", "chips": 1,
+                             "why": "test"})
+    next(m for m in man["end_to_end"]
+         if m["name"] == "search_rate")["workloads"].append(cell)
+    man["per_layer"].append({"name": "at_first_share", "unit": "%",
+                             "better": "higher", "source": "host_clock",
+                             "layer": "search driver",
+                             "moves": "search_rate", "workloads": [cell]})
+    with open(path, "w") as f:
+        json.dump(man, f)
+    rc, res = bh.run_cell(capsys, cell, seconds="30")
+    assert rc == 0 and res["correct"] is True, res
+    assert res["diag"]["pool_spent"] is True and res["attempted"] == 8
+    assert res["diag"]["past_first"] == 0
+    assert set(res["metrics"]) == {"setup_s", "search_rate"}
+    assert res["diag"]["compiles_in_window"] == 0
+    rc, res = bh.run_cell(capsys, cell, "--trace", "1")
+    assert rc == 0 and res["correct"] is True, res
+    assert res["metrics"]["at_first_share"]["value"] == 100.0
+
+    # judged by sha256d: a secret that solves md5 alone is wrong
+    nonce, pos = bytes.fromhex(made["pool"][0][0]), 1
+    while not (reference.solves("md5", nonce, md5_search.secret_at(pos), 2)
+               and not reference.solves("sha256d", nonce,
+                                        md5_search.secret_at(pos), 2)):
+        pos += 1
+    secret = md5_search.secret_at(pos)
+    req = harness.Request(nonce=nonce, ntz=2, sent_ntz=2, due=0.0,
+                          answered=1.0, secret=secret)
+
+    class Answered:
+        requests, stray = [req], []
+
+    valid, checks = harness.judge(manifest.load_cell(cell), Answered)
+    assert valid == [] and checks["wrong"] == 1
+
+
 def _run_command(cwd: str, env: dict) -> subprocess.CompletedProcess:
     return subprocess.run(
         [sys.executable, "benchmark/run.py", "--workload",

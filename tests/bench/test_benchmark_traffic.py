@@ -5,11 +5,13 @@ from __future__ import annotations
 import hashlib
 import itertools
 import json
+import os
 import queue
+import shutil
 import threading
 import time
 
-import bench_helpers  # noqa: F401  (puts the checkout on sys.path)
+import bench_helpers  # puts the checkout on sys.path
 import pytest
 
 from benchmark import harness, reference, traffic
@@ -208,12 +210,91 @@ def test_a_pool_is_sent_stratified_by_size_and_then_ends():
 
 def test_a_pool_must_fill_its_strata_at_its_own_difficulty(tmp_path):
     path = tmp_path / "m.json"
+    good = {"loop": "closed", "nonce_len": 4, "difficulties": [[8, 1.0]],
+            "pool_ntz": 8, "pool_hash": "md5", "strata": 4, "pool": POOL}
+    path.write_text(json.dumps(good))
+    assert traffic.load_mix(str(path)) == good
     for bad in ({"pool": POOL[:47]}, {"difficulties": [[7, 1.0]]}):
-        mix = {"loop": "closed", "nonce_len": 4, "difficulties": [[8, 1.0]],
-               "pool_ntz": 8, "strata": 4, "pool": POOL, **bad}
-        path.write_text(json.dumps(mix))
+        path.write_text(json.dumps({**good, **bad}))
         with pytest.raises(ValueError):
             traffic.load_mix(str(path))
+
+
+def test_a_pool_must_name_its_hash(tmp_path):
+    path = tmp_path / "m.json"
+    mix = {"loop": "closed", "nonce_len": 4, "difficulties": [[8, 1.0]],
+           "pool_ntz": 8, "strata": 4, "pool": POOL}
+    path.write_text(json.dumps(mix))
+    with pytest.raises(ValueError, match="pool_hash"):
+        traffic.load_mix(str(path))
+
+
+def _root_with_cell(tmp_path, hash_model: str, traffic_name: str,
+                    runs: str = "") -> str:
+    """A checkout with one cell: a configuration of ``hash_model``, whose
+    worker runs ``runs`` (default the same), on the repository's mix
+    ``traffic_name``."""
+    root = tmp_path / "checkout"
+    (root / "benchmark" / "configs").mkdir(parents=True)
+    (root / "benchmark" / "traffic").mkdir()
+    src = os.path.join(bench_helpers.REPO, "benchmark", "traffic",
+                       traffic_name + ".json")
+    shutil.copy(src, root / "benchmark" / "traffic")
+    (root / "benchmark" / "configs" / "c.json").write_text(json.dumps(
+        {"name": "c", "workers": 1, "chips": 1, "hash_model": hash_model,
+         "worker": {"Backend": "python",
+                    "HashModel": runs or hash_model}}))
+    (root / "BENCHMARK.json").write_text(json.dumps({
+        "configs": [{"name": "c", "file": "benchmark/configs/c.json"}],
+        "workloads": [{"name": "c." + traffic_name, "config": "c",
+                       "traffic": traffic_name, "chips": 1}],
+        "end_to_end": [], "per_layer": []}))
+    return str(root)
+
+
+@pytest.mark.parametrize("hash_model,mix", [("sha256d", "search-d8"),
+                                            ("md5", "search-d7-sha256d")])
+def test_a_cell_whose_pool_was_solved_under_another_hash_is_refused(
+        tmp_path, monkeypatch, hash_model, mix):
+    from benchmark import manifest
+
+    root = _root_with_cell(tmp_path, hash_model, mix)
+    monkeypatch.setattr(manifest, "ROOT", root)
+    touched = []
+    monkeypatch.setattr(harness, "device_check", touched.append)
+    pool_hash = traffic.load_mix(os.path.join(
+        root, "benchmark", "traffic", mix + ".json"))["pool_hash"]
+    with pytest.raises(ValueError) as err:
+        harness.run(["--workload", "c." + mix, "--seed", "1",
+                     "--seconds", "1"])
+    assert hash_model in str(err.value) and pool_hash in str(err.value)
+    assert pool_hash != hash_model and touched == []
+
+
+@pytest.mark.parametrize("hash_model,runs,mix", [
+    ("sha256d", "md5", "small-zipf"), ("md5", "sha256d", "search-d8")])
+def test_a_config_whose_worker_runs_another_hash_is_refused(
+        tmp_path, monkeypatch, hash_model, runs, mix):
+    from benchmark import manifest
+
+    monkeypatch.setattr(manifest, "ROOT",
+                        _root_with_cell(tmp_path, hash_model, mix, runs))
+    touched = []
+    monkeypatch.setattr(harness, "device_check", touched.append)
+    with pytest.raises(ValueError) as err:
+        harness.run(["--workload", "c." + mix, "--seed", "1",
+                     "--seconds", "1"])
+    assert "HashModel" in str(err.value) and runs in str(err.value)
+    assert touched == []
+
+
+def test_an_open_loop_mix_takes_any_hash(tmp_path, monkeypatch):
+    from benchmark import manifest
+
+    monkeypatch.setattr(manifest, "ROOT",
+                        _root_with_cell(tmp_path, "sha256d", "small-zipf"))
+    assert manifest.load_cell("c.small-zipf").config["hash_model"] == (
+        "sha256d")
 
 
 @pytest.mark.parametrize("secret,first,credit", [
