@@ -186,11 +186,13 @@ class _RateMeter:
 _RATE_METER = _RateMeter()
 
 
-def _register_mesh_hashes() -> None:
-    """Create ``search.mesh_hashes`` at each solve's start, so a worker
-    whose solves all ran on one device reads 0 there, where a program
-    that does not count mesh work has no such counter at all."""
+def _register_part_counters() -> None:
+    """Create ``search.mesh_hashes`` and ``search.finalize_hashes`` at
+    each solve's start, so a worker whose solves all ran on one device,
+    or under a hash with no ``finalize`` stage, reads 0 there, where a
+    program that does not count that part has no such counter at all."""
     metrics.inc("search.mesh_hashes", 0)
+    metrics.inc("search.finalize_hashes", 0)
 
 
 # canonical home is the jax-free partition module (advisor r3: the
@@ -304,7 +306,7 @@ class RequestOperands:
         return self._dense
 
     def _place(self, make_pack, sharding):
-        with SPANS.span("search.bind"):
+        with SPANS.span("search.bind", hash=self.model.name):
             pack = make_pack()
             placed = jax.device_put(pack.words, sharding)
         metrics.inc("search.operand_binds")
@@ -499,6 +501,8 @@ def persistent_search(
         metrics.inc("search.hashes", n_cand)
         if on_mesh:
             metrics.inc("search.mesh_hashes", n_cand)
+        if model.finalize is not None:
+            metrics.inc("search.finalize_hashes", n_cand)
 
     def drain_one() -> Tuple[Optional[SearchResult], bool]:
         """Poll the head to readiness, then convert.  Returns
@@ -591,7 +595,7 @@ def persistent_search(
             metrics.inc("search.found")
         return found
 
-    _register_mesh_hashes()
+    _register_part_counters()
     _RATE_METER.enter()
     try:
         with WATCHDOG.active():
@@ -602,7 +606,7 @@ def persistent_search(
                     # drain left it
                     dispatches = 0
                     with SPANS.span("search.segment", width=width,
-                                    dispatches=0) as seg:
+                                    dispatches=0, hash=model.name) as seg:
                         WATCHDOG.beat()  # step build may compile below
                         k = launch_steps_for(vw, target_chunks, tbc,
                                              launch_candidates)

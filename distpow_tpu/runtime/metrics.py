@@ -20,6 +20,10 @@ declaration distpow-lint's ``metrics-registry`` rule verifies every
   mesh factories mark, parallel/mesh_search.py ``_on_mesh``); the
   width-0 probe, single-device steps and one-device meshes add
   nothing here
+* ``search.finalize_hashes`` — the part of ``search.hashes`` whose
+  candidates ran a model's ``finalize`` stage (models/registry.py:
+  sha256d's second compression), counted by the search driver at the
+  same points and clipped the same way; 0 for a hash with no such stage
 * ``search.launches``      — device dispatches
 * ``search.cancelled``     — searches stopped by a cancel check
 * ``search.found``         — searches that returned a secret
@@ -242,7 +246,7 @@ KNOWN_COUNTERS = frozenset({
     "search.hashes", "search.launches", "search.cancelled", "search.found",
     "search.persistent_steps",
     "search.halted_dispatches", "search.mesh_hashes",
-    "search.operand_binds",
+    "search.finalize_hashes", "search.operand_binds",
     "worker.mine_rpcs", "worker.found_rpcs", "worker.cancel_rpcs",
     "worker.results_sent", "worker.forward_retries",
     "coord.mine_rpcs", "coord.fanouts", "coord.late_results",

@@ -170,6 +170,44 @@ def test_search_models(algo, nonce):
     assert got is not None and got.secret == oracle
 
 
+@pytest.mark.parametrize("algo", ["sha256d", "md5"])
+def test_search_counts_finalize_hashes_and_names_the_hash(algo):
+    """Seeded random nonces through the default one-device step, each
+    solved past the width-0 probe, by the chained persistent step: each
+    secret is the oracle's reference-order first, every candidate
+    of a sha256d solve ran its ``finalize`` stage
+    (``search.finalize_hashes`` equals ``search.hashes``) and md5's
+    none, and every ``search.segment`` and ``search.bind`` span names
+    the hash."""
+    import random
+
+    from distpow_tpu.runtime.metrics import REGISTRY
+    from distpow_tpu.runtime.spans import SPANS
+
+    rng = random.Random(31)
+    tbs = list(range(256))
+    model = get_hash_model(algo)
+    for i in range(3):
+        nonce = bytes(rng.randrange(256) for _ in range(4))
+        hashes0 = REGISTRY.get("search.hashes")
+        final0 = REGISTRY.get("search.finalize_hashes")
+        SPANS.reset()
+        with SPANS.bind(3100 + i, "w-test"):
+            got = solve(nonce, 3, tbs, model=model, batch_size=1 << 13)
+        assert got is not None and len(got.secret) > 1
+        assert got.secret == puzzle.python_search(nonce, 3, tbs, algo=algo)
+        hashes = REGISTRY.get("search.hashes") - hashes0
+        final = REGISTRY.get("search.finalize_hashes") - final0
+        assert hashes > 256
+        assert final == (hashes if algo == "sha256d" else 0)
+        spans = [s for s in SPANS.spans_for(3100 + i)
+                 if s["name"] in ("search.segment", "search.bind")]
+        assert {s["name"] for s in spans} == {"search.segment",
+                                              "search.bind"}
+        assert all(s["attrs"]["hash"] == algo for s in spans)
+    assert "search.finalize_hashes" in REGISTRY.snapshot()["counters"]
+
+
 @pytest.mark.slow
 def test_search_sha512_boundary_layouts():
     """sha512 two-block-tail padding boundary + long-nonce host
